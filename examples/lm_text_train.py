@@ -34,12 +34,8 @@ CORPUS = [
 def main() -> int:
     import jax
 
-    # Not a no-op: some environments pre-import jax from a site hook
-    # that programmatically overrides jax_platforms AFTER the env var
-    # was read — re-assert the user's choice (same as lm_train.py).
-    env_platforms = os.environ.get("JAX_PLATFORMS")
-    if env_platforms and jax.config.jax_platforms != env_platforms:
-        jax.config.update("jax_platforms", env_platforms)
+    from tpu_ddp.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import numpy as np
 

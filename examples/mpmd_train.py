@@ -65,9 +65,10 @@ def main(argv=None) -> int:
     rank = args.rank if args.rank is not None else 0
 
     import jax
-    env_platforms = os.environ.get("JAX_PLATFORMS")
-    if env_platforms and jax.config.jax_platforms != env_platforms:
-        jax.config.update("jax_platforms", env_platforms)
+
+    from tpu_ddp.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     import jax.numpy as jnp
     import numpy as np
 

@@ -29,11 +29,8 @@ from common import parse_arguments  # noqa: E402
 def main(argv=None) -> int:
     args = parse_arguments(argv, require_num_nodes=True)
 
-    import jax
-
-    env_platforms = os.environ.get("JAX_PLATFORMS")
-    if env_platforms and jax.config.jax_platforms != env_platforms:
-        jax.config.update("jax_platforms", env_platforms)
+    from tpu_ddp.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import numpy as np
 

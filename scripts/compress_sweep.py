@@ -206,9 +206,5 @@ if __name__ == "__main__":
             f"{flags} --xla_force_host_platform_device_count=8").strip()
     import jax
 
-    if jax.config.jax_platforms != "cpu":
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except Exception:
-            pass
+    jax.config.update("jax_platforms", "cpu")
     main(int(os.environ.get("N_DEVICES", "8")))

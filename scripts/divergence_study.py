@@ -113,9 +113,5 @@ if __name__ == "__main__":
             f"{flags} --xla_force_host_platform_device_count=4").strip()
     import jax
 
-    if jax.config.jax_platforms != "cpu":
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except Exception:
-            pass
+    jax.config.update("jax_platforms", "cpu")
     main()

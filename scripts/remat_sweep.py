@@ -94,7 +94,8 @@ def _timing(trainer, state, staged, compiled_cost: dict) -> dict:
     xb = compiled_cost.get("xla_bytes_accessed")
     if xb:
         out["achieved_hbm_gbps"] = round(xb / step_s / 1e9, 1)
-        out["achieved_hbm_frac"] = round(xb / (bw_gbps * 1e9) / step_s, 4)
+        out["achieved_hbm_frac"] = (
+            round(xb / (bw_gbps * 1e9) / step_s, 4) if bw_gbps else None)
     return out
 
 

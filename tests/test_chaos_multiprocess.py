@@ -160,7 +160,7 @@ def test_elastic_reshard_absorbs_unannounced_crash(tmp_path):
     the survivor first hits the failed gloo collective, then must wait
     for the launcher to publish the shrunken epoch and convert the
     wreckage into a membership change (engine._raise_membership_change)
-    instead of dying on the XlaRuntimeError."""
+    instead of dying on the JaxRuntimeError."""
     env = dict(SMOKE_ENV)
     env.update({
         "TPU_DDP_CHAOS_FAULTS": "hard-exit@2:rank=1",

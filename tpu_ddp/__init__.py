@@ -22,8 +22,4 @@ mesh instead of the reference's gloo/TCP process group.
 
 __version__ = "0.1.0"
 
-from tpu_ddp.utils import compat as _compat
-
-_compat.install()  # backfill jax.shard_map on older jax releases
-
 from tpu_ddp.utils.config import TrainConfig, SEED  # noqa: F401

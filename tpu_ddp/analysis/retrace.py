@@ -5,10 +5,19 @@ scan every epoch, so a "compiled" training loop silently re-lowered and
 re-compiled the same program over and over — visible only as a
 mysteriously slow wall clock. :func:`no_retrace` turns it into a hard
 failure: it counts XLA compiles per callable name for the duration of
-the block (via jax's own compile-path debug logging, so there is no
-flag to flip and no monkeypatching of jit internals) and raises
-:class:`RetraceError` if any watched callable compiles more than
-``max_compiles`` times.
+the block and raises :class:`RetraceError` if any watched callable
+compiles more than ``max_compiles`` times.
+
+Counting goes through jax's public instrumentation hook
+(``jax.monitoring.register_event_duration_secs_listener``): every
+request to the backend compiler records one
+``/jax/core/compile/backend_compile_duration`` event carrying the
+program's name (``jit(<callable>)``) and how long it took. A program
+loaded from the persistent compilation cache records the event too
+(with a short duration), so a warm cache never hides a retrace. The
+same events give :attr:`_CompileCounter.compile_seconds`, the set-up
+time a block paid — ``chip_smoke.py`` reports it apart from the
+steady-state time.
 
 Counting is by CALLABLE NAME, deliberately: the retrace bug class is
 "the same function compiled twice with different shapes/avals", which
@@ -24,11 +33,16 @@ The pytest fixture (tests/conftest.py) exposes this as ``no_retrace``.
 
 from __future__ import annotations
 
-import logging
 from contextlib import contextmanager
 
-_COMPILE_LOGGER = "jax._src.interpreters.pxla"
-_COMPILE_PREFIX = "Compiling %s"
+import jax
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# Lowering to MLIR and backend compilation, one event each per program.
+# (The trace-duration events nest — an inner jitted function's trace
+# lies inside its caller's — so they are left out of the sum.)
+_SETUP_EVENTS = (_COMPILE_EVENT,
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration")
 
 # jax compiles these tiny helper programs for EAGER ops outside any
 # user jit (one per dtype/shape combination) — they are not retraces
@@ -53,27 +67,44 @@ class RetraceError(AssertionError):
     """A watched callable compiled more often than allowed."""
 
 
-class _CompileCounter(logging.Handler):
+class _CompileCounter:
+    """Listener for jax's compile-path duration events."""
+
     def __init__(self, watch, ignore):
-        super().__init__(level=logging.DEBUG)
         self.watch = tuple(watch) if watch is not None else None
         self.ignore = ignore
         self.counts: dict = {}
-        self.shapes: dict = {}
+        self.compile_seconds = 0.0
 
-    def emit(self, record):
-        if not record.msg.startswith(_COMPILE_PREFIX):
+    def __call__(self, event, duration, fun_name="?", **_):
+        if event in _SETUP_EVENTS:
+            self.compile_seconds += duration
+        if event != _COMPILE_EVENT:
             return
-        args = record.args or ()
-        name = str(args[0]) if args else "?"
+        name = str(fun_name)
+        if name.startswith("jit(") and name.endswith(")"):
+            name = name[4:-1]
         if self.watch is not None:
             if name not in self.watch:
                 return
         elif name in self.ignore or name.startswith("_"):
             return
         self.counts[name] = self.counts.get(name, 0) + 1
-        if len(args) > 1:
-            self.shapes.setdefault(name, []).append(str(args[1])[:200])
+
+
+@contextmanager
+def count_compiles(watch=None, ignore=IGNORED_CALLABLES):
+    """Yield a live :class:`_CompileCounter` for the block: ``.counts``
+    maps callable name -> compiles so far (``watch`` restricts counting
+    to the given names; without it every non-helper compile counts) and
+    ``.compile_seconds`` sums lower + compile time of every program the
+    block built. No budget is enforced — that is :func:`no_retrace`."""
+    counter = _CompileCounter(watch, ignore)
+    jax.monitoring.register_event_duration_secs_listener(counter)
+    try:
+        yield counter
+    finally:
+        jax.monitoring.unregister_event_duration_listener(counter)
 
 
 @contextmanager
@@ -83,39 +114,21 @@ def no_retrace(max_compiles: int = 1, watch=None,
 
     ``max_compiles`` is the per-callable budget for the whole block
     (1 = "compile at most once"; use 0 for a block that must reuse
-    existing executables only). ``watch`` restricts counting to the
-    given callable names; without it every non-helper compile counts.
+    existing executables only). ``watch`` and ``ignore`` are
+    :func:`count_compiles`'s.
 
-    Yields the live counter (``.counts`` maps name -> compiles so far)
-    and raises :class:`RetraceError` on exit if any callable exceeded
-    the budget, naming the callable and the argument shapes of each
-    compile — the shape drift IS the diagnosis for the common bug
-    (an un-padded batch remainder, a Python-int axis that became a
-    float, a fresh closure identity per epoch).
+    Yields the live counter and raises :class:`RetraceError` on exit if
+    any callable exceeded the budget. The usual causes: an un-padded
+    batch remainder, a Python-int axis that became a float, a fresh
+    closure identity per epoch.
     """
-    logger = logging.getLogger(_COMPILE_LOGGER)
-    counter = _CompileCounter(watch, ignore)
-    old_level = logger.level
-    old_propagate = logger.propagate
-    logger.addHandler(counter)
-    # The handler needs DEBUG records delivered; stop propagation so
-    # forcing DEBUG doesn't spray jax's compile chatter through root
-    # handlers for the duration of the block. Restore both on exit.
-    logger.setLevel(logging.DEBUG)
-    logger.propagate = False
-    try:
+    with count_compiles(watch, ignore) as counter:
         yield counter
-    finally:
-        logger.removeHandler(counter)
-        logger.setLevel(old_level)
-        logger.propagate = old_propagate
     offenders = {n: c for n, c in counter.counts.items()
                  if c > max_compiles}
     if offenders:
-        detail = "; ".join(
-            f"{n!r} compiled {c}x "
-            f"(shapes: {' | '.join(counter.shapes.get(n, [])[:4])})"
-            for n, c in sorted(offenders.items()))
+        detail = "; ".join(f"{n!r} compiled {c}x"
+                           for n, c in sorted(offenders.items()))
         raise RetraceError(
             f"unexpected recompilation (> {max_compiles} per "
             f"callable): {detail} — the round-8 bug class: a "

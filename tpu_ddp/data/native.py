@@ -56,12 +56,13 @@ class NativeLib:
         self.build_error: str | None = None
 
     def _build(self) -> bool:
-        if os.path.exists(self._lib_path):
-            if not os.path.exists(self._src_path):
-                return True  # prebuilt .so shipped without source: use it
-            if os.path.getmtime(self._lib_path) >= \
-                    os.path.getmtime(self._src_path):
-                return True
+        # The .so is never committed (.gitignore): it is always built
+        # here from the committed source, so one older than its source
+        # is rebuilt.
+        if os.path.exists(self._lib_path) and \
+                os.path.getmtime(self._lib_path) >= \
+                os.path.getmtime(self._src_path):
+            return True
         try:
             # Build ONLY this library's target: a compile failure in a
             # sibling library must not poison this one, and per-target

@@ -4,7 +4,7 @@ The reference overlaps host work with compute through DataLoader workers +
 ``pin_memory=True`` (reference part1/main.py:36-41). The TPU-native
 equivalent is to issue ``device_put`` for upcoming batches before the
 current step completes — JAX transfers are asynchronous, so a small
-lookahead hides the PCIe/tunnel latency behind the device step.
+lookahead hides the PCIe latency behind the device step.
 """
 
 from __future__ import annotations

@@ -130,6 +130,10 @@ def launch(
     host-platform device count of ``devices_per_proc``, so a laptop/CI host
     emulates an ``nproc``-node cluster with ``nproc * devices_per_proc``
     total dp slots. Extra env wins over the computed defaults.
+    ``platform="tpu"`` is accepted with ``nproc=1`` only (the one child
+    drives every local chip) and refused otherwise, before any spawn.
+    This process imports jax but never initialises a backend, so the
+    child finds the chips free.
 
     ``heartbeat_timeout`` arms the watchdog: workers inherit
     ``TPU_DDP_HEARTBEAT_DIR`` (a fresh temp dir unless ``heartbeat_dir``
@@ -154,6 +158,16 @@ def launch(
     """
     if nproc < 1:
         raise ValueError("nproc must be >= 1")
+    if platform == "tpu" and nproc > 1:
+        # Refused before anything is spawned: libtpu gives every process
+        # all of the host's chips unless its environment binds it to
+        # one, and nothing here does — the first child would take the
+        # chips and the others fail or hang at backend start-up.
+        raise ValueError(
+            "platform='tpu' takes nproc=1: one process drives all of "
+            "the host's chips as dp slots (parts/common.py), and "
+            "several would each open every chip. On a pod, run one "
+            "main.py per host instead")
     if part in PARTS:
         script = PARTS_DIR / part / "main.py"
     elif part.endswith(".py"):
@@ -590,8 +604,9 @@ def main(argv=None) -> int:
     p.add_argument("--nproc", type=int, required=True,
                    help="number of rank processes (the --num-nodes value)")
     p.add_argument("--platform", default="cpu",
-                   help="JAX platform for workers (default cpu; use tpu "
-                        "only with per-process device isolation)")
+                   help="JAX platform for workers (default cpu; tpu "
+                        "only with --nproc 1: the one worker drives "
+                        "every local chip)")
     p.add_argument("--devices-per-proc", type=int, default=1,
                    help="forced CPU device count per worker (cpu only)")
     p.add_argument("--port", type=int, default=None,

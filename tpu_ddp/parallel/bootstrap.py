@@ -78,16 +78,9 @@ def init_distributed_setup(
     # jax.process_count, ...) — jax.distributed.initialize must run first.
     if world_size > 1 and not jax.distributed.is_initialized():
         coordinator = f"{master_ip}:{master_port}"
-        if "cpu" in os.environ.get("JAX_PLATFORMS", "").lower().split(","):
-            # The CPU backend's default collectives implementation
-            # ("none") rejects multi-process computations at the first
-            # collective; gloo-over-TCP is the working one — and the
-            # literal analogue of the reference's gloo process group.
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-            except (AttributeError, ValueError):
-                pass  # flag renamed/absent: that jax works by default
+        # On the CPU backend the cross-process collectives are jax's
+        # default gloo-over-TCP — the literal analogue of the reference's
+        # gloo process group; nothing to configure.
         from tpu_ddp.resilience.elastic import (bootstrap as
                                                 elastic_bootstrap,
                                                 elastic_env_active)

@@ -587,7 +587,6 @@ def spmd_pipeline_hlo(model, mesh, num_micro: int, seq_len: int,
     """Compiled HLO of the SPMD 1F1B grad step on ``mesh`` (positive
     overlap control: per-tick edge ppermutes interleave with compute)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from tpu_ddp.parallel.mesh import PIPE_AXIS
     from tpu_ddp.parallel.pipeline import (pipeline_1f1b_grads,
@@ -602,10 +601,10 @@ def spmd_pipeline_hlo(model, mesh, num_micro: int, seq_len: int,
             ls, n, g = pipeline_1f1b_grads(
                 model, p, x, y, pp_size=pp, num_micro=num_micro)
             return ls[None], g
-        return shard_map(body, mesh=mesh,
-                         in_specs=(specs, P(), P()),
-                         out_specs=(P(PIPE_AXIS), specs),
-                         check_rep=False)(p, x, y)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(specs, P(), P()),
+                             out_specs=(P(PIPE_AXIS), specs),
+                             check_vma=False)(p, x, y)
 
     x = jnp.zeros((batch, seq_len), jnp.int32)
     p = jax.device_put(params, jax.tree.map(
@@ -622,7 +621,6 @@ def mega_edge_hlo(model, mesh, num_micro: int, seq_len: int,
     and feeds ALL remaining compute, so ``assert_overlap`` must fail."""
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from tpu_ddp.parallel.mesh import PIPE_AXIS
     from tpu_ddp.parallel.pipeline import (pipeline_param_specs,
@@ -654,8 +652,9 @@ def mega_edge_hlo(model, mesh, num_micro: int, seq_len: int,
         return jnp.sum(nll)[None]
 
     def step(p, x, y):
-        return shard_map(body, mesh=mesh, in_specs=(specs, P(), P()),
-                         out_specs=P(PIPE_AXIS), check_rep=False)(p, x, y)
+        return jax.shard_map(body, mesh=mesh, in_specs=(specs, P(), P()),
+                             out_specs=P(PIPE_AXIS),
+                             check_vma=False)(p, x, y)
 
     x = jnp.zeros((batch, seq_len), jnp.int32)
     p = jax.device_put(params, jax.tree.map(

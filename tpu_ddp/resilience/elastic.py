@@ -201,13 +201,19 @@ def beacon_dir(directory: str, epoch: int) -> str:
 # ---------------------------------------------------------------------------
 
 
+# Liveness is the launcher's per-rank file-heartbeat watchdog, not the
+# coordination service, so its own heartbeat budget is out of reach
+# (the 10 s x 100000 beats the older interval/count pair gave).
+_HEARTBEAT_TIMEOUT_S = 1_000_000
+
+
 def bootstrap(coordinator: str, num_processes: int, process_id: int,
               init_timeout: int = 60) -> None:
     """Join (or re-join) a coordination world without the static-world
     fatalities of ``jax.distributed.initialize``. Safe to call after
     :func:`teardown_world`; rank 0 hosts the service."""
     from jax._src import distributed as jdist
-    from jax._src.lib import xla_extension as xe
+    from jax._src.lib import _jax
 
     state = jdist.global_state
     if state.client is not None:
@@ -215,12 +221,12 @@ def bootstrap(coordinator: str, num_processes: int, process_id: int,
                            "teardown_world() before re-bootstrapping")
     if process_id == 0:
         bind = "[::]:" + coordinator.rsplit(":", 1)[1]
-        state.service = xe.get_distributed_runtime_service(
+        state.service = _jax.get_distributed_runtime_service(
             bind, num_processes,
-            heartbeat_interval=10, max_missing_heartbeats=100000)
-    state.client = xe.get_distributed_runtime_client(
+            heartbeat_timeout=_HEARTBEAT_TIMEOUT_S)
+    state.client = _jax.get_distributed_runtime_client(
         coordinator, process_id, init_timeout=init_timeout,
-        heartbeat_interval=10, max_missing_heartbeats=100000,
+        heartbeat_timeout=_HEARTBEAT_TIMEOUT_S,
         missed_heartbeat_callback=_benign_coordination_error,
         shutdown_on_destruction=False, use_compression=True)
     state.client.connect()

@@ -177,7 +177,6 @@ def build_cp_prefill_step(model, block_size: int, blocks_per_seq: int,
     rides each rank's head group). The chunk's K/V and logits then
     all-gather so the pool scatter and the boundary sample are
     replicated — identical math to the single-rank step."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if mode == "ulysses":
@@ -217,10 +216,10 @@ def build_cp_prefill_step(model, block_size: int, blocks_per_seq: int,
         lg = lax.all_gather(logits, "sp", axis=0, tiled=True)
         return kc[:, 0], vc[:, 0], lg            # (L, C, KV, hd), (C, V)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(None, "sp"), P()),
-        out_specs=(P(), P(), P()), check_rep=False)
+        out_specs=(P(), P(), P()), check_vma=False)
 
     def step(params, pool_k, pool_v, table, tokens, start, prompt_len,
              temp, seed):

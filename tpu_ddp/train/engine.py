@@ -749,7 +749,7 @@ class Trainer:
         """Fused on-device normalization for raw uint8 batches.
 
         Transferring uint8 moves 4x fewer bytes over PCIe than host-side
-        float32 normalization (tunnel/HBM bandwidth is the bottleneck);
+        float32 normalization (host-to-device bandwidth is the bottleneck);
         the arithmetic then fuses into the first conv. Branch is on the
         static dtype, so f32 inputs (the reference-parity host path,
         reference part1/main.py:20-31) compile to a no-op. Constants come
@@ -1353,7 +1353,7 @@ class Trainer:
         """When a step died because a PEER died, convert the wreckage
         into a :class:`~tpu_ddp.resilience.elastic.MembershipChange`.
 
-        A lost rank surfaces on survivors as an ``XlaRuntimeError`` from
+        A lost rank surfaces on survivors as a ``JaxRuntimeError`` from
         the in-flight collective (gloo: "Connection closed by peer").
         That alone does not prove a membership change — a genuinely
         broken network should still crash — so this waits up to
@@ -1366,8 +1366,7 @@ class Trainer:
         caller re-raises the original error."""
         if elastic is None:
             return
-        from jaxlib.xla_extension import XlaRuntimeError
-        if not isinstance(exc, XlaRuntimeError):
+        if not isinstance(exc, jax.errors.JaxRuntimeError):
             return
         from tpu_ddp.resilience.elastic import MembershipChange
         from tpu_ddp.resilience.watchdog import touch_heartbeat

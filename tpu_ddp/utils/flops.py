@@ -61,12 +61,19 @@ def peak_tflops(device) -> tuple[float | None, str]:
             return float(env), "env:TPU_DDP_PEAK_TFLOPS"
         except ValueError:
             return None, f"unparseable TPU_DDP_PEAK_TFLOPS={env!r}"
+    return _by_device_kind(device, _PEAKS, "peak")
+
+
+def _by_device_kind(device, table, what: str) -> tuple[float | None, str]:
+    """First ``table`` entry whose key is in the device's kind, with its
+    source; (None, reason) off-TPU or for a kind not in the table."""
     if device.platform != "tpu":
-        return None, f"non-TPU platform {device.platform!r}: no peak table"
+        return None, (f"non-TPU platform {device.platform!r}: "
+                      f"no {what} table")
     kind = device.device_kind.lower()
-    for sub, peak in _PEAKS:
+    for sub, value in table:
         if sub in kind:
-            return peak, f"device_kind {device.device_kind!r}"
+            return value, f"device_kind {device.device_kind!r}"
     return None, f"unknown device_kind {device.device_kind!r}"
 
 
@@ -187,29 +194,22 @@ _HBM_GBPS = (
 )
 
 
-def device_hbm_gbps(device,
-                    default: float = 819.0) -> tuple[float, str]:
-    """(HBM bandwidth GB/s for ``device``, source label).
+def device_hbm_gbps(device) -> tuple[float | None, str]:
+    """(HBM bandwidth GB/s for ``device``, source string).
 
-    ``TPU_DDP_HBM_GBPS`` overrides; unknown kinds fall back to
-    ``default`` (the v5e bench chip) with the source saying so — so
-    bandwidth-utilization accounting degrades to a LABELED estimate,
-    never a number indistinguishable from a real measurement (the
-    peak_tflops contract, with a fallback instead of None)."""
+    The :func:`peak_tflops` contract: a non-TPU platform or a kind not in
+    the table returns (None, reason), so bandwidth utilization is
+    reported as null rather than against another chip's peak.
+    ``TPU_DDP_HBM_GBPS`` overrides (for chips not in the table); a value
+    that does not parse raises ``ValueError``."""
     env = os.environ.get("TPU_DDP_HBM_GBPS")
     if env:
         try:
             return float(env), "env:TPU_DDP_HBM_GBPS"
         except ValueError:
-            pass
-    kind = getattr(device, "device_kind", "")
-    for sub, bw in _HBM_GBPS:
-        if sub in kind.lower():
-            return bw, f"device_kind {kind!r}"
-    return default, (f"FALLBACK default (platform "
-                     f"{getattr(device, 'platform', '?')!r}, kind "
-                     f"{kind!r} not in table) — estimate, not the "
-                     "real chip's bandwidth")
+            raise ValueError(
+                f"TPU_DDP_HBM_GBPS={env!r} is not a number") from None
+    return _by_device_kind(device, _HBM_GBPS, "bandwidth")
 
 
 def mfu_fields(flops_per_step: float | None, step_seconds: float,
