@@ -266,10 +266,12 @@ def _ladder_env():
 def p1_ladder() -> dict:
     import jax
 
+    from tpu_ddp.utils.profiling import DDP_TRAIN_STEP
+
     with _ladder_env():
-        part1 = _run_ladder_part("part1", [], "base", 1)
+        part1 = _run_ladder_part("part1", [], DDP_TRAIN_STEP, 1)
         part3 = _run_ladder_part("part3", ["--num-nodes", "1"],
-                                 "sharded_body", len(jax.devices()))
+                                 DDP_TRAIN_STEP, len(jax.devices()))
     return {"part1": part1, "part3": part3,
             "compile_s": round(part1["compile_s"] + part3["compile_s"], 2),
             "steady_s_per_step": {"part1": part1["steady_s_per_step"],
@@ -395,6 +397,7 @@ def _lm_steps(trainer, batch: int, steps: int) -> dict:
 
     from tpu_ddp.analysis.retrace import no_retrace
     from tpu_ddp.train.lm import make_lm_batch
+    from tpu_ddp.utils.profiling import LM_TRAIN_STEP
 
     model = trainer.model
     state = trainer.init_state(seed=SEED)
@@ -404,7 +407,7 @@ def _lm_steps(trainer, batch: int, steps: int) -> dict:
     _assert_mosaic(trainer.lower_train_step(state, x, y).as_text(),
                    "LM train step")
     losses, times = [], []
-    with no_retrace(watch=("_base_step",)) as compiles:
+    with no_retrace(watch=(LM_TRAIN_STEP,)) as compiles:
         for _ in range(steps):
             t0 = time.perf_counter()
             state, loss = trainer.train_step(state, x, y)
@@ -413,7 +416,7 @@ def _lm_steps(trainer, batch: int, steps: int) -> dict:
             times.append(time.perf_counter() - t0)
     if not all(math.isfinite(v) for v in losses):
         raise AssertionError(f"non-finite LM loss in {losses}")
-    if compiles.counts != {"_base_step": 1}:
+    if compiles.counts != {LM_TRAIN_STEP: 1}:
         raise AssertionError(f"LM train step compiles {compiles.counts}, "
                              "expected one")
     params = sum(int(p.size) for p in jax.tree.leaves(state.params))
@@ -452,6 +455,7 @@ def p4_serve() -> dict:
     from tpu_ddp.analysis.retrace import no_retrace
     from tpu_ddp.models import generate, make_transformer
     from tpu_ddp.serve import ServeEngine
+    from tpu_ddp.utils.profiling import SERVE_DECODE, SERVE_PREFILL
 
     _assert_kernels_compiled()
     model = make_transformer(LM_PRESET, max_seq_len=LM_SEQ_LEN)
@@ -478,13 +482,14 @@ def p4_serve() -> dict:
                            "int8 prefill step")
         # Warm both programs (two prefill chunks, one decode step) so
         # compilation stays out of the timed window.
-        with no_retrace(watch=("step",), max_compiles=2) as warm:
+        with no_retrace(watch=(SERVE_DECODE, SERVE_PREFILL)) as warm:
             engine.submit(prompts[0][:engine.prefill_chunk + 8], 2)
             engine.run()
-        if warm.counts != {"step": 2}:
+        if warm.counts != {SERVE_DECODE: 1, SERVE_PREFILL: 1}:
             raise AssertionError(f"{quant}: decode + prefill compiles "
                                  f"{warm.counts}, expected two")
-        with no_retrace(watch=("step",), max_compiles=0):
+        with no_retrace(watch=(SERVE_DECODE, SERVE_PREFILL),
+                        max_compiles=0):
             t0 = time.perf_counter()
             reqs = [engine.submit(p, SERVE_NEW_TOKENS, seed=i)
                     for i, p in enumerate(prompts)]
@@ -550,6 +555,7 @@ def p5_four_chips() -> dict:
     from tpu_ddp.train.engine import Trainer
     from tpu_ddp.train.lm import LMTrainer
     from tpu_ddp.utils.config import TrainConfig
+    from tpu_ddp.utils.profiling import DDP_TRAIN_STEP
 
     n = len(jax.devices())
     if n < 4:
@@ -602,7 +608,7 @@ def p5_four_chips() -> dict:
 
     with _ladder_env():
         part3 = _run_ladder_part("part3", ["--num-nodes", "1"],
-                                 "sharded_body", n)
+                                 DDP_TRAIN_STEP, n)
     compile_s += part3["compile_s"]
 
     lm = make_transformer(LM_PRESET, max_seq_len=LM_SEQ_LEN,

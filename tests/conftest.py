@@ -75,7 +75,8 @@ def no_retrace():
     """The retrace sentinel (tpu_ddp/analysis/retrace.py) as a fixture:
 
         def test_loop(no_retrace):
-            with no_retrace(watch=("train_step",)):
+            # a program's name: tpu_ddp/utils/profiling.py PROGRAMS
+            with no_retrace(watch=(profiling.DDP_TRAIN_STEP,)):
                 for _ in range(5):
                     trainer.train_step(state, *batch)
 

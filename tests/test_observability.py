@@ -10,7 +10,7 @@ from tpu_ddp.models import get_model
 from tpu_ddp.train.engine import Trainer
 from tpu_ddp.utils.config import TrainConfig
 from tpu_ddp.utils.metrics import MetricsLogger, from_env
-from tpu_ddp.utils.profiling import annotate, profile_trace
+from tpu_ddp.utils.profiling import profile_trace, span
 
 
 class TestMetricsLogger:
@@ -68,8 +68,15 @@ class TestProfiling:
     def test_trace_writes_files(self, tmp_path):
         d = str(tmp_path / "prof")
         with profile_trace(d):
-            with annotate("toy"):
+            with span("tpu_ddp.lm.train_step", step=7):
                 _ = jnp.sum(jnp.arange(16.0))
         import os
         found = [os.path.join(r, f) for r, _, fs in os.walk(d) for f in fs]
         assert found, "profiler produced no trace files"
+        # The span and its count come back from the written trace.
+        import jax
+        data = jax.profiler.ProfileData.from_file(
+            next(f for f in found if f.endswith(".xplane.pb")))
+        events = [e for plane in data.planes for line in plane.lines
+                  for e in line.events if e.name == "tpu_ddp.lm.train_step"]
+        assert [dict(e.stats) for e in events] == [{"step": 7}]

@@ -1,0 +1,357 @@
+"""Tracing (tpu_ddp/utils/profiling.py): the tables against the call
+sites, both ways; under a CPU profiler session tiny runs of
+``ServeEngine``, ``LMTrainer`` and ``train_epoch`` yield every span with
+its counts, nested as the tables say; with no session open the same runs
+give bit-equal tokens and losses (a span is a no-op, a name is metadata).
+"""
+
+import glob
+import os
+import re
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_ddp.models.transformer import make_transformer
+from tpu_ddp.models.vgg import VGGModel
+from tpu_ddp.parallel.mesh import make_mesh
+from tpu_ddp.serve import ServeEngine
+from tpu_ddp.train.engine import Trainer
+from tpu_ddp.train.lm import LMTrainer, make_lm_batch
+from tpu_ddp.utils import profiling
+from tpu_ddp.utils.config import TrainConfig
+from tpu_ddp.utils.profiling import (KERNELS, PROGRAMS, SCOPES, SPANS,
+                                     profile_trace, span)
+
+ROOT = Path(__file__).resolve().parents[1]
+SOURCES = sorted((ROOT / "tpu_ddp").rglob("*.py"))
+SPAN_CALL = re.compile(r"\bspan(?:ned)?\(\s*(?:[\w.]+,\s*)?([^\s,)]+)")
+SERVE = sorted(n for n in SPANS if n.startswith("tpu_ddp.serve."))
+
+
+def _calls(pattern, sources=SOURCES) -> dict:
+    """{first match group: [files]} over the program's sources."""
+    found: dict = {}
+    for path in sources:
+        text = path.read_text()
+        if path.name == "profiling.py":
+            text = text.split("\ndef span(")[0]     # the tables, not the API
+        for m in pattern.finditer(text):
+            found.setdefault(m.group(1), []).append(path.name)
+    return found
+
+
+# ---- the tables against the tree, both ways ---------------------------------
+
+def test_every_span_call_names_a_table_entry_and_every_entry_is_emitted():
+    calls = _calls(SPAN_CALL)
+    literal = {c.strip('"') for c in calls if c.startswith('"')}
+    assert set(calls) == {f'"{n}"' for n in literal}, \
+        f"span( with a name that is not a literal: {calls}"
+    assert literal == set(SPANS)
+    assert all(n.startswith(profiling.PREFIX) for n in SPANS)
+    for layer, covers, counts in SPANS.values():
+        assert layer and covers and isinstance(counts, tuple)
+
+
+def test_every_kernel_and_scope_name_is_in_its_table_and_used():
+    kernels = _calls(re.compile(r'^\s+name="(\w+)",$', re.M),
+                     sorted((ROOT / "tpu_ddp/ops/pallas").glob("*.py")))
+    assert set(kernels) == set(KERNELS)
+    assert {f for fs in kernels.values() for f in fs} == {
+        "flash_attention.py", "quant_matmul.py", "sgd.py", "bn_relu.py"}
+    pallas = sum(p.read_text().count("pl.pallas_call(")
+                 for p in (ROOT / "tpu_ddp/ops/pallas").glob("*.py"))
+    assert pallas == len(KERNELS) == 9
+    scopes = _calls(re.compile(r'jax\.named_scope\("(\w+)"\)'))
+    assert set(scopes) == set(SCOPES)
+
+
+def test_every_program_name_is_used_once_per_builder():
+    used = _calls(re.compile(r"@program\((\w+)\)"))
+    constants = {k: v for k, v in vars(profiling).items()
+                 if k.isupper() and isinstance(v, str) and v in PROGRAMS}
+    assert set(used) == set(constants)
+    assert set(constants.values()) == set(PROGRAMS)
+    with pytest.raises(KeyError):
+        profiling.program("step")
+
+
+# ---- tiny runs --------------------------------------------------------------
+
+def _lm():
+    return make_transformer("TransformerLM-tiny", max_seq_len=64,
+                            compute_dtype=jnp.float32)
+
+
+def _serve(model, params, **kw):
+    """Six requests through four slots, prompts of one to three chunks;
+    every token and log-probability, and what the scheduler's lengths
+    summed to at each decode step."""
+    engine = ServeEngine(model, params, num_slots=4, block_size=8,
+                         prefill_chunk=8, **kw)
+    rng = np.random.default_rng(3)
+    reqs = [engine.submit(rng.integers(0, 1024, size=n), 5, seed=i)
+            for i, n in enumerate((5, 12, 20, 7, 9, 17))]
+    lengths = []
+    inner = engine._run_decode_step
+
+    def recording(dslots):
+        lengths.append(sum(engine.sched.slots[i].length for i in dslots))
+        return inner(dslots)
+
+    engine._run_decode_step = recording
+    steps = engine.run()
+    return {"tokens": [list(r.tokens) for r in reqs],
+            "logprobs": [list(r.logprobs) for r in reqs],
+            "rids": [r.rid for r in reqs], "lengths": lengths,
+            "steps": steps,
+            "prompts": [int(r.prompt.size) for r in reqs]}
+
+
+def _lm_losses(model):
+    trainer = LMTrainer(model, make_mesh(jax.devices()[:1]))
+    state = trainer.init_state(seed=2)
+    rng = np.random.default_rng(4)
+    losses = []
+    for _ in range(3):
+        x, y = trainer.put_batch(
+            *make_lm_batch(rng.integers(0, 1024, size=(2, 33))))
+        state, loss = trainer.train_step(state, x, y)
+        losses.append(np.asarray(loss).tolist())
+    return losses
+
+
+def _epoch_losses(**cfg):
+    model = VGGModel(name="tiny", cfg=(8, "M", 16, "M"),
+                     compute_dtype=jnp.float32)
+    trainer = Trainer(model, TrainConfig(log_every=1, **cfg),
+                      strategy="none")
+    rng = np.random.default_rng(6)
+    batches = [(rng.normal(size=(8, 4, 4, 3)).astype(np.float32),
+                rng.integers(0, 10, size=8).astype(np.int32))
+               for _ in range(5)]
+    lines = []
+    trainer.train_epoch(trainer.init_state(), batches, log=lines.append)
+    return [ln for ln in lines if "loss" in ln]
+
+
+# name -> run(model, params): every instrumented path once
+RUNS = {
+    "serve": _serve,
+    "spec": lambda m, p: _serve(m, p, spec_k=2, spec_draft="self-1"),
+    "chain": lambda m, p: _serve(m, p, spec_k=2, spec_draft="chain"),
+    "lm": lambda m, p: _lm_losses(m),
+    "epoch": lambda m, p: _epoch_losses(),
+    "epoch_multi": lambda m, p: _epoch_losses(steps_per_dispatch=2),
+}
+
+
+def _read_events(logdir, prefix) -> list:
+    """[(name, start, end, counts)] of the host events whose name starts
+    with ``prefix``, outermost first."""
+    path = sorted(glob.glob(os.path.join(
+        logdir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines if plane.name == "/host:CPU" else ():
+            out.extend((e.name, e.start_ns, e.end_ns, dict(e.stats))
+                       for e in line.events if e.name.startswith(prefix))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+@pytest.fixture(scope="module")
+def runs(tmp_path_factory):
+    """The tiny runs twice: with no profiler session (which also pays
+    the compiles), then inside one, each under a marker span."""
+    model = _lm()
+    params = model.init(jax.random.key(0))
+    plain = {key: fn(model, params) for key, fn in RUNS.items()}
+    logdir = str(tmp_path_factory.mktemp("trace"))
+    traced = {}
+    with profile_trace(logdir):
+        for key, fn in RUNS.items():
+            with jax.profiler.TraceAnnotation(f"test.{key}"):
+                traced[key] = fn(model, params)
+    spans = _read_events(logdir, profiling.PREFIX)
+    by_run = {name[5:]: [s for s in spans if lo <= s[1] and s[2] <= hi]
+              for name, lo, hi, _ in _read_events(logdir, "test.")}
+    return {"plain": plain, "traced": traced, "spans": by_run}
+
+
+@pytest.mark.parametrize("key", sorted(RUNS))
+def test_tracing_changes_no_token_and_no_loss(runs, key):
+    assert runs["traced"][key] == runs["plain"][key]
+    assert runs["spans"][key], "the traced run recorded no span"
+
+
+@pytest.mark.parametrize("name", sorted(SPANS))
+def test_span_is_emitted_with_the_counts_of_the_table(runs, name):
+    hits = [s for spans in runs["spans"].values() for s in spans
+            if s[0] == name]
+    assert hits, f"no run emitted {name}"
+    counts = set(SPANS[name][2])
+    for _, start, end, stats in hits:
+        assert set(stats) == counts and end >= start
+        assert all(isinstance(v, (int, float)) for v in stats.values())
+
+
+def _parent(spans, child):
+    """The innermost span that encloses ``child``."""
+    at = spans.index(child)
+    around = [s for s in spans[:at]
+              if s[1] <= child[1] and child[2] <= s[2]]
+    return min(around, key=lambda s: s[2] - s[1]) if around else None
+
+
+@pytest.mark.parametrize("key", ["serve", "spec", "chain"])
+def test_serve_spans_nest_as_the_table_says(runs, key):
+    spans = runs["spans"][key]
+    want = {"tpu_ddp.serve.step": None,
+            "tpu_ddp.serve.schedule": "tpu_ddp.serve.step",
+            "tpu_ddp.serve.admit": "tpu_ddp.serve.schedule",
+            "tpu_ddp.serve.prefill": "tpu_ddp.serve.step",
+            "tpu_ddp.serve.decode": "tpu_ddp.serve.step"}
+    want.update({n: "tpu_ddp.serve.decode" for n in SERVE
+                 if n.startswith("tpu_ddp.serve.decode.")})
+    assert {s[0] for s in spans} == set(SERVE)
+    for s in spans:
+        parent = _parent(spans, s)
+        assert (parent[0] if parent else None) == want[s[0]], s
+    steps = [s for s in spans if s[0] == "tpu_ddp.serve.step"]
+    assert [s[3]["n"] for s in steps] == list(range(1, len(steps) + 1))
+    assert len(steps) == runs["traced"][key]["steps"] + 1   # the idle one
+    for s in steps:
+        kids = [c[0] for c in spans if _parent(spans, c) is s]
+        assert kids[0] == "tpu_ddp.serve.schedule"
+        assert 0 <= s[3]["live"] <= 4 and s[3]["blocks_in_use"] >= 0
+    for d in (s for s in spans if s[0] == "tpu_ddp.serve.decode"):
+        kids = [c[0] for c in spans if _parent(spans, c) is d]
+        assert kids == [f"tpu_ddp.serve.decode.{p}" for p in
+                        ("tables", "dispatch", "fetch", "emit")]
+
+
+def test_a_request_shares_its_rid_between_admit_and_prefill(runs):
+    spans, run = runs["spans"]["serve"], runs["traced"]["serve"]
+    admits = {s[3]["rid"]: s[3] for s in spans
+              if s[0] == "tpu_ddp.serve.admit"}
+    assert sorted(admits) == sorted(run["rids"])
+    for rid, prompt in zip(run["rids"], run["prompts"]):
+        chunks = [s[3] for s in spans if s[0] == "tpu_ddp.serve.prefill"
+                  and s[3]["rid"] == rid]
+        assert admits[rid]["prompt_tokens"] == prompt
+        assert admits[rid]["cached_tokens"] == 0
+        assert admits[rid]["waited_ms"] >= 0
+        assert sum(c["tokens"] for c in chunks) == prompt   # not padded
+        assert [c["start"] for c in chunks] == list(range(0, prompt, 8))
+        assert [c["final"] for c in chunks] == [0] * (len(chunks) - 1) + [1]
+    # six requests on four slots: the last two waited in the queue
+    assert max(s[3]["queue"] for s in spans
+               if s[0] == "tpu_ddp.serve.step") == 6
+
+
+def test_context_tokens_are_the_schedulers_own_lengths(runs):
+    spans, run = runs["spans"]["serve"], runs["traced"]["serve"]
+    decodes = [s[3] for s in spans if s[0] == "tpu_ddp.serve.decode"]
+    assert [d["context_tokens"] for d in decodes] == run["lengths"]
+    assert all(1 <= d["slots"] <= 4 for d in decodes)
+
+
+def test_trainer_spans_carry_their_steps(runs):
+    lm = runs["spans"]["lm"]
+    assert [s[0] for s in lm] == ["tpu_ddp.lm.put_batch",
+                                  "tpu_ddp.lm.train_step"] * 3
+    assert [s[3] for s in lm[1::2]] == [{"step": i} for i in range(3)]
+    assert all(s[3] == {"tokens": 64} for s in lm[0::2])
+    for key, dispatches in (("epoch", 5), ("epoch_multi", 3)):
+        spans = runs["spans"][key]
+        names = [s[0] for s in spans]
+        assert names.count("tpu_ddp.train.data_next") == 6   # 5 + the end
+        assert names.count("tpu_ddp.train.dispatch") == dispatches
+        assert names.count("tpu_ddp.train.harvest") == dispatches
+        assert names.count("tpu_ddp.train.put_batch") == dispatches
+    single = [s[3] for s in runs["spans"]["epoch"]
+              if s[0] == "tpu_ddp.train.dispatch"]
+    assert single == [{"it": i, "step": i} for i in range(5)]
+
+
+# ---- program names reach the compiled programs ------------------------------
+
+def _lowered():
+    model = _lm()
+    params = model.init(jax.random.key(0))
+    geo = dict(num_slots=4, block_size=8, prefill_chunk=8)
+    engine = ServeEngine(model, params, **geo)
+    spec = ServeEngine(model, params, spec_k=2, spec_draft="self-1", **geo)
+    tiered = ServeEngine(model, params, kv_tiers=2, hbm_blocks=9, **geo)
+    lm = LMTrainer(model, make_mesh(jax.devices()[:1]))
+    state = lm.init_state()
+    x, y = lm.put_batch(*make_lm_batch(np.zeros((2, 33), np.int64)))
+    vgg = Trainer(VGGModel(name="tiny", cfg=(8, "M", 16, "M"),
+                           compute_dtype=jnp.float32),
+                  TrainConfig(), strategy="none")
+    batch = vgg.put_batch(np.zeros((4, 4, 4, 3), np.float32),
+                          np.zeros(4, np.int32))
+    return {
+        profiling.SERVE_DECODE: engine.lower_decode_step,
+        profiling.SERVE_PREFILL: engine.lower_prefill_step,
+        profiling.SERVE_SPEC: spec.lower_spec_step,
+        profiling.SERVE_DECODE_TIERED: tiered.lower_tiered_decode_step,
+        profiling.SERVE_PREFILL_TIERED: tiered.lower_tiered_prefill_step,
+        profiling.LM_TRAIN_STEP: lambda: lm.lower_train_step(state, x, y),
+        profiling.DDP_TRAIN_STEP:
+        lambda: vgg.lower_train_step(vgg.init_state(), *batch),
+        profiling.DDP_EVAL_STEP:
+        lambda: vgg._eval_step.lower(vgg.init_state().params, *batch[:2]),
+    }
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    return _lowered()
+
+
+@pytest.mark.parametrize("name", [
+    profiling.SERVE_DECODE, profiling.SERVE_PREFILL, profiling.SERVE_SPEC,
+    profiling.SERVE_DECODE_TIERED, profiling.SERVE_PREFILL_TIERED,
+    profiling.LM_TRAIN_STEP, profiling.DDP_TRAIN_STEP,
+    profiling.DDP_EVAL_STEP])
+def test_lowered_program_carries_its_name(lowered, name):
+    text = lowered[name]().as_text()
+    assert re.search(rf"module @jit_{name}\b", text), text[:200]
+
+
+def test_scopes_reach_the_lowered_decode_step(lowered):
+    text = lowered[profiling.SERVE_DECODE]().as_text(debug_info=True)
+    for scope in ("embed", "attn/kv_write", "attn/kv_gather", "mlp", "head",
+                  "sample"):
+        assert f"jit({profiling.SERVE_DECODE})/{scope}/" in text, scope
+    text = lowered[profiling.LM_TRAIN_STEP]().as_text(debug_info=True)
+    for scope in ("loss", "optimizer", "grad_sync"):
+        assert re.search(rf"[/(]{scope}[/)]", text), scope
+
+
+# ---- the operator's page ----------------------------------------------------
+
+def test_design_page_prints_the_tables():
+    page = (ROOT / "docs" / "DESIGN.md").read_text()
+    section = page.split("Tracing", 1)[1]
+    for name in (*SPANS, *PROGRAMS, *KERNELS, *SCOPES,
+                 "TPU_DDP_PROFILE_DIR", "--trace 1", "Perfetto"):
+        assert f"`{name}`" in section or name in section, name
+    for name, (_, _, counts) in SPANS.items():
+        row = next(ln for ln in section.splitlines()
+                   if ln.startswith(f"| `{name}`"))
+        for count in counts:
+            assert f"`{count}`" in row, (name, count)
+    assert "Tracing" in (ROOT / "README.md").read_text()
+
+
+def test_span_outside_a_session_is_a_noop():
+    with span("tpu_ddp.serve.step", n=1, queue=0, live=0, blocks_in_use=0):
+        pass
+    assert list(profiling.spanned([1, 2, 3], "tpu_ddp.train.data_next")) \
+        == [1, 2, 3]

@@ -29,6 +29,7 @@ from tpu_ddp.publish import (
 )
 from tpu_ddp.publish.subscriber import _APPLY
 from tpu_ddp.serve import ServeEngine
+from tpu_ddp.utils.profiling import SERVE_DECODE, SERVE_PREFILL
 
 GEOM = dict(num_slots=4, block_size=8, prefill_chunk=8)
 
@@ -186,8 +187,8 @@ class TestAtomicSwap:
         r = eng.submit([1, 2, 3], 2)
         eng.run()
         # Steady state: further version flips reuse every executable.
-        with no_retrace(0, watch=("push_pack", "apply_delta", "step",
-                                  "prefill")):
+        with no_retrace(0, watch=("push_pack", "apply_delta",
+                                  SERVE_DECODE, SERVE_PREFILL)):
             p = _perturb(params, 0.02)
             for step in range(2, 5):
                 pub.publish(params=p, step=step)

@@ -78,6 +78,7 @@ from tpu_ddp.serve.scheduler import (
     tenant_of,
 )
 from tpu_ddp.utils.metrics import MetricsLogger
+from tpu_ddp.utils.profiling import SERVE_ADOPT_DECODE, program
 
 
 @functools.lru_cache(maxsize=32)
@@ -90,6 +91,7 @@ def _build_adopt_decode_step(model, block_size: int,
     decode computes and nothing heavy depends on it — the dataflow
     freedom ``update_overlap_report`` verifies."""
 
+    @program(SERVE_ADOPT_DECODE)
     def step(params, pool_k, pool_v, adopt_ids, adopt_k, adopt_v,
              tables, lengths, last_tokens, temps, seeds):
         pool_k = pool_k.at[:, adopt_ids].set(

@@ -120,11 +120,13 @@ def block_finish(model, blk, x, o):
     from tpu_ddp.ops.quant import qdot
     cd = model.compute_dtype
     b, L = x.shape[0], x.shape[1]
-    o = qdot(o.reshape(b, L, -1), blk["wo"], cd,
-             reshape=(-1, model.d_model)).astype(cd)
-    x = x + o
-    y = layer_norm(x, blk["ln2"]["scale"], blk["ln2"]["bias"])
-    return x + mlp(model, blk, y)
+    with jax.named_scope("attn"):
+        o = qdot(o.reshape(b, L, -1), blk["wo"], cd,
+                 reshape=(-1, model.d_model)).astype(cd)
+        x = x + o
+    with jax.named_scope("mlp"):
+        y = layer_norm(x, blk["ln2"]["scale"], blk["ln2"]["bias"])
+        return x + mlp(model, blk, y)
 
 
 def forward_cached(model, params, tokens, caches, start: int):
@@ -137,15 +139,17 @@ def forward_cached(model, params, tokens, caches, start: int):
     cd = model.compute_dtype
     b, L = tokens.shape
     pos = start + jnp.arange(L)
-    x = params["embed"][tokens].astype(cd)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cd)
     new_caches = []
     for blk, (ck, cv) in zip(params["blocks"], caches):
-        q, k, v = project_qkv(model, blk, x, pos)
-        ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                      (0, start, 0, 0))
-        cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                      (0, start, 0, 0))
-        o = attend_cached(model, q, ck, cv, pos)
+        with jax.named_scope("attn"):
+            q, k, v = project_qkv(model, blk, x, pos)
+            ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype),
+                                          (0, start, 0, 0))
+            cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype),
+                                          (0, start, 0, 0))
+            o = attend_cached(model, q, ck, cv, pos)
         x = block_finish(model, blk, x, o)
         new_caches.append((ck, cv))
     logits = model.head_apply(params, x[:, -1:])[:, 0]

@@ -342,8 +342,9 @@ class TransformerLM:
         tree (decode_quant, ops/quant.py) runs the fused weight-only
         matmul; a plain fp tree traces the identical dot."""
         from tpu_ddp.ops.quant import qdot
-        logits = qdot(x, params["head"], self.compute_dtype)
-        return logits.astype(jnp.float32)
+        with jax.named_scope("head"):
+            logits = qdot(x, params["head"], self.compute_dtype)
+            return logits.astype(jnp.float32)
 
     def trunk_with_aux(self, params, tokens, rng=None, stats=None):
         """Everything but the vocabulary projection: embed -> blocks ->
@@ -363,9 +364,11 @@ class TransformerLM:
         lc = tokens.shape[1]
         self.check_seq_len(lc)
         pos = self._positions(lc)
-        x = params["embed"][tokens].astype(cd)
-        if rng is not None:
-            x = self._dropout(x, jax.random.fold_in(rng, self.num_layers))
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens].astype(cd)
+            if rng is not None:
+                x = self._dropout(
+                    x, jax.random.fold_in(rng, self.num_layers))
         aux = jnp.float32(0.0)
         from tpu_ddp.memory import cast_saved, effective_remat, wrap_stage
         remat = effective_remat(self.remat_policy, "attn")
@@ -381,7 +384,9 @@ class TransformerLM:
             r = jax.random.fold_in(rng, i) if rng is not None else None
             x, a = blk_fn(blk, cast_saved(x, self.act_dtype, cd), pos, r)
             aux = aux + a
-        x = layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
+        with jax.named_scope("head"):
+            x = layer_norm(x, params["ln_f"]["scale"],
+                           params["ln_f"]["bias"])
         return x, aux / max(self.num_layers, 1)
 
     def block_apply(self, blk, x, pos):
@@ -445,41 +450,44 @@ class TransformerLM:
             # it IDENTICAL across mp shards — the residual stream is
             # replicated over tp, so its mask must be too).
             r1, r2 = jax.random.split(rng)
-        y = layer_norm(x, blk["ln1"]["scale"], blk["ln1"]["bias"])
-        # Under GQA k/v stay at KV-head width end to end: every attend()
-        # path contracts grouped — ring/blockwise/full in jnp, and the
-        # flash kernel indexes K/V blocks by q-head group natively — so
-        # collectives, memory and score math all carry KV-width bytes.
-        q, k, v = self.qkv_proj(blk, self._tp_in(y), pos)
-        o = attend(q, k, v, causal=True, axis_name=self.sp_axis,
-                   axis_size=self.sp_size, flash=self.use_flash,
-                   mode=self.sp_mode)
-        # Row-parallel output projection: partial sums psum'd over tp.
-        wo = blk["wo"].astype(cd).reshape(h_loc * hd, self.d_model)
-        o = self._tp_out(jnp.dot(
-            o.reshape(b, lc, h_loc * hd), wo,
-            preferred_element_type=jnp.float32)).astype(cd)
-        x = x + self._dropout(o, r1)
-        y = layer_norm(x, blk["ln2"]["scale"], blk["ln2"]["bias"])
-        if self.moe_experts:
-            from tpu_ddp.parallel.moe import moe_mlp
-            y, aux = moe_mlp(
-                y, blk["router"], blk["w1"], blk["w2"],
-                num_experts=self.moe_experts,
-                capacity_factor=self.moe_capacity_factor,
-                top_k=self.moe_top_k,
-                ep_axis=self.ep_axis or "ep", ep_size=self._ep,
-                tp_in=self._tp_in, tp_out=self._tp_out, stats=stats)
-            return x + self._dropout(y, r2), aux
-        # Column-parallel up-projection (local d_ff slice) ...
-        y = jnp.dot(self._tp_in(y), blk["w1"].astype(cd),
-                    preferred_element_type=jnp.float32)
-        y = jax.nn.gelu(y.astype(jnp.float32)).astype(cd)
-        # ... row-parallel down-projection, psum'd.
-        y = self._tp_out(jnp.dot(
-            y, blk["w2"].astype(cd),
-            preferred_element_type=jnp.float32)).astype(cd)
-        return x + self._dropout(y, r2), jnp.float32(0.0)
+        with jax.named_scope("attn"):
+            y = layer_norm(x, blk["ln1"]["scale"], blk["ln1"]["bias"])
+            # Under GQA k/v stay at KV-head width end to end: every
+            # attend() path contracts grouped — ring/blockwise/full in
+            # jnp, and the flash kernel indexes K/V blocks by q-head
+            # group natively — so collectives, memory and score math all
+            # carry KV-width bytes.
+            q, k, v = self.qkv_proj(blk, self._tp_in(y), pos)
+            o = attend(q, k, v, causal=True, axis_name=self.sp_axis,
+                       axis_size=self.sp_size, flash=self.use_flash,
+                       mode=self.sp_mode)
+            # Row-parallel output projection: partial sums psum'd over tp.
+            wo = blk["wo"].astype(cd).reshape(h_loc * hd, self.d_model)
+            o = self._tp_out(jnp.dot(
+                o.reshape(b, lc, h_loc * hd), wo,
+                preferred_element_type=jnp.float32)).astype(cd)
+            x = x + self._dropout(o, r1)
+        with jax.named_scope("mlp"):
+            y = layer_norm(x, blk["ln2"]["scale"], blk["ln2"]["bias"])
+            if self.moe_experts:
+                from tpu_ddp.parallel.moe import moe_mlp
+                y, aux = moe_mlp(
+                    y, blk["router"], blk["w1"], blk["w2"],
+                    num_experts=self.moe_experts,
+                    capacity_factor=self.moe_capacity_factor,
+                    top_k=self.moe_top_k,
+                    ep_axis=self.ep_axis or "ep", ep_size=self._ep,
+                    tp_in=self._tp_in, tp_out=self._tp_out, stats=stats)
+                return x + self._dropout(y, r2), aux
+            # Column-parallel up-projection (local d_ff slice) ...
+            y = jnp.dot(self._tp_in(y), blk["w1"].astype(cd),
+                        preferred_element_type=jnp.float32)
+            y = jax.nn.gelu(y.astype(jnp.float32)).astype(cd)
+            # ... row-parallel down-projection, psum'd.
+            y = self._tp_out(jnp.dot(
+                y, blk["w2"].astype(cd),
+                preferred_element_type=jnp.float32)).astype(cd)
+            return x + self._dropout(y, r2), jnp.float32(0.0)
 
     def route_stats(self, params, tokens):
         """Diagnostic routing-health probe: one deterministic trunk
@@ -499,7 +507,9 @@ class TransformerLM:
 
     def head_apply(self, params, x):
         """Final LayerNorm + LM head: (B, L, dm) -> (B, L, V) float32."""
-        x = layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
+        with jax.named_scope("head"):
+            x = layer_norm(x, params["ln_f"]["scale"],
+                           params["ln_f"]["bias"])
         return self.project(params, x)
 
     def num_params(self, params=None, key=None) -> int:

@@ -142,6 +142,7 @@ def _bn_relu_fwd_impl(x2d, scale, bias, *, eps, interpret):
         in_specs=[_row_spec(br, lanes)],
         out_specs=(_chan_spec(lanes), _chan_spec(lanes)),
         out_shape=(chan, chan),
+        name="bn_relu_stats",
         interpret=interpret,
     )(xf)
     mean = _combine_chan(s, k, c) / r                      # (1, C)
@@ -154,6 +155,7 @@ def _bn_relu_fwd_impl(x2d, scale, bias, *, eps, interpret):
         in_specs=[_row_spec(br, lanes)] + [_chan_spec(lanes)] * 4,
         out_specs=_row_spec(br, lanes),
         out_shape=jax.ShapeDtypeStruct(xf.shape, jnp.float32),
+        name="bn_relu_apply",
         interpret=interpret,
     )(xf, _fold_chan(mean, k, c_pad), _fold_chan(inv, k, c_pad),
       _fold_chan(scale.reshape(1, c), k, c_pad),
@@ -210,6 +212,7 @@ def _bn_relu_bwd_impl(x2d, g2d, mean, inv, scale, bias, *, interpret):
         in_specs=[_row_spec(br, lanes)] * 2 + [_chan_spec(lanes)] * 4,
         out_specs=(_chan_spec(lanes), _chan_spec(lanes)),
         out_shape=(chan, chan),
+        name="bn_relu_bwd_reduce",
         interpret=interpret,
     )(xf, gf, mean_f, inv_f, scale_f, bias_f)
     dbias = _combine_chan(db_f, k, c)                      # (1, C)
@@ -221,6 +224,7 @@ def _bn_relu_bwd_impl(x2d, g2d, mean, inv, scale, bias, *, interpret):
         in_specs=[_row_spec(br, lanes)] * 2 + [_chan_spec(lanes)] * 6,
         out_specs=_row_spec(br, lanes),
         out_shape=jax.ShapeDtypeStruct(xf.shape, jnp.float32),
+        name="bn_relu_bwd_dx",
         interpret=interpret,
     )(xf, gf, mean_f, inv_f, scale_f, bias_f,
       _fold_chan(dbias, k, c_pad), _fold_chan(dscale, k, c_pad))

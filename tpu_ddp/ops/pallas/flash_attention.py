@@ -204,6 +204,7 @@ def _fwd_impl(q3, k3, v3, *, scale, seq_len, causal, n_heads, n_kv,
         scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32),
                         pltpu.VMEM((bq, 128), jnp.float32),
                         pltpu.VMEM((bq, dp), jnp.float32)],
+        name="flash_fwd",
         interpret=interpret,
     )(q3, k3, v3)
     return o, lse
@@ -341,6 +342,7 @@ def _bwd_impl(q3, k3, v3, o3, lse, do3, *, scale, seq_len, causal,
         out_shape=(jax.ShapeDtypeStruct(k3.shape, k3.dtype),
                    jax.ShapeDtypeStruct(v3.shape, v3.dtype)),
         scratch_shapes=[pltpu.VMEM((bk, dp), jnp.float32)] * 2,
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(q3, k3, v3, do3, lse, delta)
 
@@ -363,6 +365,7 @@ def _bwd_impl(q3, k3, v3, o3, lse, do3, *, scale, seq_len, causal,
         out_specs=block3("outer", bq),
         out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
         scratch_shapes=[pltpu.VMEM((bq, dp), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q3, k3, v3, do3, lse, delta)
     return dq, dk, dv

@@ -66,6 +66,7 @@ def _qmm(x2d, q, s2d, *, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        name="quant_matmul",
         interpret=interpret,
     )(x2d, q, s2d)
 

@@ -61,6 +61,7 @@ def _sgd_leaf(p2d, g2d, b2d, *, lr, momentum, weight_decay, interpret):
         out_specs=(spec, spec),
         out_shape=(out_shape, out_shape),
         input_output_aliases={0: 0, 2: 1},
+        name="fused_sgd",
         interpret=interpret,
     )(p2d, g2d, b2d)
 

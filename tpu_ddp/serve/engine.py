@@ -70,6 +70,12 @@ from tpu_ddp.serve.scheduler import (
     tenant_of,
 )
 from tpu_ddp.utils.metrics import MetricsLogger
+from tpu_ddp.utils.profiling import (
+    SERVE_DECODE,
+    SERVE_PREFILL,
+    program,
+    span,
+)
 
 
 @dataclasses.dataclass
@@ -122,6 +128,22 @@ class Request:
         return self.first_token_at - self.submitted_at
 
 
+def paged_kv(pool_k, pool_v, li: int, bidx, off, k, v, tables, view):
+    """Layer ``li``'s half of the paged cache: scatter the new ``k`` /
+    ``v`` rows to ``(bidx, off)`` (scope ``kv_write``), then gather the
+    layer's pool through ``tables`` into the contiguous ``view + (KV,
+    hd)`` the decode core attends over (scope ``kv_gather``). Shared by
+    every step program over the single-tier pool, so a trace names the
+    two halves the same way in all of them."""
+    with jax.named_scope("kv_write"):
+        pool_k = pool_k.at[li, bidx, off].set(k.astype(pool_k.dtype))
+        pool_v = pool_v.at[li, bidx, off].set(v.astype(pool_v.dtype))
+    with jax.named_scope("kv_gather"):
+        ck = pool_k[li][tables].reshape(view + pool_k.shape[3:])
+        cv = pool_v[li][tables].reshape(view + pool_v.shape[3:])
+    return pool_k, pool_v, ck, cv
+
+
 def decode_bank(model, block_size: int, blocks_per_seq: int, params,
                 pool_k, pool_v, tables, lengths, last_tokens, temps,
                 seeds):
@@ -133,33 +155,33 @@ def decode_bank(model, block_size: int, blocks_per_seq: int, params,
     there being exactly ONE implementation of this body."""
     S = tables.shape[0]
     cd = model.compute_dtype
-    x = params["embed"][last_tokens[:, None]].astype(cd)  # (S, 1, dm)
+    with jax.named_scope("embed"):
+        x = params["embed"][last_tokens[:, None]].astype(cd)  # (S, 1, dm)
     pos = lengths[:, None]                                # (S, 1)
     bidx = jnp.take_along_axis(
         tables, (lengths // block_size)[:, None], axis=1)[:, 0]
     off = lengths % block_size
     for li, blk in enumerate(params["blocks"]):
-        q, k, v = project_qkv(model, blk, x, pos)
-        pool_k = pool_k.at[li, bidx, off].set(
-            k[:, 0].astype(pool_k.dtype))
-        pool_v = pool_v.at[li, bidx, off].set(
-            v[:, 0].astype(pool_v.dtype))
-        view = (S, blocks_per_seq * block_size) + pool_k.shape[3:]
-        ck = pool_k[li][tables].reshape(view)
-        cv = pool_v[li][tables].reshape(view)
-        o = attend_cached(model, q, ck, cv, pos)
+        with jax.named_scope("attn"):
+            q, k, v = project_qkv(model, blk, x, pos)
+            pool_k, pool_v, ck, cv = paged_kv(
+                pool_k, pool_v, li, bidx, off, k[:, 0], v[:, 0], tables,
+                (S, blocks_per_seq * block_size))
+            o = attend_cached(model, q, ck, cv, pos)
         x = block_finish(model, blk, x, o)
     logits = model.head_apply(params, x)[:, 0]            # (S, V)
-    toks, lps = jax.vmap(
-        lambda lg, t, sd, p: sample_token(model, lg, t, sd, p))(
-            logits, temps, seeds, lengths + 1)
-    # In-graph non-finite detection, the decode analog of StepGuard's
-    # gradient check: a slot whose logits went NaN/Inf (poisoned KV
-    # pages, numerical blow-up) is flagged so the host quarantines
-    # exactly that request — never the whole bank. Checking logits
-    # (not just the sampled logprob) catches an isolated Inf the
-    # sampled position might miss.
-    bad = ~(jnp.all(jnp.isfinite(logits), axis=-1) & jnp.isfinite(lps))
+    with jax.named_scope("sample"):
+        toks, lps = jax.vmap(
+            lambda lg, t, sd, p: sample_token(model, lg, t, sd, p))(
+                logits, temps, seeds, lengths + 1)
+        # In-graph non-finite detection, the decode analog of
+        # StepGuard's gradient check: a slot whose logits went NaN/Inf
+        # (poisoned KV pages, numerical blow-up) is flagged so the host
+        # quarantines exactly that request — never the whole bank.
+        # Checking logits (not just the sampled logprob) catches an
+        # isolated Inf the sampled position might miss.
+        bad = ~(jnp.all(jnp.isfinite(logits), axis=-1)
+                & jnp.isfinite(lps))
     return pool_k, pool_v, toks, lps, bad
 
 
@@ -174,6 +196,7 @@ def _build_decode_step(model, block_size: int, blocks_per_seq: int):
     ``lengths`` (S,) cache positions written so far, ``last_tokens``
     (S,) the pending token each slot feeds at position ``lengths``."""
 
+    @program(SERVE_DECODE)
     def step(params, pool_k, pool_v, tables, lengths, last_tokens,
              temps, seeds):
         return decode_bank(model, block_size, blocks_per_seq, params,
@@ -193,6 +216,7 @@ def _build_prefill_step(model, block_size: int, blocks_per_seq: int):
     chunk (the one containing position ``prompt_len - 1``); earlier
     chunks compute and discard it so every chunk is ONE program."""
 
+    @program(SERVE_PREFILL)
     def step(params, pool_k, pool_v, table, tokens, start, prompt_len,
              temp, seed):
         cd = model.compute_dtype
@@ -202,22 +226,21 @@ def _build_prefill_step(model, block_size: int, blocks_per_seq: int):
         safe = jnp.clip(p // block_size, 0, blocks_per_seq - 1)
         blk_idx = jnp.where(valid, table[safe], PagedKVPool.NULL_BLOCK)
         off = p % block_size
-        x = params["embed"][tokens].astype(cd)                # (1, C, dm)
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens].astype(cd)            # (1, C, dm)
         for li, blkp in enumerate(params["blocks"]):
-            q, k, v = project_qkv(model, blkp, x, p)
-            pool_k = pool_k.at[li, blk_idx, off].set(
-                k[0].astype(pool_k.dtype))
-            pool_v = pool_v.at[li, blk_idx, off].set(
-                v[0].astype(pool_v.dtype))
-            view = (1, blocks_per_seq * block_size) + pool_k.shape[3:]
-            ck = pool_k[li][table].reshape(view)
-            cv = pool_v[li][table].reshape(view)
-            o = attend_cached(model, q, ck, cv, p)
+            with jax.named_scope("attn"):
+                q, k, v = project_qkv(model, blkp, x, p)
+                pool_k, pool_v, ck, cv = paged_kv(
+                    pool_k, pool_v, li, blk_idx, off, k[0], v[0], table,
+                    (1, blocks_per_seq * block_size))
+                o = attend_cached(model, q, ck, cv, p)
             x = block_finish(model, blkp, x, o)
         logits = model.head_apply(params, x)[0]               # (C, V)
         last = jnp.clip(prompt_len - 1 - start, 0, C - 1)
-        tok, lp = sample_token(model, logits[last], temp, seed,
-                               prompt_len)
+        with jax.named_scope("sample"):
+            tok, lp = sample_token(model, logits[last], temp, seed,
+                                   prompt_len)
         return pool_k, pool_v, tok, lp
 
     return jax.jit(step, donate_argnums=(1, 2))
@@ -712,39 +735,66 @@ class ServeEngine:
         at a fraction of its width. Matching the budgets keeps bank
         occupancy at its k=0 level."""
         self._step_n += 1
-        if self.chaos is not None:
-            # May raise ReplicaCrashError — BEFORE any state mutation,
-            # so a router-harvested engine is always consistent.
-            self.chaos.replica_step(self._step_n)
-        if self.subscriber is not None:
-            # Weight streaming: stage at most one delta bucket, flip
-            # the version when an update completes — BETWEEN steps, so
-            # a flip is atomic at token granularity (the token this
-            # step samples is entirely on the flipped-to version).
-            self.subscriber.on_engine_step()
-        self._shed_expired()
-        admitted = self.sched.admit()
-        for _ in admitted:
-            self.metrics.inc("serve_admitted")
+        with span("tpu_ddp.serve.step", n=self._step_n,
+                  queue=len(self.sched.queue), live=self.sched.live,
+                  blocks_in_use=(self.pool.total_usable
+                                 - self.pool.free_count)):
+            return self._step()
+
+    def _step(self) -> bool:
+        with span("tpu_ddp.serve.schedule"):
+            if self.chaos is not None:
+                # May raise ReplicaCrashError — BEFORE any state
+                # mutation, so a router-harvested engine is always
+                # consistent.
+                self.chaos.replica_step(self._step_n)
+            if self.subscriber is not None:
+                # Weight streaming: stage at most one delta bucket, flip
+                # the version when an update completes — BETWEEN steps,
+                # so a flip is atomic at token granularity (the token
+                # this step samples is entirely on the flipped-to
+                # version).
+                self.subscriber.on_engine_step()
+            self._shed_expired()
+            admitted = self.sched.admit()
+            now = time.perf_counter()
+            for i in admitted:
+                self.metrics.inc("serve_admitted")
+                s = self.sched.slots[i]
+                # A marker, not a region: a span's counts are fixed
+                # when it opens, so what was admitted hangs under
+                # ``schedule`` as its children.
+                with span("tpu_ddp.serve.admit", rid=s.request.rid,
+                          waited_ms=(now - s.request.submitted_at) * 1e3,
+                          prompt_tokens=int(s.request.prompt.size),
+                          cached_tokens=s.prefill_done):
+                    pass
+            pi = self.sched.prefill_slot()
         did = False
 
         budget = self.spec_k + 1 if self.spec_k > 0 else 1
-        for _ in range(budget):
-            pi = self.sched.prefill_slot()
+        for chunk in range(budget):
+            if chunk:
+                with span("tpu_ddp.serve.schedule"):
+                    pi = self.sched.prefill_slot()
             if pi is None:
                 break
             did = True
             self._run_prefill_chunk(pi)
 
-        dslots = self.sched.decode_slots()
+        with span("tpu_ddp.serve.schedule"):
+            dslots = self.sched.decode_slots()
         if dslots:
             did = True
-            if self.spec_k > 0 and self._spec_kind == "chain":
-                self._run_chain_step(dslots)
-            elif self.spec_k > 0:
-                self._run_spec_step(dslots)
-            else:
-                self._run_decode_step(dslots)
+            with span("tpu_ddp.serve.decode", slots=len(dslots),
+                      context_tokens=sum(self.sched.slots[i].length
+                                         for i in dslots)):
+                if self.spec_k > 0 and self._spec_kind == "chain":
+                    self._run_chain_step(dslots)
+                elif self.spec_k > 0:
+                    self._run_spec_step(dslots)
+                else:
+                    self._run_decode_step(dslots)
 
         self.metrics.observe("serve_queue_depth",
                              len(self.sched.queue))
@@ -850,8 +900,16 @@ class ServeEngine:
 
     def _run_prefill_chunk(self, pi: int) -> None:
         s = self.sched.slots[pi]
+        start = s.prefill_done
+        end = min(start + self.prefill_chunk, int(s.request.prompt.size))
+        with span("tpu_ddp.serve.prefill", rid=s.request.rid,
+                  tokens=end - start, start=start,
+                  final=int(end >= s.request.prompt.size)):
+            self._prefill_chunk(pi, s, start)
+
+    def _prefill_chunk(self, pi: int, s, start: int) -> None:
         req = s.request
-        start, C = s.prefill_done, self.prefill_chunk
+        C = self.prefill_chunk
         chunk = np.zeros((1, C), np.int32)
         piece = req.prompt[start:start + C]
         chunk[0, :piece.size] = piece
@@ -918,63 +976,70 @@ class ServeEngine:
 
     def _run_decode_step(self, dslots: list[int]) -> None:
         S, BPS = self.num_slots, self.blocks_per_seq
-        tables = np.zeros((S, BPS), np.int32)
-        lengths = np.zeros(S, np.int32)
-        last = np.zeros(S, np.int32)
-        temps = np.zeros(S, np.float32)
-        seeds = np.zeros(S, np.int32)
         tiered = self.pool.tiers > 1
-        if tiered:
-            # Residency before tables: poison first (its promote may
-            # shuffle tiers), then the whole read set on device, then
-            # every slot's write-frontier block hot — one batched call
-            # so no frontier evicts another.
-            self._maybe_poison(dslots)
-            allblocks, frontiers = [], []
-            for i in dslots:
-                self.sched.ensure_block(i)
-                s = self.sched.slots[i]
-                allblocks.extend(s.blocks)
-                frontiers.append(s.blocks[s.length // self.block_size])
-            self.pool.ensure_device(allblocks)
-            self.pool.ensure_hot(frontiers, keep=allblocks)
-            cold_tables = np.zeros((S, BPS), np.int32)
-        for i in dslots:
-            if not tiered:
-                self.sched.ensure_block(i)
-            s = self.sched.slots[i]
+        with span("tpu_ddp.serve.decode.tables"):
+            tables = np.zeros((S, BPS), np.int32)
+            lengths = np.zeros(S, np.int32)
+            last = np.zeros(S, np.int32)
+            temps = np.zeros(S, np.float32)
+            seeds = np.zeros(S, np.int32)
             if tiered:
-                tables[i], cold_tables[i] = self.pool.slot_tables(
-                    s.blocks, BPS)
+                # Residency before tables: poison first (its promote
+                # may shuffle tiers), then the whole read set on device,
+                # then every slot's write-frontier block hot — one
+                # batched call so no frontier evicts another.
+                self._maybe_poison(dslots)
+                allblocks, frontiers = [], []
+                for i in dslots:
+                    self.sched.ensure_block(i)
+                    s = self.sched.slots[i]
+                    allblocks.extend(s.blocks)
+                    frontiers.append(
+                        s.blocks[s.length // self.block_size])
+                self.pool.ensure_device(allblocks)
+                self.pool.ensure_hot(frontiers, keep=allblocks)
+                cold_tables = np.zeros((S, BPS), np.int32)
+            for i in dslots:
+                if not tiered:
+                    self.sched.ensure_block(i)
+                s = self.sched.slots[i]
+                if tiered:
+                    tables[i], cold_tables[i] = self.pool.slot_tables(
+                        s.blocks, BPS)
+                else:
+                    tables[i] = self._table_for(s)
+                lengths[i] = s.length
+                last[i] = s.pending_token
+                temps[i] = s.request.temperature
+                seeds[i] = s.request.seed
+            if not tiered:
+                self._maybe_poison(dslots)
+        with span("tpu_ddp.serve.decode.dispatch"):
+            if tiered:
+                k, v, toks, lps, bad = self._tiered_decode(
+                    self._decode_params, self.pool.k, self.pool.v,
+                    self.pool.cold_k, self.pool.cold_v,
+                    self.pool.cold_sk, self.pool.cold_sv,
+                    jnp.asarray(tables), jnp.asarray(cold_tables),
+                    jnp.asarray(lengths), jnp.asarray(last),
+                    jnp.asarray(temps), jnp.asarray(seeds))
             else:
-                tables[i] = self._table_for(s)
-            lengths[i] = s.length
-            last[i] = s.pending_token
-            temps[i] = s.request.temperature
-            seeds[i] = s.request.seed
-        if tiered:
-            k, v, toks, lps, bad = self._tiered_decode(
-                self._decode_params, self.pool.k, self.pool.v,
-                self.pool.cold_k, self.pool.cold_v,
-                self.pool.cold_sk, self.pool.cold_sv,
-                jnp.asarray(tables), jnp.asarray(cold_tables),
-                jnp.asarray(lengths), jnp.asarray(last),
-                jnp.asarray(temps), jnp.asarray(seeds))
-        else:
-            self._maybe_poison(dslots)
-            k, v, toks, lps, bad = self._decode(
-                self._decode_params, self.pool.k, self.pool.v,
-                jnp.asarray(tables), jnp.asarray(lengths),
-                jnp.asarray(last), jnp.asarray(temps),
-                jnp.asarray(seeds))
-        self.pool.commit(k, v)
-        toks, lps, bad = np.asarray(toks), np.asarray(lps), np.asarray(bad)
-        for i in dslots:
-            if bad[i]:
-                self._quarantine(i)
-                continue
-            self.sched.slots[i].length += 1
-            self._emit(i, int(toks[i]), float(lps[i]))
+                k, v, toks, lps, bad = self._decode(
+                    self._decode_params, self.pool.k, self.pool.v,
+                    jnp.asarray(tables), jnp.asarray(lengths),
+                    jnp.asarray(last), jnp.asarray(temps),
+                    jnp.asarray(seeds))
+            self.pool.commit(k, v)
+        with span("tpu_ddp.serve.decode.fetch"):
+            toks, lps, bad = (np.asarray(toks), np.asarray(lps),
+                              np.asarray(bad))
+        with span("tpu_ddp.serve.decode.emit"):
+            for i in dslots:
+                if bad[i]:
+                    self._quarantine(i)
+                    continue
+                self.sched.slots[i].length += 1
+                self._emit(i, int(toks[i]), float(lps[i]))
 
     def _run_chain_step(self, dslots: list[int]) -> None:
         """The "chain" speculative schedule (spec_draft="chain"): one
@@ -1010,6 +1075,21 @@ class ServeEngine:
         counts each emitted non-first column as an accepted proposal
         (rejected on the quarantine column), so
         ``proposed == accepted + rejected`` stays exact."""
+        with span("tpu_ddp.serve.decode.tables"):
+            *inputs, active = self._chain_tables(dslots)
+        with span("tpu_ddp.serve.decode.dispatch"):
+            cols = self._chain_dispatch(dslots, *inputs, active)
+        with span("tpu_ddp.serve.decode.fetch"):
+            toks = np.stack([np.asarray(t) for t, _, _ in cols])  # (W', S)
+            lps = np.stack([np.asarray(l) for _, l, _ in cols])
+            bad = np.stack([np.asarray(b) for _, _, b in cols])
+        with span("tpu_ddp.serve.decode.emit"):
+            self._chain_emit(dslots, active, toks, lps, bad)
+
+    def _chain_tables(self, dslots: list[int]) -> tuple:
+        """Host half of a chain window: blocks for the whole window,
+        tier residency, the tables and per-slot vectors, and the
+        per-column ``active`` mask (W, S)."""
         S, BPS = self.num_slots, self.blocks_per_seq
         W = self.spec_k + 1
         tables = np.zeros((S, BPS), np.int32)
@@ -1050,6 +1130,13 @@ class ServeEngine:
         if not tiered:
             self._maybe_poison(dslots)
         active = np.arange(W)[:, None] < remaining[None, :]  # (W, S)
+        return tables, cold_tables, lengths, last, temps, seeds, active
+
+    def _chain_dispatch(self, dslots, tables, cold_tables, lengths, last,
+                        temps, seeds, active) -> list:
+        """Device half: one upload, then a dispatch per live column;
+        returns each column's (tokens, logprobs, bad) device arrays."""
+        tiered = self.pool.tiers > 1
         # Fast-path test per column: every LIVE slot still in budget
         # (idle rows are never active — judging them would force the
         # masked path on any partially-full bank; on the fast path
@@ -1101,11 +1188,11 @@ class ServeEngine:
                     d_tables, d_lengths, d_last, d_temps, d_seeds)
             cols.append((toks, lps, bad))
         self.pool.commit(pk, pv)
-        toks = np.stack([np.asarray(t) for t, _, _ in cols])  # (W', S)
-        lps = np.stack([np.asarray(l) for _, l, _ in cols])
-        bad = np.stack([np.asarray(b) for _, _, b in cols])
+        return cols
+
+    def _chain_emit(self, dslots, active, toks, lps, bad) -> None:
         live = set(dslots)
-        for c in range(ncols):
+        for c in range(toks.shape[0]):
             for i in sorted(live):
                 if not active[c, i]:
                     continue
@@ -1138,84 +1225,88 @@ class ServeEngine:
         (accept_length — never a draft token). Rejected tail blocks go
         back to the pool via the scheduler's ``trim_blocks`` rollback,
         so ``accounting_ok()`` holds between steps."""
-        S, BPS = self.num_slots, self.blocks_per_seq
-        tables = np.zeros((S, BPS), np.int32)
-        lengths = np.zeros(S, np.int32)
-        last = np.zeros(S, np.int32)
-        temps = np.zeros(S, np.float32)
-        seeds = np.zeros(S, np.int32)
-        limits = np.zeros(S, np.int32)
-        tiered = self.pool.tiers > 1
-        if tiered:
-            self._maybe_poison(dslots)
-        for i in dslots:
-            self.sched.ensure_blocks(i, self.spec_k + 1)
-        if tiered:
-            # All-hot translation: the fused draft+verify program
-            # addresses ONE buffer, so every block it touches promotes
-            # first and the table carries HOT SLOT ids (hot_slot) in
-            # place of logical ids — the program itself is the
-            # untouched round-17 one, which is the exactness argument.
-            # The cost: a sequence's whole table must fit hot during
-            # its spec step (ensure_hot raises otherwise) — spec decode
-            # does not stream cold pages; the chain schedule does.
-            allb = []
-            for i in dslots:
-                allb.extend(self.sched.slots[i].blocks)
-            self.pool.ensure_hot(allb)
-        for i in dslots:
-            s = self.sched.slots[i]
+        with span("tpu_ddp.serve.decode.tables"):
+            S, BPS = self.num_slots, self.blocks_per_seq
+            tables = np.zeros((S, BPS), np.int32)
+            lengths = np.zeros(S, np.int32)
+            last = np.zeros(S, np.int32)
+            temps = np.zeros(S, np.float32)
+            seeds = np.zeros(S, np.int32)
+            limits = np.zeros(S, np.int32)
+            tiered = self.pool.tiers > 1
             if tiered:
-                row = [self.pool.hot_slot(b) for b in s.blocks]
-                tables[i, :len(row)] = row
-            else:
-                tables[i] = self._table_for(s)
-            lengths[i] = s.length
-            last[i] = s.pending_token
-            temps[i] = s.request.temperature
-            seeds[i] = s.request.seed
-            limits[i] = len(s.request.prompt) + s.request.max_new_tokens
-        if not tiered:
-            self._maybe_poison(dslots)
-        k, v, drafted, toks, lps, bad = self._spec(
-            self._decode_params, self._draft_params,
-            self.pool.k, self.pool.v,
-            jnp.asarray(tables), jnp.asarray(lengths),
-            jnp.asarray(last), jnp.asarray(temps),
-            jnp.asarray(seeds), jnp.asarray(limits))
-        self.pool.commit(k, v)
-        drafted, toks = np.asarray(drafted), np.asarray(toks)
-        lps, bad = np.asarray(lps), np.asarray(bad)
-        for i in dslots:
-            s = self.sched.slots[i]
-            req = s.request
-            g = accept_length(drafted[i], toks[i], self.spec_k)
-            req.spec_proposed += self.spec_k
-            self.spec_proposed += self.spec_k
-            emitted = 0
-            quarantined = False
-            for c in range(g + 1):
-                if bad[i, c]:
-                    quarantined = True
-                    break
-                s.length += 1
-                self._emit(i, int(toks[i, c]), float(lps[i, c]))
-                emitted += 1
-                if req.done:
-                    break
-            acc = max(emitted - 1, 0)
-            req.spec_accepted += acc
-            self.spec_accepted += acc
-            req.spec_rejected += self.spec_k - acc
-            self.spec_rejected += self.spec_k - acc
-            if quarantined:
-                self._quarantine(i)
-            elif not req.done:
-                # KV rollback: free the tail blocks the rejected
-                # columns over-allocated; garbage beyond ``length``
-                # inside kept blocks is causally masked and the next
-                # step's write at ``length`` overwrites the frontier.
-                self.sched.trim_blocks(i)
+                self._maybe_poison(dslots)
+            for i in dslots:
+                self.sched.ensure_blocks(i, self.spec_k + 1)
+            if tiered:
+                # All-hot translation: the fused draft+verify program
+                # addresses ONE buffer, so every block it touches promotes
+                # first and the table carries HOT SLOT ids (hot_slot) in
+                # place of logical ids — the program itself is the
+                # untouched round-17 one, which is the exactness argument.
+                # The cost: a sequence's whole table must fit hot during
+                # its spec step (ensure_hot raises otherwise) — spec decode
+                # does not stream cold pages; the chain schedule does.
+                allb = []
+                for i in dslots:
+                    allb.extend(self.sched.slots[i].blocks)
+                self.pool.ensure_hot(allb)
+            for i in dslots:
+                s = self.sched.slots[i]
+                if tiered:
+                    row = [self.pool.hot_slot(b) for b in s.blocks]
+                    tables[i, :len(row)] = row
+                else:
+                    tables[i] = self._table_for(s)
+                lengths[i] = s.length
+                last[i] = s.pending_token
+                temps[i] = s.request.temperature
+                seeds[i] = s.request.seed
+                limits[i] = len(s.request.prompt) + s.request.max_new_tokens
+            if not tiered:
+                self._maybe_poison(dslots)
+        with span("tpu_ddp.serve.decode.dispatch"):
+            k, v, drafted, toks, lps, bad = self._spec(
+                self._decode_params, self._draft_params,
+                self.pool.k, self.pool.v,
+                jnp.asarray(tables), jnp.asarray(lengths),
+                jnp.asarray(last), jnp.asarray(temps),
+                jnp.asarray(seeds), jnp.asarray(limits))
+            self.pool.commit(k, v)
+        with span("tpu_ddp.serve.decode.fetch"):
+            drafted, toks = np.asarray(drafted), np.asarray(toks)
+            lps, bad = np.asarray(lps), np.asarray(bad)
+        with span("tpu_ddp.serve.decode.emit"):
+            for i in dslots:
+                s = self.sched.slots[i]
+                req = s.request
+                g = accept_length(drafted[i], toks[i], self.spec_k)
+                req.spec_proposed += self.spec_k
+                self.spec_proposed += self.spec_k
+                emitted = 0
+                quarantined = False
+                for c in range(g + 1):
+                    if bad[i, c]:
+                        quarantined = True
+                        break
+                    s.length += 1
+                    self._emit(i, int(toks[i, c]), float(lps[i, c]))
+                    emitted += 1
+                    if req.done:
+                        break
+                acc = max(emitted - 1, 0)
+                req.spec_accepted += acc
+                self.spec_accepted += acc
+                req.spec_rejected += self.spec_k - acc
+                self.spec_rejected += self.spec_k - acc
+                if quarantined:
+                    self._quarantine(i)
+                elif not req.done:
+                    # KV rollback: free the tail blocks the rejected
+                    # columns over-allocated; garbage beyond ``length``
+                    # inside kept blocks is causally masked and the next
+                    # step's write at ``length`` overwrites the frontier.
+                    self.sched.trim_blocks(i)
 
     def spec_stats(self) -> dict:
         """The engine's speculation ledger (router stats roll this

@@ -51,6 +51,12 @@ from tpu_ddp.models.decode import (attend_cached, block_finish,
                                    project_qkv, sample_token)
 from tpu_ddp.parallel.compress import page_dequantize
 from tpu_ddp.serve.kv_pool import PagedKVPool
+from tpu_ddp.utils.profiling import (
+    SERVE_DECODE_TIERED,
+    SERVE_PREFILL_CP,
+    SERVE_PREFILL_TIERED,
+    program,
+)
 
 
 def _mixed_view(hot_buf, cold_buf, cold_scale, li, hot_tables,
@@ -109,6 +115,7 @@ def build_tiered_decode_step(model, block_size: int,
     are the mutating state); cold buffers and scales are read-only —
     decode never writes a cold page."""
 
+    @program(SERVE_DECODE_TIERED)
     def step(params, hot_k, hot_v, cold_k, cold_v, cold_sk, cold_sv,
              hot_tables, cold_tables, lengths, last_tokens, temps,
              seeds):
@@ -129,6 +136,7 @@ def build_tiered_prefill_step(model, block_size: int,
     hot (the engine promotes them first); earlier chunks' pages may
     have gone cold and are read through the dequant."""
 
+    @program(SERVE_PREFILL_TIERED)
     def step(params, hot_k, hot_v, cold_k, cold_v, cold_sk, cold_sv,
              hot_table, cold_table, tokens, start, prompt_len, temp,
              seed):
@@ -221,6 +229,7 @@ def build_cp_prefill_step(model, block_size: int, blocks_per_seq: int,
         in_specs=(P(), P(), P(), P(), P(None, "sp"), P()),
         out_specs=(P(), P(), P()), check_vma=False)
 
+    @program(SERVE_PREFILL_CP)
     def step(params, pool_k, pool_v, table, tokens, start, prompt_len,
              temp, seed):
         C = tokens.shape[1]

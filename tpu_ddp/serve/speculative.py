@@ -92,6 +92,7 @@ from tpu_ddp.models.decode import (
     sample_token,
 )
 from tpu_ddp.serve.kv_pool import PagedKVPool
+from tpu_ddp.utils.profiling import SERVE_SPEC, program
 
 __all__ = ["parse_spec_draft", "draft_bank", "verify_bank",
            "build_spec_step", "accept_length", "SPEC_DRAFTS"]
@@ -256,6 +257,7 @@ def build_spec_step(model, block_size: int, blocks_per_seq: int,
     if k < 1:
         raise ValueError(f"spec_k must be >= 1 to speculate, got {k}")
 
+    @program(SERVE_SPEC)
     def step(params, dparams, pool_k, pool_v, tables, lengths,
              last_tokens, temps, seeds, limits):
         pool_k, pool_v, drafted = draft_bank(

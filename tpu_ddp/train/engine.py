@@ -39,6 +39,9 @@ from tpu_ddp.resilience.guard import (StepGuard, nonfinite_flag,
                                       select_update)
 from tpu_ddp.utils.config import TrainConfig
 from tpu_ddp.utils.metrics import MetricsLogger
+from tpu_ddp.utils.profiling import (DDP_EVAL_STEP, DDP_TRAIN_MULTI_STEP,
+                                     DDP_TRAIN_STEP, program, span,
+                                     spanned)
 from tpu_ddp.utils.timing import IterationTimer
 
 
@@ -285,7 +288,7 @@ class Trainer:
                 NamedSharding(mesh, P(DATA_AXIS)) if self.is_fsdp
                 else self._repl_sharding)
         self._train_step = self._build_train_step()
-        self._eval_step = jax.jit(self._eval_step_impl)
+        self._eval_step = self._build_eval_step()
         # TPU_DDP_AUDIT=warn|error: static donation/precision audit of
         # the train step before it burns a single real step
         # (tpu_ddp/analysis/gate.py). The audit's compile lands in the
@@ -604,7 +607,7 @@ class Trainer:
                 NamedSharding(mesh, P(DATA_AXIS)) if self.is_fsdp
                 else self._repl_sharding)
         self._train_step = self._build_train_step()
-        self._eval_step = jax.jit(self._eval_step_impl)
+        self._eval_step = self._build_eval_step()
         # Memoized mesh-bound closures: stale against the new world.
         for attr in ("_multi_step_cache", "_sharded_eval",
                      "_materialize_fn"):
@@ -773,17 +776,18 @@ class Trainer:
         With equal unpadded shards this reduces to the plain local batch
         mean, i.e. the reference's semantics
         (part2/part2b/main.py:124-132) exactly."""
-        per_ex = softmax_cross_entropy(logits, labels)
-        wsum = jnp.sum(weights * per_ex)
-        n_local = jnp.sum(weights)
-        if self.mesh is not None:
-            n_total = lax.psum(n_local, DATA_AXIS)
-            n_replicas = lax.psum(1.0, DATA_AXIS)
-            loss_for_grad = n_replicas * wsum / n_total
-        else:
-            loss_for_grad = wsum / jnp.maximum(n_local, 1.0)
-        local_mean = wsum / jnp.maximum(n_local, 1.0)
-        return loss_for_grad, local_mean
+        with jax.named_scope("loss"):
+            per_ex = softmax_cross_entropy(logits, labels)
+            wsum = jnp.sum(weights * per_ex)
+            n_local = jnp.sum(weights)
+            if self.mesh is not None:
+                n_total = lax.psum(n_local, DATA_AXIS)
+                n_replicas = lax.psum(1.0, DATA_AXIS)
+                loss_for_grad = n_replicas * wsum / n_total
+            else:
+                loss_for_grad = wsum / jnp.maximum(n_local, 1.0)
+            local_mean = wsum / jnp.maximum(n_local, 1.0)
+            return loss_for_grad, local_mean
 
     def _guarded_apply(self, params, opt_state, loss, grads, apply_fn,
                        extra_bad=None):
@@ -797,11 +801,13 @@ class Trainer:
         int8 path's raw-gradient nonfinite count — see
         resilience/guard.py:nonfinite_flag)."""
         if self.guard is None:
-            new_params, new_opt = apply_fn()
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = apply_fn()
             return new_params, new_opt, jnp.zeros((), jnp.float32)
         bad = nonfinite_flag(loss, grads, self._guard_axis,
                              extra_bad=extra_bad)
-        new_params, new_opt = apply_fn()
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = apply_fn()
         return (select_update(bad, params, new_params),
                 select_update(bad, opt_state, new_opt),
                 bad.astype(jnp.float32))
@@ -864,8 +870,9 @@ class Trainer:
             # Under ZeRO sync_fn is the identity: the optimizer's own
             # reduce_scatter + all_gather pair performs the
             # synchronization.
-            grads = self.sync_fn(grads, DATA_AXIS) \
-                if self.mesh is not None else self.sync_fn(grads)
+            with jax.named_scope("grad_sync"):
+                grads = self.sync_fn(grads, DATA_AXIS) \
+                    if self.mesh is not None else self.sync_fn(grads)
             guard_grads = grads
         if self.is_zero:
             # Clip (if any) happens on the wrapper's dp-scattered slices
@@ -900,10 +907,11 @@ class Trainer:
             # 'none' each replica clips by its own norm — consistent
             # with that rung's no-sync semantics.)
             from tpu_ddp.ops.optim import clip_scale_from_sq, clip_tree
-            sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                     for g in jax.tree.leaves(grads))
-            grads = clip_tree(grads,
-                              clip_scale_from_sq(sq, self.clip_grad_norm))
+            with jax.named_scope("clip"):
+                sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                         for g in jax.tree.leaves(grads))
+                grads = clip_tree(
+                    grads, clip_scale_from_sq(sq, self.clip_grad_norm))
         params, opt_state, skipped = self._guarded_apply(
             params, opt_state, loss, guard_grads,
             lambda: self.optimizer.apply(params, grads, opt_state))
@@ -1026,6 +1034,7 @@ class Trainer:
         don2 = () if keep_inputs else (0, 1)
         don3 = () if keep_inputs else (0, 1, 2)
         if self.mesh is None:
+            @program(DDP_TRAIN_STEP)
             def base(params, opt_state, images, labels, weights):
                 params, opt_state, loss, skipped, _ = self._base_step(
                     params, opt_state, images, labels, weights)
@@ -1041,6 +1050,7 @@ class Trainer:
             # Stateful compression (int8): the carry threads through the
             # jitted step as a third donated argument — the residual is
             # param-sized, so donation keeps one buffer alive, not two.
+            @program(DDP_TRAIN_STEP)
             def comp_body(params, opt_state, comp, images, labels,
                           weights):
                 params, opt_state, loss, skipped, comp = self._base_step(
@@ -1060,6 +1070,7 @@ class Trainer:
             )
             return jax.jit(mapped, donate_argnums=don3)
 
+        @program(DDP_TRAIN_STEP)
         def sharded_body(params, opt_state, images, labels, weights):
             params, opt_state, loss, skipped, _ = self._base_step(
                 params, opt_state, images, labels, weights)
@@ -1141,6 +1152,7 @@ class Trainer:
         # (k, dp, 2) with one — so harvesting a whole K-group costs a
         # single fetch.
         if self.mesh is None:
+            @program(DDP_TRAIN_MULTI_STEP)
             def body(params, opt_state, xs, ys, ws):
                 params, opt_state, _, losses, skips = scan_body(
                     params, opt_state, None, xs, ys, ws)
@@ -1150,6 +1162,7 @@ class Trainer:
 
             fn = jax.jit(body, donate_argnums=(0, 1))
         elif self._comp_stateful:
+            @program(DDP_TRAIN_MULTI_STEP)
             def comp_sharded_body(params, opt_state, comp, xs, ys, ws):
                 params, opt_state, comp, losses, skips = scan_body(
                     params, opt_state, comp, xs, ys, ws)
@@ -1169,6 +1182,7 @@ class Trainer:
                 check_vma=False)
             fn = jax.jit(mapped, donate_argnums=(0, 1, 2))
         else:
+            @program(DDP_TRAIN_MULTI_STEP)
             def sharded_body(params, opt_state, xs, ys, ws):
                 params, opt_state, _, losses, skips = scan_body(
                     params, opt_state, None, xs, ys, ws)
@@ -1479,7 +1493,12 @@ class Trainer:
                  else cfg.dispatch_depth)
         pipe = DispatchPipeline(depth)
 
-        def on_harvest(harv_it, harv_step, result):
+        def on_harvest(harv_it, harv_step, fused):
+            with span("tpu_ddp.train.harvest", it=harv_it):
+                harvested(harv_it, harv_step,
+                          self._materialize_fused(fused))
+
+        def harvested(harv_it, harv_step, result):
             local_loss, skipped = result
             window.account(harv_it, local_loss, harv_step)
             if self.guard is not None:
@@ -1534,7 +1553,9 @@ class Trainer:
             if self._publisher is not None:
                 self._publisher.after_step(state, harv_step)
 
-        for it, item in enumerate(stream, start=start_iter):
+        for it, item in enumerate(
+                spanned(stream, "tpu_ddp.train.data_next"),
+                start=start_iter):
             if cfg.max_iters is not None and it >= cfg.max_iters:
                 break
             if elastic is not None and elastic.changed():
@@ -1572,8 +1593,14 @@ class Trainer:
             # whose steps are built non-donating — carries it.
             prev_state = state if elastic is not None else None
             try:
-                x, y, w = item if use_prefetch else self.put_batch(*item)
-                state, fused = self.train_step_async(state, x, y, w)
+                if use_prefetch:
+                    x, y, w = item
+                else:
+                    with span("tpu_ddp.train.put_batch"):
+                        x, y, w = self.put_batch(*item)
+                with span("tpu_ddp.train.dispatch", it=it,
+                          step=state.step):
+                    state, fused = self.train_step_async(state, x, y, w)
                 if sync_iter:
                     # Force completion before stopping the clock — the
                     # JAX-correct analogue of the reference's synchronous
@@ -1582,8 +1609,7 @@ class Trainer:
                 timer.stop(it)
                 pipe.submit(
                     fused,
-                    lambda f, i=it, s=state.step: on_harvest(
-                        i, s, self._materialize_fused(f)),
+                    lambda f, i=it, s=state.step: on_harvest(i, s, f),
                     sync=sync_iter)
             except Exception as e:  # noqa: BLE001 — filtered below
                 # A peer dying mid-collective surfaces HERE (the gloo
@@ -1629,12 +1655,13 @@ class Trainer:
             if heartbeat is not None:
                 touch_heartbeat(heartbeat[0], heartbeat[1], step)
 
-        def harvest_single(harv_it, harv_step, result):
-            local, skipped = result
-            window.account(harv_it, local, harv_step)
-            if self.guard is not None:
-                self.guard.record(harv_step, skipped, local)
-            beat(harv_step)
+        def harvest_single(harv_it, harv_step, fused):
+            with span("tpu_ddp.train.harvest", it=harv_it):
+                local, skipped = self._materialize_fused(fused)
+                window.account(harv_it, local, harv_step)
+                if self.guard is not None:
+                    self.guard.record(harv_step, skipped, local)
+                beat(harv_step)
 
         def materialize_group(fused):
             """(K, 2) host rows of [loss, skip] — this process's first
@@ -1644,17 +1671,19 @@ class Trainer:
                     fused.addressable_shards[0].data)[:, 0, :]
             return np.asarray(fused)
 
-        def harvest_group(first_it, last_step, rows):
-            for j in range(K):
-                # The group's state advanced by K; attribute each
-                # iteration its own global step.
-                window.account(first_it + j, float(rows[j, 0]),
-                               last_step - K + j + 1)
-                if self.guard is not None:
-                    self.guard.record(last_step - K + j + 1,
-                                      bool(rows[j, 1] > 0),
-                                      float(rows[j, 0]))
-            beat(last_step)
+        def harvest_group(first_it, last_step, fused):
+            with span("tpu_ddp.train.harvest", it=first_it):
+                rows = materialize_group(fused)
+                for j in range(K):
+                    # The group's state advanced by K; attribute each
+                    # iteration its own global step.
+                    window.account(first_it + j, float(rows[j, 0]),
+                                   last_step - K + j + 1)
+                    if self.guard is not None:
+                        self.guard.record(last_step - K + j + 1,
+                                          bool(rows[j, 1] > 0),
+                                          float(rows[j, 0]))
+                beat(last_step)
 
         it = start_iter
         buf: list = []
@@ -1664,20 +1693,23 @@ class Trainer:
             for bx, by in buf:
                 sync_iter = depth_groups == 0 or it <= timer.last_iter
                 timer.start()
-                state, fused = self.train_step_async(
-                    state, *self.put_batch(bx, by))
+                with span("tpu_ddp.train.put_batch"):
+                    batch = self.put_batch(bx, by)
+                with span("tpu_ddp.train.dispatch", it=it,
+                          step=state.step):
+                    state, fused = self.train_step_async(state, *batch)
                 if sync_iter:
                     jax.block_until_ready(fused)
                 timer.stop(it)
                 pipe.submit(
                     fused,
                     lambda f, i=it, s=state.step: harvest_single(
-                        i, s, self._materialize_fused(f)),
+                        i, s, f),
                     sync=sync_iter)
                 it += 1
             buf.clear()
 
-        for item in batches:
+        for item in spanned(batches, "tpu_ddp.train.data_next"):
             if cfg.max_iters is not None \
                     and it + len(buf) >= cfg.max_iters:
                 break
@@ -1696,9 +1728,13 @@ class Trainer:
                 sync_group = depth_groups == 0 or it <= timer.last_iter
                 if timed:
                     timer.start()
-                xs = np.stack([b[0] for b in buf])
-                ys = np.stack([b[1] for b in buf])
-                state, _ = multi(state, *self.put_batches(xs, ys))
+                with span("tpu_ddp.train.put_batch"):
+                    xs = np.stack([b[0] for b in buf])
+                    ys = np.stack([b[1] for b in buf])
+                    group = self.put_batches(xs, ys)
+                with span("tpu_ddp.train.dispatch", it=it,
+                          step=state.step):
+                    state, _ = multi(state, *group)
                 fused = self._last_fused
                 if sync_group:
                     jax.block_until_ready(fused)
@@ -1707,7 +1743,7 @@ class Trainer:
                 pipe.submit(
                     fused,
                     lambda f, i=it, s=state.step: harvest_group(
-                        i, s, materialize_group(f)),
+                        i, s, f),
                     sync=sync_group)
                 it += K
                 buf.clear()
@@ -1719,12 +1755,18 @@ class Trainer:
 
     # ---- eval (reference test_model, part1/main.py:96-111) -------------
 
-    def _eval_step_impl(self, params, images, labels):
-        logits = self.model.apply(params, self._maybe_normalize(images))
-        # Batch-mean loss (summed over batches by the caller, divided by
-        # number of batches — the reference's per-batch averaging semantics,
-        # part1/main.py:108) + top-1 correct count.
-        return cross_entropy_loss(logits, labels), top1_correct(logits, labels)
+    def _build_eval_step(self):
+        @program(DDP_EVAL_STEP)
+        def step(params, images, labels):
+            logits = self.model.apply(params,
+                                      self._maybe_normalize(images))
+            # Batch-mean loss (summed over batches by the caller, divided
+            # by number of batches — the reference's per-batch averaging
+            # semantics, part1/main.py:108) + top-1 correct count.
+            return (cross_entropy_loss(logits, labels),
+                    top1_correct(logits, labels))
+
+        return jax.jit(step)
 
     def _build_sharded_eval(self):
         """Test batch sharded over dp, per-shard sums psum'd — N x less
@@ -1733,6 +1775,7 @@ class Trainer:
         identical to the replicated pass (weighted sums reduce to the
         same totals regardless of the split; wrap-padding rows carry
         weight 0). Opt-in via ``evaluate(..., sharded=True)``."""
+        @program(DDP_EVAL_STEP)
         def body(params, images, labels, weights):
             logits = self.model.apply(params, self._maybe_normalize(images))
             per_ex = softmax_cross_entropy(logits, labels)

@@ -42,6 +42,8 @@ from tpu_ddp.ops.loss import (chunked_vocab_cross_entropy,
 from tpu_ddp.ops.optim import AdamW
 from tpu_ddp.parallel.mesh import (DATA_AXIS, EXPERT_AXIS, MODEL_AXIS,
                                    PIPE_AXIS, SEQ_AXIS)
+from tpu_ddp.utils.profiling import (LM_TRAIN_MULTI_STEP, LM_TRAIN_STEP,
+                                     program, span)
 
 
 def _spec_axes(spec) -> set:
@@ -108,8 +110,12 @@ class _MeshTrainer:
         return ()
 
     def _compile_step(self, batch_spec, loss_spec):
+        @program(LM_TRAIN_STEP)
+        def step(*args):
+            return self._base_step(*args)
+
         mapped = jax.shard_map(
-            self._base_step,
+            step,
             mesh=self.mesh,
             in_specs=(self._param_specs, self._opt_specs, batch_spec,
                       batch_spec, *self._extra_in_specs()),
@@ -128,10 +134,17 @@ class _MeshTrainer:
         overridden where the trainer re-lays-out parameters."""
         return self.optimizer.decay_mask(params)
 
+    def put_batch(self, inputs, targets):
+        """Host (B, L) inputs / targets -> device arrays in the batch
+        sharding; the subclass's ``_put_batch`` checks divisibility."""
+        with span("tpu_ddp.lm.put_batch", tokens=np.size(inputs)):
+            return self._put_batch(inputs, targets)
+
     def train_step(self, state: LMTrainState, inputs, targets):
-        params, opt_state, loss = self._train_step(
-            state.params, state.opt_state, inputs, targets,
-            *self._extra_args(state))
+        with span("tpu_ddp.lm.train_step", step=state.step):
+            params, opt_state, loss = self._train_step(
+                state.params, state.opt_state, inputs, targets,
+                *self._extra_args(state))
         return LMTrainState(params, opt_state, state.step + 1), loss
 
     def lower_train_step(self, state: LMTrainState, inputs, targets):
@@ -154,23 +167,24 @@ class _MeshTrainer:
         must NOT be summed (they would multi-count it). Every device
         lands on the same norm, so the scale is consistent everywhere.
         One psum per distinct axis set, not per leaf."""
-        g_l, treedef = jax.tree.flatten(grads)
-        s_l = jax.tree.leaves(specs, is_leaf=_is_spec)
-        groups: dict = {}
-        for g, spec in zip(g_l, s_l):
-            axes = tuple(sorted(a for a in _spec_axes(spec)
-                                if self.mesh.shape[a] > 1))
-            groups.setdefault(axes, []).append(
-                jnp.sum(jnp.square(g.astype(jnp.float32))))
-        sq = jnp.float32(0.0)
-        for axes, sums in groups.items():
-            s = sum(sums)
-            if axes:
-                s = lax.psum(s, axes)
-            sq = sq + s
-        from tpu_ddp.ops.optim import clip_scale_from_sq, clip_tree
-        return clip_tree(treedef.unflatten(g_l),
-                         clip_scale_from_sq(sq, self.clip_grad_norm))
+        with jax.named_scope("clip"):
+            g_l, treedef = jax.tree.flatten(grads)
+            s_l = jax.tree.leaves(specs, is_leaf=_is_spec)
+            groups: dict = {}
+            for g, spec in zip(g_l, s_l):
+                axes = tuple(sorted(a for a in _spec_axes(spec)
+                                    if self.mesh.shape[a] > 1))
+                groups.setdefault(axes, []).append(
+                    jnp.sum(jnp.square(g.astype(jnp.float32))))
+            sq = jnp.float32(0.0)
+            for axes, sums in groups.items():
+                s = sum(sums)
+                if axes:
+                    s = lax.psum(s, axes)
+                sq = sq + s
+            from tpu_ddp.ops.optim import clip_scale_from_sq, clip_tree
+            return clip_tree(treedef.unflatten(g_l),
+                             clip_scale_from_sq(sq, self.clip_grad_norm))
 
     def _put_sharded(self, array, sharding):
         from tpu_ddp.parallel.mesh import put_sharded
@@ -353,6 +367,7 @@ class _MeshTrainer:
             batch_spec = P((DATA_AXIS, EXPERT_AXIS), SEQ_AXIS)
             extra_specs = self._extra_in_specs()
 
+            @program(LM_TRAIN_MULTI_STEP)
             def body(params, opt_state, inputs_k, targets_k, *extras_k):
                 def step(carry, xs):
                     p, o = carry
@@ -673,24 +688,28 @@ class LMTrainer(_MeshTrainer):
             if self.vocab_chunk:
                 hidden, aux = self.model.trunk_with_aux(p, inputs,
                                                         rng=rng)
-                nll = chunked_vocab_cross_entropy(
-                    hidden.reshape(-1, hidden.shape[-1]), p["head"],
-                    targets.reshape(-1), self.vocab_chunk)
+                with jax.named_scope("loss"):
+                    nll = chunked_vocab_cross_entropy(
+                        hidden.reshape(-1, hidden.shape[-1]), p["head"],
+                        targets.reshape(-1), self.vocab_chunk)
             else:
                 logits, aux = self.model.apply_with_aux(p, inputs,
                                                         rng=rng)
-                nll = softmax_cross_entropy(
-                    logits.reshape(-1, logits.shape[-1]),
-                    targets.reshape(-1))
-            local_sum = jnp.sum(nll)
-            local_n = jnp.float32(nll.size)
-            total = lax.psum(local_n, self._data_axes)
-            n_shards = lax.psum(1.0, self._data_axes)
-            # Scale so pmean-of-grads == grad of the GLOBAL token mean.
-            # mp shards hold the same tokens and compute the same loss.
-            loss_for_grad = (n_shards * local_sum / total
-                             + self.moe_aux_coef * aux)
-            return loss_for_grad, local_sum / local_n
+                with jax.named_scope("loss"):
+                    nll = softmax_cross_entropy(
+                        logits.reshape(-1, logits.shape[-1]),
+                        targets.reshape(-1))
+            with jax.named_scope("loss"):
+                local_sum = jnp.sum(nll)
+                local_n = jnp.float32(nll.size)
+                total = lax.psum(local_n, self._data_axes)
+                n_shards = lax.psum(1.0, self._data_axes)
+                # Scale so pmean-of-grads == grad of the GLOBAL token
+                # mean. mp shards hold the same tokens and compute the
+                # same loss.
+                loss_for_grad = (n_shards * local_sum / total
+                                 + self.moe_aux_coef * aux)
+                return loss_for_grad, local_sum / local_n
 
         if self.is_fsdp:
             def grad_fn(p, x, y, r):
@@ -725,13 +744,16 @@ class LMTrainer(_MeshTrainer):
                                         for a in self._data_axes
                                         if a in sharded]))
                 return g / float(self.dp * excluded)
-            grads = jax.tree.map(leaf, grads, self._orig_specs)
+            with jax.named_scope("grad_sync"):
+                grads = jax.tree.map(leaf, grads, self._orig_specs)
             if self.clip_grad_norm is not None:
                 # Flat dp shards: the flat specs carry the (mp..., dp)
                 # axes each slice is distinct over.
                 grads = self._clip_by_global_norm(grads,
                                                   self._param_specs)
-            params, opt_state = self.zero3.apply(params, grads, opt_state)
+            with jax.named_scope("optimizer"):
+                params, opt_state = self.zero3.apply(params, grads,
+                                                     opt_state)
             return params, opt_state, local_mean.reshape(1, 1)
 
         if self.opt_zero1:
@@ -741,25 +763,30 @@ class LMTrainer(_MeshTrainer):
             # accumulation already scattered over dp — the same non-dp
             # algebra applies elementwise to the f32 slices (linear ops
             # commute with slicing).
-            grads = self._sync_grads(grads, skip_axes=(DATA_AXIS,))
-            if self.opt_zero2:
-                params, opt_state = self.optimizer.apply_scattered(
-                    params, grads, opt_state,
-                    clip_norm=self.clip_grad_norm)
-            elif self.clip_grad_norm is not None:
-                params, opt_state = self.optimizer.apply(
-                    params, grads, opt_state,
-                    clip_norm=self.clip_grad_norm)
-            else:
-                params, opt_state = self.optimizer.apply(params, grads,
-                                                         opt_state)
+            with jax.named_scope("grad_sync"):
+                grads = self._sync_grads(grads, skip_axes=(DATA_AXIS,))
+            with jax.named_scope("optimizer"):
+                if self.opt_zero2:
+                    params, opt_state = self.optimizer.apply_scattered(
+                        params, grads, opt_state,
+                        clip_norm=self.clip_grad_norm)
+                elif self.clip_grad_norm is not None:
+                    params, opt_state = self.optimizer.apply(
+                        params, grads, opt_state,
+                        clip_norm=self.clip_grad_norm)
+                else:
+                    params, opt_state = self.optimizer.apply(
+                        params, grads, opt_state)
             return params, opt_state, local_mean.reshape(1, 1)
 
-        grads = self._sync_grads(grads)
+        with jax.named_scope("grad_sync"):
+            grads = self._sync_grads(grads)
         if self.clip_grad_norm is not None:
             grads = self._clip_by_global_norm(grads, self._param_specs)
-        params, opt_state = self.optimizer.apply(
-            params, grads, opt_state, decay_mask=self._decay_mask(params))
+        with jax.named_scope("optimizer"):
+            params, opt_state = self.optimizer.apply(
+                params, grads, opt_state,
+                decay_mask=self._decay_mask(params))
         # (1, 1) per shard -> (dp*ep, sp) global: each shard's chunk mean.
         return params, opt_state, local_mean.reshape(1, 1)
 
@@ -787,7 +814,7 @@ class LMTrainer(_MeshTrainer):
         return [{k: np.asarray(v) for k, v in layer.items()}
                 for layer in stats]
 
-    def put_batch(self, inputs, targets):
+    def _put_batch(self, inputs, targets):
         inputs = np.ascontiguousarray(inputs, np.int32)
         targets = np.ascontiguousarray(targets, np.int32)
         b, L = inputs.shape
@@ -1345,7 +1372,7 @@ class PipelineLMTrainer(_MeshTrainer):
         mean = lax.psum(local_mean, PIPE_AXIS)
         return params, opt_state, mean.reshape(1, 1)
 
-    def put_batch(self, inputs, targets):
+    def _put_batch(self, inputs, targets):
         inputs = np.ascontiguousarray(inputs, np.int32)
         targets = np.ascontiguousarray(targets, np.int32)
         b, L = inputs.shape

@@ -1,10 +1,35 @@
-"""Profiler integration.
+"""Tracing: the one module through which the program names what it does.
 
-The reference's only tracing is a hand-rolled ``perf_counter_ns`` harness
-(SURVEY.md §5) — preserved in :mod:`tpu_ddp.utils.timing`. This module adds
-the TPU-native deep profiler: XLA device traces via ``jax.profiler``,
-viewable in TensorBoard/Perfetto, enabled by flag or the
-``TPU_DDP_PROFILE_DIR`` env var.
+A trace is taken by opening a ``jax.profiler`` session:
+:func:`profile_trace` (``TPU_DDP_PROFILE_DIR`` makes ``parts/common.py``
+trace the ladder's first epoch with it) or ``benchmark/run.py --trace
+1``. While a session is open the profiler records, on ONE clock,
+
+- **host spans** (:func:`span`, a ``jax.profiler.TraceAnnotation``): what
+  the host was doing, with the counts of that step as the event's stats;
+- **programs**: each jitted program the device ran, under the name its
+  Python function carries (:func:`program`), on the device plane's
+  "XLA Modules" line as ``jit_<name>(<fingerprint>)``;
+- **kernels**: each Pallas kernel, under the ``name=`` of its
+  ``pallas_call``, which XLA:TPU makes the custom call's instruction name;
+- **scopes** (``jax.named_scope``): the layer an operation belongs to, as
+  the path in the "XLA Ops" events' ``tf_op`` stat
+  (``jit(serve_decode)/attn/kv_gather/...``).
+
+With no session open a span is a no-op and the names are only metadata
+of the compiled programs: nothing here is switched on or off by a flag,
+an environment variable or a config field.
+
+The tables below are the single source of every name. ``docs/DESIGN.md``
+("Tracing") prints them, ``benchmark/layer_metrics/`` reads them by name,
+and ``tests/test_profiling.py`` holds the call sites to them both ways.
+
+**Rules for a count** (a keyword of :func:`span`). It is a Python ``int``
+or ``float`` that the step already has on the host. Never a device value:
+no ``np.asarray``, ``.item()`` or ``float()`` of a ``jax.Array`` to fill
+a count, which would be a sync. Nothing that costs more than O(slots) to
+compute, because the argument is computed with tracing off too. A span's
+counts are fixed when it opens.
 """
 
 from __future__ import annotations
@@ -14,11 +39,166 @@ import os
 
 import jax
 
+PREFIX = "tpu_ddp."
+
+# name -> (layer as in PERF.md section 3, what the span covers, counts)
+SPANS = {
+    "tpu_ddp.serve.step": (
+        "serving", "all of ServeEngine.step()",
+        ("n", "queue", "live", "blocks_in_use")),
+    "tpu_ddp.serve.schedule": (
+        "serving", "chaos / subscriber hooks, deadline shedding, "
+        "sched.admit(), and each pick of a prefill slot or of the decode "
+        "slots", ()),
+    "tpu_ddp.serve.admit": (
+        "serving", "one marker per admitted request, inside schedule",
+        ("rid", "waited_ms", "prompt_tokens", "cached_tokens")),
+    "tpu_ddp.serve.prefill": (
+        "serving", "one prefill chunk: build, upload, dispatch, "
+        "pool.commit and, on the final chunk, the first token's fetch "
+        "and emit", ("rid", "tokens", "start", "final")),
+    "tpu_ddp.serve.decode": (
+        "serving", "one whole-bank decode step (plain, chain or fused "
+        "speculative)", ("slots", "context_tokens")),
+    "tpu_ddp.serve.decode.tables": (
+        "serving", "ensure_block(s), tier residency, the numpy tables "
+        "and vectors", ()),
+    "tpu_ddp.serve.decode.dispatch": (
+        "serving", "jnp.asarray uploads, the jitted call(s), pool.commit",
+        ()),
+    "tpu_ddp.serve.decode.fetch": (
+        "serving", "the blocking np.asarray of tokens, log-probabilities "
+        "and flags", ()),
+    "tpu_ddp.serve.decode.emit": (
+        "serving", "the per-slot loop: quarantine, length += 1, _emit "
+        "(stamps, callbacks, retire)", ()),
+    "tpu_ddp.lm.put_batch": (
+        "train loop", "LMTrainer / PipelineLMTrainer.put_batch: host "
+        "arrays to sharded device arrays", ("tokens",)),
+    "tpu_ddp.lm.train_step": (
+        "train loop", "the dispatch of one jitted LM step (not the wait "
+        "for its loss: that is the caller's)", ("step",)),
+    "tpu_ddp.train.data_next": (
+        "host data path", "next() of the batch stream train_epoch "
+        "iterates", ()),
+    "tpu_ddp.train.put_batch": (
+        "train loop", "Trainer.put_batch(es) inside train_epoch", ()),
+    "tpu_ddp.train.dispatch": (
+        "train loop", "train_step_async, or one K-step group",
+        ("it", "step")),
+    "tpu_ddp.train.harvest": (
+        "train loop", "a harvested step: loss fetch, guard, heartbeat, "
+        "checkpoint / invariant / publish cadences", ("it",)),
+}
+
+# Jitted programs: the __name__ of the function handed to jax.jit, so
+# "jit_<name>" in HLO, in jax.monitoring's compile events
+# (analysis/retrace.py) and on the trace's "XLA Modules" line.
+SERVE_DECODE = "serve_decode"
+SERVE_PREFILL = "serve_prefill"
+SERVE_SPEC = "serve_spec"
+SERVE_DECODE_TIERED = "serve_decode_tiered"
+SERVE_PREFILL_TIERED = "serve_prefill_tiered"
+SERVE_PREFILL_CP = "serve_prefill_cp"
+SERVE_ADOPT_DECODE = "serve_adopt_decode"
+LM_TRAIN_STEP = "lm_train_step"
+LM_TRAIN_MULTI_STEP = "lm_train_multi_step"
+DDP_TRAIN_STEP = "ddp_train_step"
+DDP_TRAIN_MULTI_STEP = "ddp_train_multi_step"
+DDP_EVAL_STEP = "ddp_eval_step"
+
+PROGRAMS = {
+    SERVE_DECODE: "serve/engine.py: one token for the whole slot bank",
+    SERVE_PREFILL: "serve/engine.py: one prefill chunk of one prompt",
+    SERVE_SPEC: "serve/speculative.py: fused draft + verify",
+    SERVE_DECODE_TIERED: "serve/long_context.py: decode over hot + cold "
+                         "K/V tiers",
+    SERVE_PREFILL_TIERED: "serve/long_context.py: prefill chunk over "
+                          "hot + cold K/V tiers",
+    SERVE_PREFILL_CP: "serve/long_context.py: context-parallel prefill "
+                      "chunk",
+    SERVE_ADOPT_DECODE: "fleet/disagg.py: K/V block adoption fused with "
+                        "the decode step",
+    LM_TRAIN_STEP: "train/lm.py: one LMTrainer / PipelineLMTrainer step",
+    LM_TRAIN_MULTI_STEP: "train/lm.py: K LM steps scanned in one "
+                         "dispatch",
+    DDP_TRAIN_STEP: "train/engine.py: one Trainer step (every sync rung)",
+    DDP_TRAIN_MULTI_STEP: "train/engine.py: K Trainer steps scanned in "
+                          "one dispatch",
+    DDP_EVAL_STEP: "train/engine.py: one evaluation batch",
+}
+
+# Pallas kernels: the name= of each pl.pallas_call in ops/pallas/.
+KERNELS = {
+    "flash_fwd": "flash_attention.py: forward, with the log-sum-exp",
+    "flash_bwd_dkv": "flash_attention.py: backward, dK and dV",
+    "flash_bwd_dq": "flash_attention.py: backward, dQ",
+    "quant_matmul": "quant_matmul.py: int8 x int8 -> f32 with scales",
+    "fused_sgd": "sgd.py: momentum SGD update in place",
+    "bn_relu_stats": "bn_relu.py: per-channel sum and sum of squares",
+    "bn_relu_apply": "bn_relu.py: normalise, scale, ReLU",
+    "bn_relu_bwd_reduce": "bn_relu.py: backward reductions",
+    "bn_relu_bwd_dx": "bn_relu.py: backward dx",
+}
+
+# jax.named_scope at the layer boundaries of the programs the cells run.
+# Metadata only: a scope changes no compiled code.
+SCOPES = {
+    "embed": "token embedding lookup",
+    "attn": "a block's attention half: LayerNorm, QKV, attention, "
+            "output projection, residual",
+    "kv_write": "inside attn, serving: the new K/V scattered into the "
+                "paged pool",
+    "kv_gather": "inside attn, serving: the pool gathered through the "
+                 "block tables into a contiguous view",
+    "mlp": "a block's MLP half: LayerNorm, MLP, residual",
+    "head": "final LayerNorm and output head",
+    "sample": "serving: token sampling and the non-finite check",
+    "loss": "trainers: cross-entropy on the logits",
+    "grad_sync": "trainers: gradient mean over the data axes",
+    "clip": "trainers: global-norm gradient clipping",
+    "optimizer": "trainers: the optimizer update",
+}
+
+
+def span(name: str, **counts):
+    """A host span on the profiler's clock, with ``counts`` as the
+    event's stats (``jax.profiler.ProfileData`` gives them back as
+    ``event.stats``). ``name`` is a key of :data:`SPANS`, written as a
+    literal at the call site. A no-op while no profiler session is open."""
+    return jax.profiler.TraceAnnotation(name, **counts)
+
+
+def spanned(iterable, name: str):
+    """``iterable``, with each ``next()`` of it under ``span(name)``."""
+    it = iter(iterable)
+    while True:
+        with span(name):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item
+
+
+def program(name: str):
+    """Decorator: give the function about to be jitted the program name
+    ``name`` (a key of :data:`PROGRAMS`)."""
+    if name not in PROGRAMS:
+        raise KeyError(f"{name!r} is not in profiling.PROGRAMS")
+
+    def rename(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+
+    return rename
+
 
 @contextlib.contextmanager
 def profile_trace(logdir: str | None = None):
-    """Capture a device trace into ``logdir`` for the duration of the
-    ``with`` block; no-op when ``logdir`` is falsy."""
+    """Capture a trace (device operations and the spans above) into
+    ``logdir`` for the duration of the ``with`` block; no-op when
+    ``logdir`` is falsy."""
     if not logdir:
         yield
         return
@@ -28,11 +208,6 @@ def profile_trace(logdir: str | None = None):
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named region that shows up on the trace timeline (host + device)."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 def profile_dir_from_env() -> str | None:
