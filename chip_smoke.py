@@ -7,14 +7,16 @@ models the repo benchmarks (VGG-11 at the reference's global batch 256;
 TransformerLM-large, 12 layers, d_model 2048, ~740M parameters), with
 random weights made from a seed, and checks the results by the repo's
 own means: the step guard's finite-loss check, the retrace sentinel, the
-``jax.numpy`` references of the four Pallas kernels, ``generate()``.
+``jax.numpy`` references of the five Pallas kernels, ``generate()``.
 
     P0  device: platform pinned to tpu, device_kind in both peak tables,
         compile-cache directory, native libraries rebuilt from source
     P1  the ladder: run_part("part1") and run_part("part3") on VGG-11
-    P2  the four Pallas kernels, compiled, against their references
+    P2  the five Pallas kernels, compiled, against their references
     P3  LMTrainer on TransformerLM-large with the flash kernel, 5 steps
-    P4  ServeEngine on TransformerLM-large, bf16 then int8 decode
+    P4  ServeEngine on TransformerLM-large, bf16 then int8 decode, the
+        paged decode kernel in the compiled step (and the gather body
+        in a head_dim 64 model's)
     P5  four chips (skipped on fewer): the five rungs agree on one step
         at dp=4, part3 end to end, one LM-large step over dp=2 x tp=2
 
@@ -49,6 +51,7 @@ import subprocess
 import sys
 import time
 import traceback
+import types
 from pathlib import Path
 from unittest import mock
 
@@ -69,9 +72,16 @@ LM_BATCH = 4                 # bench.py's LM-large microbatch
 LM_STEPS = 5
 SERVE_PROMPT_LENS = (64, 128, 200, 301, 400, 512)
 SERVE_NEW_TOKENS = 32
+SERVE_AGREE_MIN = 26        # of the 32, with generate(), first prompt (PR 21)
+SERVE_GATHER_PRESET = "TransformerLM-small"     # head_dim 64
 FLASH_SHAPES = ((1, 2048, 16, 128), (1, 1000, 16, 128))  # (B, L, H, D)
 # LM-large's four decode matmuls (K -> N) at M = 8 live rows.
 INT8_SHAPES = ((2048, 6144), (2048, 8192), (8192, 2048), (2048, 32000))
+# Paged decode attention: (layers, blocks, block, KV heads, head_dim,
+# slots, Q heads, blocks per slot) and the slots' lengths, one a full
+# table, one a single token, the rest on and around page boundaries.
+PAGED_SHAPE = (2, 8 * 64 + 1, 16, 2, 128, 8, 24, 64)
+PAGED_LENGTHS = (1024, 1, 16, 17, 129, 511, 700, 33)
 VGG_LEAF = (3, 3, 256, 512)          # a VGG-11 conv kernel
 VGG_ACTIVATION = (256, 32, 32, 64)   # first conv output at batch 256
 
@@ -287,8 +297,10 @@ def p2_kernels() -> dict:
     from tpu_ddp.analysis.retrace import count_compiles
     from tpu_ddp.models.vgg import batch_norm
     from tpu_ddp.ops.optim import SGD
+    from tpu_ddp.models.decode import attend_cached
     from tpu_ddp.ops.pallas import (batch_norm_relu, flash_attention,
                                     int8_matmul)
+    from tpu_ddp.ops.pallas.paged_attention import paged_decode_attention
     from tpu_ddp.ops.quant import quantize_weight
     from tpu_ddp.parallel.ring_attention import full_attention
 
@@ -341,6 +353,27 @@ def p2_kernels() -> dict:
                       x, q.astype(x.dtype),
                       preferred_element_type=jnp.float32) * s,
                   (x, qw.q, qw.s), TOL_F32_DOT)
+
+        layers, blocks, bs, kvh, hd, slots, heads, bps = PAGED_SHAPE
+        pools = [jax.random.normal(next(keys),
+                                   (layers, blocks, bs, kvh * hd),
+                                   jnp.bfloat16) for _ in range(2)]
+        tables = 1 + jax.random.permutation(
+            next(keys), blocks - 1).reshape(slots, bps).astype(jnp.int32)
+        lengths = jnp.asarray(PAGED_LENGTHS, jnp.int32)
+        q = jax.random.normal(next(keys), (slots, heads, hd), jnp.bfloat16)
+
+        def gathered(q, pk, pv, tables, lengths):
+            view = (slots, bps * bs, kvh, hd)
+            return attend_cached(
+                types.SimpleNamespace(head_dim=hd), q[:, None],
+                pk[1][tables].reshape(view), pv[1][tables].reshape(view),
+                (lengths - 1)[:, None])[:, 0]
+
+        check("paged_decode_attention",
+              lambda q, pk, pv, t, n: paged_decode_attention(
+                  q, pk, pv, t, n, layer=1, kv_heads=kvh),
+              gathered, (q, *pools, tables, lengths), TOL_BF16)
 
         tree = {"w": jax.random.normal(next(keys), VGG_LEAF, jnp.float32),
                 "b": jax.random.normal(next(keys), VGG_LEAF[-1:],
@@ -448,12 +481,20 @@ def p3_lm_trainer() -> dict:
 
 # ---- P4 ------------------------------------------------------------------
 
+def _decode_attention(engine) -> str:
+    """How the engine's COMPILED decode step attends: through the paged
+    kernel, in place, or over the gathered ``max_seq_len`` view."""
+    text = engine.lower_decode_step().compile().as_text()
+    return "paged_decode_attn" if "paged_decode_attn" in text else "gather"
+
+
 def p4_serve() -> dict:
     import jax
     import numpy as np
 
     from tpu_ddp.analysis.retrace import no_retrace
     from tpu_ddp.models import generate, make_transformer
+    from tpu_ddp.ops.pallas import paged_attention
     from tpu_ddp.serve import ServeEngine
     from tpu_ddp.utils.profiling import SERVE_DECODE, SERVE_PREFILL
 
@@ -488,6 +529,12 @@ def p4_serve() -> dict:
         if warm.counts != {SERVE_DECODE: 1, SERVE_PREFILL: 1}:
             raise AssertionError(f"{quant}: decode + prefill compiles "
                                  f"{warm.counts}, expected two")
+        # head_dim 128, block 16, bf16 pool: inside the paged kernel's
+        # predicate, so the compiled step must hold the kernel.
+        attends = _decode_attention(engine)
+        if attends != "paged_decode_attn":
+            raise AssertionError(f"{quant}: the compiled decode step of "
+                                 f"{model.name} attends by {attends}")
         with no_retrace(watch=(SERVE_DECODE, SERVE_PREFILL),
                         max_compiles=0):
             t0 = time.perf_counter()
@@ -506,7 +553,8 @@ def p4_serve() -> dict:
                 raise AssertionError(f"{quant}: request {r.rid} has a "
                                      "non-finite logprob")
         generated = len(reqs) * SERVE_NEW_TOKENS
-        cell = {"requests": len(reqs), "engine_steps": steps,
+        cell = {"decode_attention": attends,
+                "requests": len(reqs), "engine_steps": steps,
                 "generated_tokens": generated,
                 "compile_s": round(warm.compile_seconds, 2),
                 "steady_s": round(wall, 3),
@@ -515,11 +563,32 @@ def p4_serve() -> dict:
         if quant == "none":
             ref = np.asarray(generate(model, params, prompts[0][None],
                                       max_new_tokens=SERVE_NEW_TOKENS))[0]
-            cell["tokens_agreeing_with_generate"] = int(
-                np.sum(ref == np.asarray(reqs[0].tokens)))
+            agree = int(np.sum(ref == np.asarray(reqs[0].tokens)))
+            cell["tokens_agreeing_with_generate"] = agree
+            # bf16 greedy streams part ways at the first near-tie; the
+            # gather body agreed on 26 of 32 (PR 21).
+            if agree < SERVE_AGREE_MIN:
+                raise AssertionError(
+                    f"only {agree} of {SERVE_NEW_TOKENS} greedy tokens "
+                    f"agree with generate() (at least {SERVE_AGREE_MIN} "
+                    "expected)")
         out["bf16" if quant == "none" else "int8"] = cell
         del engine, reqs
         gc.collect()
+    # A model outside the predicate (head_dim 64) keeps the gather body:
+    # reported, not hidden.
+    small = make_transformer(SERVE_GATHER_PRESET, max_seq_len=LM_SEQ_LEN)
+    engine = ServeEngine(small, small.init(jax.random.key(SEED)))
+    inside = paged_attention.supports(small.head_dim, engine.block_size,
+                                      engine.pool.k.dtype,
+                                      small.compute_dtype)
+    attends = _decode_attention(engine)
+    if inside or attends != "gather":
+        raise AssertionError(f"{small.name} (head_dim {small.head_dim}): "
+                             f"predicate {inside}, attends by {attends}")
+    out["outside_predicate"] = {"model": small.name,
+                                "head_dim": small.head_dim,
+                                "decode_attention": attends}
     out["compile_s"] = round(compile_s, 2)
     out["steady_s_per_token"] = {k: out[k]["steady_s_per_token"]
                                  for k in ("bf16", "int8")}

@@ -108,7 +108,7 @@ class TestRefcounts:
                                       np.asarray(pool.v[:, b]))
         # Writing the copy leaves the shared original untouched.
         pool.commit(pool.k.at[:, c].set(9.0), pool.v)
-        assert float(pool.k[0, b, 0, 0, 0]) == 7.0
+        assert float(pool.k[0, b, 0, 0]) == 7.0
 
 
 class TestPrefixIndex:
@@ -273,9 +273,8 @@ class TestDisagg:
                      adopt_v, tables, lengths, last_tokens, temps,
                      seeds):
             k, v, toks, lps, _bad = decode_bank(
-                model, fleet.block_size, fleet.blocks_per_seq, params,
-                pool_k, pool_v, tables, lengths, last_tokens, temps,
-                seeds)
+                model, fleet.block_size, params, pool_k, pool_v, tables,
+                lengths, last_tokens, temps, seeds)
             k = k.at[:, adopt_ids].set(adopt_k.astype(k.dtype))
             v = v.at[:, adopt_ids].set(adopt_v.astype(v.dtype))
             return k, v, toks, lps
@@ -288,7 +287,7 @@ class TestDisagg:
         S, BPS = fleet.num_slots, fleet.blocks_per_seq
         pk = sds(fleet.pool.k.shape, fleet.pool.k.dtype)
         pay = sds((model.num_layers, 2, fleet.block_size,
-                   model.kv_heads, model.head_dim), jnp.float32)
+                   model.kv_heads * model.head_dim), jnp.float32)
         i32 = functools.partial(sds, dtype=jnp.int32)
         bad = fn.lower(spec, pk, pk, i32((2,)), pay, pay,
                        i32((S, BPS)), i32((S,)), i32((S,)),

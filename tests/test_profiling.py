@@ -62,10 +62,11 @@ def test_every_kernel_and_scope_name_is_in_its_table_and_used():
                      sorted((ROOT / "tpu_ddp/ops/pallas").glob("*.py")))
     assert set(kernels) == set(KERNELS)
     assert {f for fs in kernels.values() for f in fs} == {
-        "flash_attention.py", "quant_matmul.py", "sgd.py", "bn_relu.py"}
+        "flash_attention.py", "quant_matmul.py", "sgd.py", "bn_relu.py",
+        "paged_attention.py"}
     pallas = sum(p.read_text().count("pl.pallas_call(")
                  for p in (ROOT / "tpu_ddp/ops/pallas").glob("*.py"))
-    assert pallas == len(KERNELS) == 9
+    assert pallas == len(KERNELS) == 10
     scopes = _calls(re.compile(r'jax\.named_scope\("(\w+)"\)'))
     assert set(scopes) == set(SCOPES)
 
@@ -355,3 +356,79 @@ def test_span_outside_a_session_is_a_noop():
         pass
     assert list(profiling.spanned([1, 2, 3], "tpu_ddp.train.data_next")) \
         == [1, 2, 3]
+
+
+# ---- bursts -----------------------------------------------------------------
+
+def _step_span(n):
+    return span("tpu_ddp.serve.step", n=n, queue=0, live=0, blocks_in_use=0)
+
+
+@pytest.mark.parametrize("n, kept", [
+    (1, True), (profiling.BURST_STEPS, True),
+    (profiling.BURST_STEPS + 1, False), (profiling.BURST_EVERY, False),
+    (profiling.BURST_EVERY + 1, True),
+    (profiling.BURST_EVERY + profiling.BURST_STEPS, True),
+    (profiling.BURST_EVERY + profiling.BURST_STEPS + 1, False)])
+def test_burst_keeps_the_first_steps_of_every_period(n, kept):
+    with profiling.burst(n):
+        inside = _step_span(n)
+        with profiling.burst(1):        # an engine stepped inside another's
+            assert isinstance(_step_span(1), jax.profiler.TraceAnnotation)
+        again = _step_span(n)
+    for s in (inside, again):
+        assert isinstance(s, jax.profiler.TraceAnnotation) == kept
+    assert isinstance(_step_span(n), jax.profiler.TraceAnnotation)
+
+
+def test_burst_restores_the_thread_after_an_exception():
+    with pytest.raises(RuntimeError):
+        with profiling.burst(profiling.BURST_STEPS + 1):
+            raise RuntimeError
+    assert isinstance(_step_span(1), jax.profiler.TraceAnnotation)
+
+
+def test_the_tiny_runs_fit_in_one_burst(runs):
+    """The span tests above see every step because their runs are shorter
+    than a burst; if one grows past it, they miss spans for this reason."""
+    for key in ("serve", "spec", "chain"):
+        assert runs["traced"][key]["steps"] + 1 <= profiling.BURST_STEPS
+
+
+def test_an_engine_step_is_annotated_whole_or_not_at_all(tmp_path):
+    """Steps BURST_STEPS and BURST_EVERY + 1 leave all their spans, the
+    steps after each of them none, and the tokens are those of a run with
+    every step in a burst."""
+    model = _lm()
+    params = model.init(jax.random.key(0))
+    prompt = np.random.default_rng(5).integers(0, 1024, size=12)
+
+    def run(jump):
+        engine = ServeEngine(model, params, num_slots=2, block_size=8,
+                             prefill_chunk=8)
+        req = engine.submit(prompt, 6, seed=0)
+        for at in jump:
+            engine._step_n = at
+            engine.step()
+            engine.step()
+        engine.run()
+        return list(req.tokens)
+
+    plain = run([0, 2])
+    with profile_trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("test.jump"):
+            traced = run([profiling.BURST_STEPS - 1, profiling.BURST_EVERY])
+    assert traced == plain
+    (_, lo, hi, _), = _read_events(str(tmp_path), "test.")
+    spans = [s for s in _read_events(str(tmp_path), profiling.PREFIX)
+             if lo <= s[1] and s[2] <= hi]
+    steps = [s for s in spans if s[0] == "tpu_ddp.serve.step"]
+    kept = [s[3]["n"] for s in steps]
+    assert kept[:2] == [profiling.BURST_STEPS, profiling.BURST_EVERY + 1]
+    assert all((n - 1) % profiling.BURST_EVERY < profiling.BURST_STEPS
+               for n in kept)
+    # nothing outside a step span: a step left out leaves no child either
+    for s in spans:
+        assert any(p[1] <= s[1] and s[2] <= p[2] for p in steps), s
+    assert {s[0] for s in spans} >= {"tpu_ddp.serve.prefill",
+                                     "tpu_ddp.serve.schedule"}

@@ -223,6 +223,95 @@ class TestEngineParity:
         assert req.done and len(req.tokens) == 5
 
 
+class TestPagedDecodeKernel:
+    """The decode step on a model inside the paged kernel's predicate
+    (head_dim 128, block 16; ops/pallas/paged_attention.py): attention
+    reads the pool in place, interpreted on the CPU. Same guarantees as
+    the gather body the tiny head_dim fixtures above keep testing."""
+
+    GEOM = dict(num_slots=4, block_size=16, prefill_chunk=8)
+    # Prompts straddle the block (16) and chunk (8) boundaries; budgets
+    # differ, six requests on four slots: slots retire and refill.
+    CASES = [(3, 6), (16, 6), (17, 12), (31, 4), (9, 20), (15, 3)]
+
+    @pytest.fixture(scope="class")
+    def wide(self):
+        m = make_transformer("TransformerLM-tiny", num_heads=4,
+                             num_kv_heads=2, d_model=512, max_seq_len=64,
+                             compute_dtype=jnp.float32)
+        assert m.head_dim == 128
+        return m
+
+    @pytest.fixture(scope="class")
+    def wparams(self, wide):
+        return wide.init(jax.random.key(1))
+
+    def test_decode_step_holds_the_kernel_and_no_gather(self, wide,
+                                                        wparams):
+        eng = ServeEngine(wide, wparams, **self.GEOM)
+        text = eng.lower_decode_step().as_text(debug_info=True)
+        assert text.count("paged_decode_attn") >= wide.num_layers
+        assert "attn/kv_write/" in text and "kv_gather" not in text
+        assert "kv_gather" in eng.lower_prefill_step().as_text(
+            debug_info=True)
+
+    def test_greedy_tokens_match_generate_logprobs_match_apply(
+            self, wide, wparams):
+        eng = ServeEngine(wide, wparams, **self.GEOM)
+        reqs = [eng.submit(_prompt(L, seed=i), n)
+                for i, (L, n) in enumerate(self.CASES)]
+        eng.run()
+        for i, ((L, n), req) in enumerate(zip(self.CASES, reqs)):
+            assert req.done and not req.cancelled
+            prompt = _prompt(L, seed=i)
+            np.testing.assert_array_equal(
+                np.asarray(req.tokens),
+                _ref_greedy(wide, wparams, prompt, n),
+                err_msg=f"request {i} (prompt {L}, max_new {n})")
+            np.testing.assert_allclose(
+                np.asarray(req.logprobs),
+                _ref_logprobs(wide, wparams, prompt, req.tokens),
+                rtol=1e-4, atol=1e-4)
+        assert eng.pool.free_count == eng.pool.total_usable
+        assert eng.sched.accounting_ok()
+
+    def test_one_decode_compile_over_40_steps_of_growing_lengths(
+            self, wide, wparams):
+        from tpu_ddp.analysis.retrace import count_compiles
+        from tpu_ddp.serve.engine import _build_decode_step
+        _build_decode_step.cache_clear()     # compile inside the block
+        eng = ServeEngine(wide, wparams, **self.GEOM)
+        reqs = [eng.submit(_prompt(L, seed=i), 40)
+                for i, L in enumerate((3, 15, 16, 17))]
+        with count_compiles(watch=("serve_decode",)) as seen:
+            steps = 0
+            while eng.step():
+                steps += 1
+        assert steps >= 40 and all(len(r.tokens) == 40 for r in reqs)
+        assert seen.counts == {"serve_decode": 1}
+
+    def test_poisoned_page_quarantines_exactly_its_own_request(
+            self, wide, wparams, monkeypatch):
+        clean = ServeEngine(wide, wparams, **self.GEOM)
+        want = [clean.submit(_prompt(L, seed=i), n)
+                for i, (L, n) in enumerate(self.CASES)]
+        clean.run()
+        monkeypatch.setenv("TPU_DDP_CHAOS_FAULTS", "nonfinite-logits@6")
+        eng = ServeEngine(wide, wparams, **self.GEOM)
+        got = [eng.submit(_prompt(L, seed=i), n)
+               for i, (L, n) in enumerate(self.CASES)]
+        with pytest.warns(UserWarning, match="quarantin"):
+            eng.run()
+        assert all(h.done for h in got)
+        assert sum(h.quarantined for h in got) == 1
+        assert eng.metrics.counters.get("serve_quarantined") == 1
+        for h, w in zip(got, want):
+            if not h.quarantined:
+                assert list(h.tokens) == list(w.tokens)
+                assert h.logprobs == w.logprobs
+        assert eng.sched.accounting_ok()
+
+
 class TestLifecycle:
     def test_no_block_leak_across_120_requests(self, model, params):
         """The acceptance drill: a pool far smaller than the offered

@@ -86,7 +86,7 @@ def _build_adopt_decode_step(model, block_size: int,
                              blocks_per_seq: int):
     """The fused transfer-landing + whole-bank decode program.
     ``adopt_ids`` (nb,) are freshly allocated (table-less) block ids;
-    ``adopt_k``/``adopt_v`` (L, nb, bs, KV, hd) is the decoded wire
+    ``adopt_k``/``adopt_v`` (L, nb, bs, KV*hd) is the decoded wire
     payload. The scatter runs FIRST so it depends on nothing the
     decode computes and nothing heavy depends on it — the dataflow
     freedom ``update_overlap_report`` verifies."""
@@ -98,9 +98,8 @@ def _build_adopt_decode_step(model, block_size: int,
             adopt_k.astype(pool_k.dtype))
         pool_v = pool_v.at[:, adopt_ids].set(
             adopt_v.astype(pool_v.dtype))
-        return decode_bank(model, block_size, blocks_per_seq, params,
-                           pool_k, pool_v, tables, lengths,
-                           last_tokens, temps, seeds)
+        return decode_bank(model, block_size, params, pool_k, pool_v,
+                           tables, lengths, last_tokens, temps, seeds)
 
     return jax.jit(step, donate_argnums=(1, 2))
 
@@ -578,12 +577,12 @@ class DisaggEngine:
             # wire carries exact-dtype bytes, never double-quantized
             # cold pages.
             kb, vb = self.prefill_pool.page_arrays(s.blocks)
-            # kb/vb: (L, nb, bs, KV, hd)
+            # kb/vb: (L, nb, bs, KV*hd)
             # Zero the garbage tail of the last block: stale positions
             # would pollute the int8 per-block quantization scales.
             valid = (np.arange(nb * self.block_size)
                      < req.prompt.size).reshape(nb, self.block_size)
-            mask = jnp.asarray(valid)[None, :, :, None, None]
+            mask = jnp.asarray(valid)[None, :, :, None]
             kb = jnp.where(mask, kb, 0)
             vb = jnp.where(mask, vb, 0)
             wire_k, n_k = self.edge.codec.encode(kb)
@@ -883,7 +882,7 @@ class DisaggEngine:
         pk = sds(self.pool.k)
         payload = jax.ShapeDtypeStruct(
             (self.model.num_layers, n_blocks, self.block_size,
-             self.model.kv_heads, self.model.head_dim), jnp.float32)
+             self.model.kv_heads * self.model.head_dim), jnp.float32)
         i32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
         return self._adopt_decode.lower(
             params, pk, pk, i32((n_blocks,)), payload, payload,

@@ -606,24 +606,24 @@ class EdgeCodec:
 
 
 def page_quantize(x, cold_dtype):
-    """Quantize KV pages ``x`` (..., bs, KV, hd) for cold storage.
+    """Quantize KV pages ``x`` (..., bs, KV*hd) for cold storage.
 
-    Returns ``(q, scale)`` with scale shaped like ``x`` minus the two
-    trailing (KV, hd) axes. ``cold_dtype`` jnp.int8 -> per-row symmetric
+    Returns ``(q, scale)`` with scale shaped like ``x`` minus the
+    trailing row axis (one scale per token row, all heads). ``cold_dtype`` jnp.int8 -> per-row symmetric
     int8; jnp.bfloat16 -> a plain downcast with unit scales (lossless
     when the hot dtype is already bf16 — the parity-testing tier)."""
     if cold_dtype == jnp.bfloat16:
         return (x.astype(jnp.bfloat16),
-                jnp.ones(x.shape[:-2], jnp.float32))
+                jnp.ones(x.shape[:-1], jnp.float32))
     xf = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xf), axis=(-2, -1))
+    amax = jnp.max(jnp.abs(xf), axis=-1)
     scale = jnp.maximum(amax / 127.0, jnp.float32(1e-30))
-    q = jnp.clip(jnp.round(xf / scale[..., None, None]), -127, 127)
+    q = jnp.clip(jnp.round(xf / scale[..., None]), -127, 127)
     return q.astype(jnp.int8), scale
 
 
 def page_dequantize(q, scale, out_dtype):
-    """Inverse of :func:`page_quantize`: (..., bs, KV, hd) pages back
+    """Inverse of :func:`page_quantize`: (..., bs, KV*hd) pages back
     in ``out_dtype`` (the pool's hot dtype)."""
     return (q.astype(jnp.float32)
-            * scale[..., None, None]).astype(out_dtype)
+            * scale[..., None]).astype(out_dtype)

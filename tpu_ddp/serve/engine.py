@@ -11,9 +11,13 @@ fused draft family; tpu_ddp/serve/speculative.py):
   zeroed block tables, so their scatters land in the null block and
   their sampled outputs are discarded host-side. Per layer it is the
   shared decode core (tpu_ddp/models/decode.py project_qkv /
-  attend_cached / block_finish) over a pool-GATHERED cache view — the
-  same math ``generate()`` runs over contiguous buffers, which is what
-  makes the engine-vs-generate parity test meaningful.
+  block_finish) around attention over the paged pool: READ IN PLACE
+  by the paged kernel (ops/pallas/paged_attention.py) where its
+  predicate takes the shapes, so a step's K/V bytes follow the live
+  context, and otherwise ``attend_cached`` over a pool-GATHERED
+  ``max_seq_len`` view. Both are the math ``generate()`` runs over
+  contiguous buffers, which is what makes the engine-vs-generate
+  parity tests meaningful.
 - ``prefill step`` — ONE ``prefill_chunk``-token slice of ONE prompt
   per call, every chunk the same static shape (short chunks padded;
   padded positions scatter to the null block and their outputs are
@@ -21,10 +25,10 @@ fused draft family; tpu_ddp/serve/speculative.py):
   long prompt can stall the decode batch: one chunk per engine step.
 
 Token positions are written BEFORE they are attended (the new token's
-K/V is scattered, then the gathered view is attended), so a query never
-reads an unwritten slot of its own sequence; everything beyond a
-query's position is causally masked to an exact zero weight
-(decode.attend_cached).
+K/V is scattered, then the pool or its gathered view is attended), so
+a query never reads an unwritten slot of its own sequence; everything
+beyond a query's position gets an exact zero weight (the kernel's
+length mask, decode.attend_cached's causal mask).
 
 Sampling is per-request and stateless (decode.sample_token): keyed by
 (request seed, absolute position), so a request replayed after
@@ -58,7 +62,13 @@ from tpu_ddp.models.decode import (
     project_qkv,
     sample_token,
 )
-from tpu_ddp.serve.kv_pool import PagedKVPool, pin_committed
+from tpu_ddp.ops.pallas import paged_attention
+from tpu_ddp.serve.kv_pool import (
+    PagedKVPool,
+    gather_view,
+    pin_committed,
+    rows,
+)
 from tpu_ddp.serve.speculative import (
     accept_length,
     build_spec_step,
@@ -73,6 +83,7 @@ from tpu_ddp.utils.metrics import MetricsLogger
 from tpu_ddp.utils.profiling import (
     SERVE_DECODE,
     SERVE_PREFILL,
+    burst,
     program,
     span,
 )
@@ -128,33 +139,50 @@ class Request:
         return self.first_token_at - self.submitted_at
 
 
-def paged_kv(pool_k, pool_v, li: int, bidx, off, k, v, tables, view):
-    """Layer ``li``'s half of the paged cache: scatter the new ``k`` /
-    ``v`` rows to ``(bidx, off)`` (scope ``kv_write``), then gather the
-    layer's pool through ``tables`` into the contiguous ``view + (KV,
-    hd)`` the decode core attends over (scope ``kv_gather``). Shared by
-    every step program over the single-tier pool, so a trace names the
-    two halves the same way in all of them."""
+def kv_write(pool_k, pool_v, li: int, bidx, off, k, v):
+    """Scatter the new ``k`` / ``v`` (n, KV, hd) into layer ``li``'s
+    pages at ``(bidx, off)``, under the scope ``kv_write``."""
     with jax.named_scope("kv_write"):
-        pool_k = pool_k.at[li, bidx, off].set(k.astype(pool_k.dtype))
-        pool_v = pool_v.at[li, bidx, off].set(v.astype(pool_v.dtype))
+        pool_k = pool_k.at[li, bidx, off].set(
+            rows(k).astype(pool_k.dtype))
+        pool_v = pool_v.at[li, bidx, off].set(
+            rows(v).astype(pool_v.dtype))
+    return pool_k, pool_v
+
+
+def paged_kv(model, pool_k, pool_v, li: int, bidx, off, k, v, tables):
+    """Layer ``li``'s half of the paged cache, the gather form: write
+    the new rows (:func:`kv_write`), then gather the layer's pool
+    through ``tables`` (S, BPS) into the contiguous (S, BPS*block_size,
+    KV, hd) view the decode core attends over (scope ``kv_gather``).
+    The prefill chunk runs it, and the decode step where the paged
+    kernel does not take the shapes, so a trace names the two halves
+    the same way in both."""
+    pool_k, pool_v = kv_write(pool_k, pool_v, li, bidx, off, k, v)
     with jax.named_scope("kv_gather"):
-        ck = pool_k[li][tables].reshape(view + pool_k.shape[3:])
-        cv = pool_v[li][tables].reshape(view + pool_v.shape[3:])
+        ck = gather_view(pool_k, li, tables, model)
+        cv = gather_view(pool_v, li, tables, model)
     return pool_k, pool_v, ck, cv
 
 
-def decode_bank(model, block_size: int, blocks_per_seq: int, params,
-                pool_k, pool_v, tables, lengths, last_tokens, temps,
-                seeds):
+def decode_bank(model, block_size: int, params, pool_k, pool_v, tables,
+                lengths, last_tokens, temps, seeds):
     """The traced body of the whole-bank decode step — one token for
     every live slot. Module-level (not a closure) so the disagg fused
     adopt+decode program (tpu_ddp/fleet/disagg.py) can prepend its
     KV-block adoption scatter and reuse the identical decode math —
     bitwise parity between fleet and single-engine output depends on
-    there being exactly ONE implementation of this body."""
-    S = tables.shape[0]
+    there being exactly ONE implementation of this body.
+
+    Attention reads the pool in place through the paged kernel
+    (ops/pallas/paged_attention.py) where its predicate over shapes and
+    dtypes takes this model, block size and pool — a step's K/V bytes
+    then follow the live context — and through the gathered
+    ``max_seq_len`` view otherwise. The choice is made here, as the
+    program is traced; both bodies attend positions ``0..lengths``."""
     cd = model.compute_dtype
+    in_place = paged_attention.supports(model.head_dim, block_size,
+                                        pool_k.dtype, cd)
     with jax.named_scope("embed"):
         x = params["embed"][last_tokens[:, None]].astype(cd)  # (S, 1, dm)
     pos = lengths[:, None]                                # (S, 1)
@@ -164,10 +192,17 @@ def decode_bank(model, block_size: int, blocks_per_seq: int, params,
     for li, blk in enumerate(params["blocks"]):
         with jax.named_scope("attn"):
             q, k, v = project_qkv(model, blk, x, pos)
-            pool_k, pool_v, ck, cv = paged_kv(
-                pool_k, pool_v, li, bidx, off, k[:, 0], v[:, 0], tables,
-                (S, blocks_per_seq * block_size))
-            o = attend_cached(model, q, ck, cv, pos)
+            if in_place:
+                pool_k, pool_v = kv_write(pool_k, pool_v, li, bidx, off,
+                                          k[:, 0], v[:, 0])
+                o = paged_attention.paged_decode_attention(
+                    q[:, 0], pool_k, pool_v, tables, lengths + 1,
+                    layer=li, kv_heads=model.kv_heads)[:, None]
+            else:
+                pool_k, pool_v, ck, cv = paged_kv(
+                    model, pool_k, pool_v, li, bidx, off, k[:, 0],
+                    v[:, 0], tables)
+                o = attend_cached(model, q, ck, cv, pos)
         x = block_finish(model, blk, x, o)
     logits = model.head_apply(params, x)[:, 0]            # (S, V)
     with jax.named_scope("sample"):
@@ -199,9 +234,8 @@ def _build_decode_step(model, block_size: int, blocks_per_seq: int):
     @program(SERVE_DECODE)
     def step(params, pool_k, pool_v, tables, lengths, last_tokens,
              temps, seeds):
-        return decode_bank(model, block_size, blocks_per_seq, params,
-                           pool_k, pool_v, tables, lengths,
-                           last_tokens, temps, seeds)
+        return decode_bank(model, block_size, params, pool_k, pool_v,
+                           tables, lengths, last_tokens, temps, seeds)
 
     return jax.jit(step, donate_argnums=(1, 2))
 
@@ -232,8 +266,8 @@ def _build_prefill_step(model, block_size: int, blocks_per_seq: int):
             with jax.named_scope("attn"):
                 q, k, v = project_qkv(model, blkp, x, p)
                 pool_k, pool_v, ck, cv = paged_kv(
-                    pool_k, pool_v, li, blk_idx, off, k[0], v[0], table,
-                    (1, blocks_per_seq * block_size))
+                    model, pool_k, pool_v, li, blk_idx, off, k[0], v[0],
+                    table[None])
                 o = attend_cached(model, q, ck, cv, p)
             x = block_finish(model, blkp, x, o)
         logits = model.head_apply(params, x)[0]               # (C, V)
@@ -735,10 +769,11 @@ class ServeEngine:
         at a fraction of its width. Matching the budgets keeps bank
         occupancy at its k=0 level."""
         self._step_n += 1
-        with span("tpu_ddp.serve.step", n=self._step_n,
-                  queue=len(self.sched.queue), live=self.sched.live,
-                  blocks_in_use=(self.pool.total_usable
-                                 - self.pool.free_count)):
+        with burst(self._step_n), \
+                span("tpu_ddp.serve.step", n=self._step_n,
+                     queue=len(self.sched.queue), live=self.sched.live,
+                     blocks_in_use=(self.pool.total_usable
+                                    - self.pool.free_count)):
             return self._step()
 
     def _step(self) -> bool:

@@ -6,8 +6,13 @@ Why paging: a contiguous per-request cache must be sized for the WORST
 case (prompt + max_new_tokens), so a fleet of short requests strands
 almost all of it. The pool instead holds one device buffer of
 fixed-size blocks per layer — ``(num_layers, num_blocks, block_size,
-KV, hd)`` — and each live request owns a list of block ids (its "block
-table"). Blocks are allocated lazily as a sequence grows and returned
+KV*hd)`` — and each live request owns a list of block ids (its "block
+table"). A position's row holds its K/V heads side by side (head ``h``
+is columns ``[h*hd, (h+1)*hd)``), so a page is one contiguous
+``(block_size, KV*hd)`` tile run that a single DMA moves and the
+paged decode kernel (ops/pallas/paged_attention.py) reads in place;
+:func:`rows` / :func:`gather_view` convert at the write and gather
+sites. Blocks are allocated lazily as a sequence grows and returned
 on retirement, so cache memory tracks the LIVE token count, not the
 worst case, and the same HBM serves many more concurrent sequences
 (the vLLM PagedAttention argument).
@@ -100,6 +105,20 @@ def pin_committed(tree):
     return jax.tree.map(lambda x: jax.device_put(x, x.sharding), tree)
 
 
+def rows(x):
+    """(..., KV, hd) K or V -> the pool's (..., KV*hd) row form."""
+    return x.reshape(x.shape[:-2] + (-1,))
+
+
+def gather_view(pool, li: int, tables, model):
+    """Layer ``li``'s pages gathered through ``tables`` (S, BPS) into
+    the contiguous (S, BPS*block_size, KV, hd) view
+    ``decode.attend_cached`` reads."""
+    pages = pool[li][tables]
+    return pages.reshape(pages.shape[0], -1, model.kv_heads,
+                         model.head_dim)
+
+
 def _pad_width(n: int) -> int:
     """Round a movement batch up to a power of two: slot vectors pad
     with slot 0 (the null page is sacrificial on BOTH tiers), so the
@@ -175,7 +194,7 @@ class PagedKVPool:
         self.tiers = tiers
         self.cold_dtype_name = cold_dtype
         self.dtype = resolve_act_dtype(cache_dtype, model.compute_dtype)
-        page = (block_size, model.kv_heads, model.head_dim)
+        page = (block_size, model.kv_heads * model.head_dim)
         # Hot buffers: at tiers == 1 the logical id IS the hot slot
         # (identity map, num_blocks slots) — the round-12 layout,
         # bitwise. At tiers > 1 hot capacity shrinks to hbm_blocks and
@@ -649,7 +668,7 @@ class PagedKVPool:
 
     def page_arrays(self, blocks):
         """Device views of ``blocks``' pages in the EXACT cache dtype,
-        shaped (L, n, bs, KV, hd) — the disagg ship path and any other
+        shaped (L, n, bs, KV*hd) — the disagg ship path and any other
         consumer that reads whole pages. Tiered pools promote to hot
         first: page readers get exact bytes, never a dequantized
         approximation the hot tier itself wouldn't serve."""

@@ -50,7 +50,7 @@ from jax import lax
 from tpu_ddp.models.decode import (attend_cached, block_finish,
                                    project_qkv, sample_token)
 from tpu_ddp.parallel.compress import page_dequantize
-from tpu_ddp.serve.kv_pool import PagedKVPool
+from tpu_ddp.serve.kv_pool import PagedKVPool, rows
 from tpu_ddp.utils.profiling import (
     SERVE_DECODE_TIERED,
     SERVE_PREFILL_CP,
@@ -65,11 +65,11 @@ def _mixed_view(hot_buf, cold_buf, cold_scale, li, hot_tables,
     and dequantized cold pages by their slot tables and select per
     block. ``hot_tables``/``cold_tables`` (S, BPS) int32, slot 0 =
     not in that tier (both null pages are zeros, kept so by scrub).
-    Returns (S, BPS, bs, KV, hd) in the hot dtype."""
+    Returns (S, BPS, bs, KV*hd) in the hot dtype."""
     hk = hot_buf[li][hot_tables]
     ck = page_dequantize(cold_buf[li][cold_tables],
                          cold_scale[li][cold_tables], hot_buf.dtype)
-    is_hot = (hot_tables > 0)[..., None, None, None]
+    is_hot = (hot_tables > 0)[..., None, None]
     return jnp.where(is_hot, hk, ck)
 
 
@@ -89,11 +89,14 @@ def tiered_decode_bank(model, block_size: int, blocks_per_seq: int,
     bidx = jnp.take_along_axis(
         hot_tables, (lengths // block_size)[:, None], axis=1)[:, 0]
     off = lengths % block_size
-    view = (S, blocks_per_seq * block_size) + hot_k.shape[3:]
+    view = (S, blocks_per_seq * block_size, model.kv_heads,
+            model.head_dim)
     for li, blk in enumerate(params["blocks"]):
         q, k, v = project_qkv(model, blk, x, pos)
-        hot_k = hot_k.at[li, bidx, off].set(k[:, 0].astype(hot_k.dtype))
-        hot_v = hot_v.at[li, bidx, off].set(v[:, 0].astype(hot_v.dtype))
+        hot_k = hot_k.at[li, bidx, off].set(
+            rows(k[:, 0]).astype(hot_k.dtype))
+        hot_v = hot_v.at[li, bidx, off].set(
+            rows(v[:, 0]).astype(hot_v.dtype))
         ck = _mixed_view(hot_k, cold_k, cold_sk, li, hot_tables,
                          cold_tables).reshape(view)
         cv = _mixed_view(hot_v, cold_v, cold_sv, li, hot_tables,
@@ -149,15 +152,16 @@ def build_tiered_prefill_step(model, block_size: int,
                             PagedKVPool.NULL_BLOCK)
         off = p % block_size
         x = params["embed"][tokens].astype(cd)
-        view = (1, blocks_per_seq * block_size) + hot_k.shape[3:]
+        view = (1, blocks_per_seq * block_size, model.kv_heads,
+                model.head_dim)
         ht = hot_table[None]
         ct = cold_table[None]
         for li, blkp in enumerate(params["blocks"]):
             q, k, v = project_qkv(model, blkp, x, p)
             hot_k = hot_k.at[li, blk_idx, off].set(
-                k[0].astype(hot_k.dtype))
+                rows(k[0]).astype(hot_k.dtype))
             hot_v = hot_v.at[li, blk_idx, off].set(
-                v[0].astype(hot_v.dtype))
+                rows(v[0]).astype(hot_v.dtype))
             ck = _mixed_view(hot_k, cold_k, cold_sk, li, ht,
                              ct).reshape(view)
             cv = _mixed_view(hot_v, cold_v, cold_sv, li, ht,
@@ -201,7 +205,7 @@ def build_cp_prefill_step(model, block_size: int, blocks_per_seq: int,
         cache_valid = jnp.arange(cache_len) < start
         x = params["embed"][tokens].astype(cd)   # (1, lc, dm)
         ks, vs = [], []
-        view = (1, cache_len) + pool_k.shape[3:]
+        view = (1, cache_len, model.kv_heads, model.head_dim)
         for li, blkp in enumerate(params["blocks"]):
             q, k, v = project_qkv(model, blkp, x, p)
             ck = pool_k[li][table].reshape(view).astype(cd)
@@ -240,8 +244,10 @@ def build_cp_prefill_step(model, block_size: int, blocks_per_seq: int,
         safe = jnp.clip(p // block_size, 0, blocks_per_seq - 1)
         blk_idx = jnp.where(valid, table[safe], PagedKVPool.NULL_BLOCK)
         off = p % block_size
-        pool_k = pool_k.at[:, blk_idx, off].set(kc.astype(pool_k.dtype))
-        pool_v = pool_v.at[:, blk_idx, off].set(vc.astype(pool_v.dtype))
+        pool_k = pool_k.at[:, blk_idx, off].set(
+            rows(kc).astype(pool_k.dtype))
+        pool_v = pool_v.at[:, blk_idx, off].set(
+            rows(vc).astype(pool_v.dtype))
         last = jnp.clip(prompt_len - 1 - start, 0, C - 1)
         tok, lp = sample_token(model, lg[last], temp, seed, prompt_len)
         return pool_k, pool_v, tok, lp

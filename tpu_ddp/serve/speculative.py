@@ -91,7 +91,7 @@ from tpu_ddp.models.decode import (
     project_qkv,
     sample_token,
 )
-from tpu_ddp.serve.kv_pool import PagedKVPool
+from tpu_ddp.serve.kv_pool import PagedKVPool, gather_view, rows
 from tpu_ddp.utils.profiling import SERVE_SPEC, program
 
 __all__ = ["parse_spec_draft", "draft_bank", "verify_bank",
@@ -141,7 +141,6 @@ def draft_bank(model, num_layers: int, block_size: int,
     target will use at that position — similar logits then make the
     same categorical draw, which is what buys the acceptance rate.
     Returns (pool_k, pool_v, proposals (S, k))."""
-    S = tables.shape[0]
     cd = model.compute_dtype
     blocks = params["blocks"][:num_layers]
 
@@ -159,12 +158,11 @@ def draft_bank(model, num_layers: int, block_size: int,
         for li, blk in enumerate(blocks):
             q, kk, vv = project_qkv(model, blk, x, pos[:, None])
             pool_k = pool_k.at[li, bidx, off].set(
-                kk[:, 0].astype(pool_k.dtype))
+                rows(kk[:, 0]).astype(pool_k.dtype))
             pool_v = pool_v.at[li, bidx, off].set(
-                vv[:, 0].astype(pool_v.dtype))
-            view = (S, blocks_per_seq * block_size) + pool_k.shape[3:]
-            ck = pool_k[li][tables].reshape(view)
-            cv = pool_v[li][tables].reshape(view)
+                rows(vv[:, 0]).astype(pool_v.dtype))
+            ck = gather_view(pool_k, li, tables, model)
+            cv = gather_view(pool_v, li, tables, model)
             o = attend_cached(model, q, ck, cv, pos[:, None])
             x = block_finish(model, blk, x, o)
         logits = model.head_apply(params, x)[:, 0]          # (S, V)
@@ -196,7 +194,7 @@ def verify_bank(model, block_size: int, blocks_per_seq: int, params,
     to the null block. Samples the target's own token at every
     position with the stateless per-position keys; returns (pool_k,
     pool_v, tokens (S, W), logprobs (S, W), bad (S, W))."""
-    S, W = tok_mat.shape
+    W = tok_mat.shape[1]
     cd = model.compute_dtype
 
     def column(pool_k, pool_v, tok, c):
@@ -212,12 +210,11 @@ def verify_bank(model, block_size: int, blocks_per_seq: int, params,
         for li, blk in enumerate(params["blocks"]):
             q, k, v = project_qkv(model, blk, x, pos[:, None])
             pool_k = pool_k.at[li, bidx, off].set(
-                k[:, 0].astype(pool_k.dtype))
+                rows(k[:, 0]).astype(pool_k.dtype))
             pool_v = pool_v.at[li, bidx, off].set(
-                v[:, 0].astype(pool_v.dtype))
-            view = (S, blocks_per_seq * block_size) + pool_k.shape[3:]
-            ck = pool_k[li][tables].reshape(view)
-            cv = pool_v[li][tables].reshape(view)
+                rows(v[:, 0]).astype(pool_v.dtype))
+            ck = gather_view(pool_k, li, tables, model)
+            cv = gather_view(pool_v, li, tables, model)
             o = attend_cached(model, q, ck, cv, pos[:, None])
             x = block_finish(model, blk, x, o)
         logits = model.head_apply(params, x)[:, 0]          # (S, V)

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import threading
 
 import jax
 
@@ -139,6 +140,8 @@ KERNELS = {
     "bn_relu_apply": "bn_relu.py: normalise, scale, ReLU",
     "bn_relu_bwd_reduce": "bn_relu.py: backward reductions",
     "bn_relu_bwd_dx": "bn_relu.py: backward dx",
+    "paged_decode_attn": "paged_attention.py: one token per slot against "
+                         "the K/V pool read in place, one call a layer",
 }
 
 # jax.named_scope at the layer boundaries of the programs the cells run.
@@ -150,7 +153,12 @@ SCOPES = {
     "kv_write": "inside attn, serving: the new K/V scattered into the "
                 "paged pool",
     "kv_gather": "inside attn, serving: the pool gathered through the "
-                 "block tables into a contiguous view",
+                 "block tables into a contiguous view. serve_prefill "
+                 "always; serve_decode and serve_adopt_decode only "
+                 "where the paged_decode_attn kernel does not take the "
+                 "shapes (they then have no such scope). serve_spec and "
+                 "the tiered and context-parallel programs gather "
+                 "without the scope",
     "mlp": "a block's MLP half: LayerNorm, MLP, residual",
     "head": "final LayerNorm and output head",
     "sample": "serving: token sampling and the non-finite check",
@@ -161,12 +169,48 @@ SCOPES = {
 }
 
 
+# ``ServeEngine.step`` annotates its steps in bursts: the first
+# BURST_STEPS of every BURST_EVERY, whole steps or nothing (see
+# :func:`burst`). Every mean a reader takes "per step" is then a mean
+# over the annotated steps.
+BURST_STEPS = 24
+BURST_EVERY = 120
+
+_thread = threading.local()     # .quiet: this thread's spans are dropped
+
+
 def span(name: str, **counts):
     """A host span on the profiler's clock, with ``counts`` as the
     event's stats (``jax.profiler.ProfileData`` gives them back as
     ``event.stats``). ``name`` is a key of :data:`SPANS`, written as a
-    literal at the call site. A no-op while no profiler session is open."""
+    literal at the call site. A no-op while no profiler session is open,
+    and inside a step that :func:`burst` left out."""
+    if getattr(_thread, "quiet", False):
+        return contextlib.nullcontext()
     return jax.profiler.TraceAnnotation(name, **counts)
+
+
+@contextlib.contextmanager
+def burst(n: int):
+    """Around iteration ``n`` (1, 2, ...) of a loop that turns over many
+    times a second: the spans this thread opens inside are recorded only
+    in the first :data:`BURST_STEPS` iterations of every
+    :data:`BURST_EVERY`.
+
+    Why: a trace's readers pair host spans with device operations, and
+    the serving engine on the chip runs some 55 steps a second of some
+    2,000 device operations each (PR 27), 3.4 times what it ran when the
+    spans went in. Consecutive whole steps, not one in five: the spans of
+    one request (``admit``, its ``prefill`` chunks, the ``decode`` steps
+    after them) stay next to each other, and a step is either all there
+    or absent, so a sum over spans divided by the ``serve.step`` spans
+    found is still a mean per step."""
+    before = getattr(_thread, "quiet", False)
+    _thread.quiet = (n - 1) % BURST_EVERY >= BURST_STEPS
+    try:
+        yield
+    finally:
+        _thread.quiet = before
 
 
 def spanned(iterable, name: str):
