@@ -259,6 +259,11 @@ class TestAtomicCutover:
         rc = eng.submit(prompt, n_new, **kw)
         while len(rc.tokens) < j:
             eng.step()
+        # The engine runs a step ahead of its readback: the token in
+        # flight was dispatched on v1 too, and run() reads it back.
+        eng.run(max_steps=0)
+        j = len(rc.tokens)
+        assert 3 <= j < n_new
         pub.publish(params=_perturb(params, 0.01), step=1)   # v2
         eng.run()
         assert rc.done and len(rc.tokens) == n_new
@@ -283,8 +288,9 @@ class TestAtomicCutover:
         pub2.publish(params=params, step=0)
         _drain(eng2, sub2)
         rr = eng2.submit(prompt, n_new, **kw)
-        while len(rr.tokens) < j:
+        while len(rr.tokens) < 3:
             eng2.step()
+        eng2.run(max_steps=0)
         pub2.publish(params=_perturb(params, 0.01), step=1)
         eng2.run()
         assert rr.tokens == rc.tokens

@@ -445,6 +445,391 @@ class TestLifecycle:
             eng.submit(_prompt(10), 20)  # 4 worst-case > 2 usable
 
 
+class TestStepAhead:
+    """The plain engine dispatches a decode step before it reads the
+    last one back (docs/DESIGN.md section 19). The reference is the same
+    engine brought to rest after every step (``run(max_steps=1)``): it
+    feeds every pending token from the host, as the engine did before
+    it ran ahead, so streams must agree bit for bit."""
+
+    # (prompt, max_new, temperature, seed, eos index or None): budgets
+    # of one and many, an EOS mid-stream, greedy and sampled, a prompt
+    # of two chunks; the last two are submitted with a step in flight.
+    MIX = [(5, 1, 0.0, 0, None), (7, 9, 0.0, 1, None),
+           (6, 8, 0.0, 2, 3), (13, 6, 0.9, 3, None),
+           (4, 7, 0.7, 4, None), (9, 5, 0.0, 5, None),
+           (3, 6, 1.1, 6, 2)]
+    LATE = 2        # how many of MIX arrive while the engine is running
+
+    @staticmethod
+    def _submit(eng, case, eos=None):
+        L, n, temp, seed, _ = case
+        return eng.submit(_prompt(L, seed=80 + seed), n, temperature=temp,
+                          seed=seed, eos_id=eos)
+
+    @classmethod
+    def _serve(cls, eng, eos, step):
+        """Serve MIX: all but the last LATE at once, those after three
+        steps. ``step`` advances the engine once."""
+        early = len(cls.MIX) - cls.LATE
+        reqs = [cls._submit(eng, c, e)
+                for c, e in zip(cls.MIX[:early], eos)]
+        for _ in range(3):
+            step(eng)
+        in_flight = eng._unread is not None
+        reqs += [cls._submit(eng, c, e)
+                 for c, e in zip(cls.MIX[early:], eos[early:])]
+        while step(eng):
+            pass
+        return reqs, in_flight
+
+    @pytest.fixture(scope="class")
+    def served(self, model, params):
+        def eos_of(case):
+            # the token the request would sample at its EOS index
+            if case[4] is None:
+                return None
+            probe = _engine(model, params)
+            r = self._submit(probe, case)
+            probe.run()
+            return int(r.tokens[case[4]])
+
+        eos = [eos_of(c) for c in self.MIX]
+        ahead = _engine(model, params)
+        got, in_flight = self._serve(ahead, eos, lambda e: e.step())
+        assert in_flight, "the late requests met an engine at rest"
+        rested = _engine(model, params)
+        want, _ = self._serve(rested, eos,
+                              lambda e: e.run(max_steps=1) > 0)
+        return {"eos": eos, "ahead": ahead, "got": got, "want": want,
+                "rested": rested}
+
+    @pytest.mark.parametrize("i", range(len(MIX)))
+    def test_stream_is_bitwise_the_rested_engines(self, served, model,
+                                                  params, i):
+        L, n, temp, seed, at = self.MIX[i]
+        got, want = served["got"][i], served["want"][i]
+        assert got.done and want.done and not got.cancelled
+        assert got.tokens == want.tokens
+        assert got.logprobs == want.logprobs          # floats, exactly
+        assert got.token_versions == want.token_versions == [0] * len(
+            got.tokens)
+        assert len(got.token_times) == len(got.tokens)
+        assert len(got.tokens) == (n if at is None else at + 1)
+        if temp == 0.0:
+            np.testing.assert_array_equal(
+                np.asarray(got.tokens),
+                _ref_greedy(model, params, _prompt(L, seed=80 + seed),
+                            n)[:len(got.tokens)])
+
+    def test_both_engines_end_empty_and_the_ahead_one_ran_ahead(
+            self, served):
+        for key in ("ahead", "rested"):
+            eng = served[key]
+            assert eng._unread is None
+            assert eng.pool.free_count == eng.pool.total_usable
+            assert eng.accounting_ok() and eng.tenant_accounting_ok()
+        c = served["ahead"].metrics.counters
+        # (MIX[0] ends on its first token, so the first decode step of
+        # all finds that token unread and no decode row: at rest)
+        assert c["serve_decode_at_rest"] == 1
+        assert c["serve_decode_ahead"] >= 8
+        r = served["rested"].metrics.counters
+        assert "serve_decode_ahead" not in r
+
+    def test_step_false_and_run_mean_nothing_in_flight(self, model,
+                                                       params):
+        eng = _engine(model, params)
+        reqs = [eng.submit(_prompt(5 + i, seed=90 + i), 4 + i)
+                for i in range(3)]
+        assert eng.step() and eng._unread is not None
+        assert eng.run(max_steps=2) == 2 and eng._unread is None
+        assert not all(r.done for r in reqs)
+        flights = []
+        while eng.step():
+            flights.append(eng._unread is not None)
+        assert eng._unread is None and all(r.done for r in reqs)
+        # the last working step only reads the one before it back
+        assert flights[-1] is False and any(flights)
+        assert not eng.step() and eng.run() == 0
+
+    def test_a_step_dispatches_before_it_reads(self, model, params):
+        """In steady state every step finds its predecessor unread:
+        ``serve_decode_ahead`` rises by one a step and
+        ``serve_decode_at_rest`` stays where the first step left it;
+        and the tokens a step hands out are the step before's."""
+        eng = _engine(model, params)
+        reqs = [eng.submit(_prompt(4, seed=95 + i), 12) for i in range(4)]
+        eng.step()                  # four slots: a chunk a step
+        c = eng.metrics.counters
+        assert c["serve_decode_at_rest"] == 1
+        assert "serve_decode_ahead" not in c
+        assert reqs[0].tokens == []             # sampled, not read yet
+        for n in range(1, 9):
+            before = [len(r.tokens) for r in reqs]
+            eng.step()
+            assert c["serve_decode_ahead"] == n
+            assert c["serve_decode_at_rest"] == 1
+            if n >= 5:
+                # all four decode; each got exactly one token, and a
+                # second sits on the device (``ahead``)
+                assert [len(r.tokens) for r in reqs] \
+                    == [b + 1 for b in before]
+                assert all(s.ahead == 1 for s in eng.sched.slots)
+        eng.run()
+        for i, r in enumerate(reqs):
+            np.testing.assert_array_equal(
+                np.asarray(r.tokens),
+                _ref_greedy(model, params, _prompt(4, seed=95 + i), 12))
+
+    def test_ahead_means_a_decode_step_unread_at_k0(self, model, params):
+        """``ahead`` is 1 where the DECODE step before is unread: a
+        final chunk's first token alone on the device is the engine at
+        rest. The speculative steps read back at once and count
+        neither way."""
+        eng = _engine(model, params)
+        eng.submit(_prompt(5, seed=97), 1)      # ends on its first token
+        late = eng.submit(_prompt(6, seed=98), 4)
+        eng.step()
+        assert eng._unread.firsts and not eng._unread.rows
+        eng.step()                  # late's chunk and its first row
+        c = eng.metrics.counters
+        assert c["serve_decode_at_rest"] == 1 and late.tokens == []
+        assert "serve_decode_ahead" not in c
+        eng.step()
+        assert c["serve_decode_ahead"] == 1
+        eng.run()
+        chain = _engine(model, params, spec_k=2, spec_draft="chain")
+        r = chain.submit(_prompt(6, seed=98), 4)
+        chain.run()
+        assert r.tokens == late.tokens
+        assert not {"serve_decode_ahead", "serve_decode_at_rest"} \
+            & set(chain.metrics.counters)
+
+    @pytest.mark.parametrize("busy", [False, True],
+                             ids=["engine_at_rest", "decode_in_flight"])
+    def test_first_token_is_out_by_the_step_after_its_chunk(
+            self, model, params, busy):
+        """What running ahead costs a new request (PERF.md section 7,
+        time to first token): its first token waits on the device for
+        ONE ``step()`` past the step that dispatched its final chunk,
+        and no longer, with or without another request's decode step
+        in flight before the chunk."""
+        eng = _engine(model, params)
+        if busy:
+            eng.submit(_prompt(4, seed=140), 30)
+            for _ in range(3):
+                eng.step()
+            assert eng._unread is not None and eng._unread.rows
+        req = eng.submit(_prompt(13, seed=141), 5)      # two chunks
+        eng.step()
+        slot = next(s for s in eng.sched.slots
+                    if s is not None and s.request is req)
+        assert slot.phase == "prefill" and slot.prefill_done == 8
+        eng.step()                                      # the final chunk
+        assert slot.phase == "decode" and slot.first_unread
+        assert req.tokens == [] and req.first_token_at is None
+        eng.step()
+        # ... and the row the same step decoded from it, on the device
+        assert len(req.tokens) == 2 and not slot.first_unread
+        assert req.first_token_at == req.token_times[0]
+        eng.run()
+        np.testing.assert_array_equal(
+            np.asarray(req.tokens),
+            _ref_greedy(model, params, _prompt(13, seed=141), 5))
+
+    # ---- what brings the engine to rest ------------------------------
+
+    # (prompt, max_new, temperature) of the four requests of _midflight
+    CASES = [(6, 14, 0.0), (9, 12, 0.8), (5, 16, 0.0), (12, 10, 0.6)]
+
+    def _midflight(self, model, params, monkeypatch=None, chaos=None,
+                   **kw):
+        """Four requests, stepped until all decode with a step in
+        flight; and the streams an undisturbed engine gives them."""
+        def submit(e):
+            return [e.submit(_prompt(L, seed=110 + i), n, temperature=t,
+                             seed=i, tenant="ab"[i % 2])
+                    for i, (L, n, t) in enumerate(self.CASES)]
+
+        clean = _engine(model, params, **kw)
+        want = submit(clean)
+        clean.run()
+        if chaos:
+            monkeypatch.setenv("TPU_DDP_CHAOS_FAULTS", chaos)
+        eng = _engine(model, params, **kw)
+        got = submit(eng)
+        for _ in range(7):
+            eng.step()
+        assert eng._unread is not None and len(eng._unread.rows) == 4
+        assert all(0 < len(r.tokens) < r.max_new_tokens for r in got)
+        return eng, got, want
+
+    @staticmethod
+    def _books_ok(eng):
+        return eng.accounting_ok() and eng.tenant_accounting_ok()
+
+    def test_cancel_hands_out_the_step_in_flight_first(self, model,
+                                                       params):
+        eng, got, want = self._midflight(model, params)
+        had = [len(r.tokens) for r in got]
+        assert eng.cancel(got[1])
+        assert eng._unread is None and self._books_ok(eng)
+        # every request, the victim too, first got its token in flight
+        assert [len(r.tokens) for r in got] == [h + 1 for h in had]
+        eng.step()
+        assert eng.metrics.counters["serve_decode_at_rest"] == 2
+        eng.run()
+        for i, (g, w) in enumerate(zip(got, want)):
+            if i == 1:
+                assert g.cancelled and g.tokens == w.tokens[:had[1] + 1]
+            else:
+                assert g.tokens == w.tokens and g.logprobs == w.logprobs
+        assert eng.pool.free_count == eng.pool.total_usable
+        assert self._books_ok(eng)
+
+    def test_cancel_after_the_last_token_in_flight_finds_it_done(
+            self, model, params):
+        eng = _engine(model, params)
+        req = eng.submit(_prompt(5, seed=120), 3)
+        while len(req.tokens) < 2:
+            eng.step()
+        assert not req.done and eng._unread is not None
+        assert not eng.cancel(req)          # the token in flight ended it
+        assert req.done and not req.cancelled and len(req.tokens) == 3
+        assert self._books_ok(eng)
+        assert eng.pool.free_count == eng.pool.total_usable
+
+    def test_drain_loses_and_replays_no_token(self, model, params):
+        from tpu_ddp.fleet.resilience import continuation_of
+        eng, got, want = self._midflight(model, params)
+        had = [len(r.tokens) for r in got]
+        harvested = eng.drain()
+        assert eng._unread is None and self._books_ok(eng)
+        assert [r.rid for r in harvested] == [r.rid for r in got]
+        assert [len(r.tokens) for r in got] == [h + 1 for h in had]
+        assert eng.pool.free_count == eng.pool.total_usable
+        assert not eng.step()
+        # replayed elsewhere, each continues exactly where it stopped
+        other = _engine(model, params)
+        for (_, _, t), g, w in zip(self.CASES, got, want):
+            prompt, left = continuation_of(g)
+            rest = other.submit(prompt, left, temperature=t, seed=g.seed)
+            other.run()
+            assert g.tokens + rest.tokens == w.tokens
+
+    def test_swap_params_stamps_the_version_of_the_dispatch(self, model,
+                                                            params):
+        eng, got, want = self._midflight(model, params)
+        had = [len(r.tokens) for r in got]
+        eng.swap_params(eng.params, 7)      # same weights, new version
+        assert eng._unread is None
+        eng.step()
+        assert eng.metrics.counters["serve_decode_at_rest"] == 2
+        eng.run()
+        for g, w, h in zip(got, want, had):
+            assert g.tokens == w.tokens and g.logprobs == w.logprobs
+            # the token in flight at the flip was dispatched on 0
+            assert g.token_versions == [0] * (h + 1) \
+                + [7] * (len(g.tokens) - h - 1)
+        assert self._books_ok(eng)
+
+    def test_nonfinite_drill_finds_the_engine_at_rest(self, model,
+                                                      params, monkeypatch):
+        eng, got, want = self._midflight(
+            model, params, monkeypatch, chaos="nonfinite-logits@8")
+        scrubbed, victims = [], []
+        scrub, quarantine = eng.pool.scrub, eng._quarantine
+        monkeypatch.setattr(eng.pool, "scrub",
+                            lambda b: (scrubbed.extend(b), scrub(b))[1])
+        monkeypatch.setattr(
+            eng, "_quarantine",
+            lambda i: (victims.append(list(eng.sched.slots[i].blocks)),
+                       quarantine(i))[1])
+        at_rest = eng.metrics.counters["serve_decode_at_rest"]
+        with pytest.warns(UserWarning, match="quarantin"):
+            eng.run()
+        assert eng.metrics.counters["serve_decode_at_rest"] == at_rest + 1
+        assert [h.quarantined for h in got] == [True, False, False, False]
+        assert len(victims) == 1 and sorted(scrubbed) == sorted(victims[0])
+        assert got[0].tokens == want[0].tokens[:len(got[0].tokens)]
+        assert len(got[0].tokens) < len(want[0].tokens)
+        for g, w in zip(got[1:], want[1:]):
+            assert g.tokens == w.tokens and g.logprobs == w.logprobs
+        assert self._books_ok(eng)
+        assert eng.pool.free_count == eng.pool.total_usable
+
+    def test_nonfinite_drill_is_asked_only_of_a_step_that_decodes(
+            self, model, params, monkeypatch, capsys):
+        """``poison_due`` brings the engine to rest and marks nothing;
+        ``poison_fires`` (which announces, and spends a one-shot drill)
+        is asked only where there is a decode slot to poison."""
+        monkeypatch.setenv("TPU_DDP_CHAOS_FAULTS", "nonfinite-logits@1")
+        eng = _engine(model, params)
+        req = eng.submit(_prompt(13, seed=150), 4)  # step 1: half a prompt
+        assert eng.chaos.poison_due(1) and eng.chaos.poison_due(1)
+        assert not eng.chaos.poison_due(2)
+        eng.run()
+        assert "[chaos]" not in capsys.readouterr().out
+        assert not req.quarantined and len(req.tokens) == 4
+
+    def test_row_past_a_quarantine_is_dropped_and_scrubbed_after(
+            self, model, params):
+        """Non-finite logits are an end the host cannot see: when the
+        flag is read, one more row of the victim is in flight. It wrote
+        into the victim's own blocks, which are scrubbed in stream
+        order after it, and its sample is dropped."""
+        eng, got, want = self._midflight(model, params)
+        victim = eng.sched.slots[2]
+        blocks = list(victim.blocks)
+        # behind the step in flight in the stream: the next step reads it
+        eng.pool.v = eng.pool.v.at[:, blocks[0]].set(jnp.nan)
+        had = len(got[2].tokens)
+        with pytest.warns(UserWarning, match="quarantin"):
+            eng.step()      # dispatches on the NaN, reads the clean step
+            assert len(got[2].tokens) == had + 1 and not got[2].done
+            eng.step()      # reads the flag with one more row in flight
+        assert got[2].quarantined and len(got[2].tokens) == had + 1
+        assert eng.sched.slots[2] is None and victim.ahead == 1
+        assert eng._unread.rows[2] is victim            # the row past it
+        # scrubbed after that row wrote: its pages hold nothing at all
+        mine = np.asarray(sorted(set(blocks) | set(victim.blocks)))
+        assert not np.asarray(eng.pool.v[:, mine]).any()
+        assert not np.asarray(eng.pool.k[:, mine]).any()
+        eng.run()
+        assert self._books_ok(eng)
+        assert eng.pool.free_count == eng.pool.total_usable
+        assert np.isfinite(np.asarray(eng.pool.v)).all()
+        assert got[2].tokens == want[2].tokens[:had + 1]
+        for i in (0, 1, 3):
+            assert got[i].tokens == want[i].tokens
+            assert got[i].logprobs == want[i].logprobs
+
+    def test_row_past_an_eos_lands_in_the_slots_own_freed_blocks(
+            self, model, params):
+        """EOS is read one step late: the row dispatched past it wrote
+        into blocks that are free again by then, and a request admitted
+        into them is served exactly."""
+        probe = _engine(model, params)
+        p = probe.submit(_prompt(6, seed=130), 12)
+        probe.run()
+        eng = _engine(model, params, num_blocks=4)      # 3 usable pages
+        a = eng.submit(_prompt(6, seed=130), 12, eos_id=int(p.tokens[4]))
+        b = eng.submit(_prompt(7, seed=131), 10)        # waits for a's
+        while not a.done:
+            eng.step()
+            assert eng.accounting_ok()
+        stale = eng._unread
+        assert a.tokens == p.tokens[:5]
+        assert stale is not None and len(stale.rows) == 1   # past the EOS
+        eng.run()
+        assert b.done and eng.pool.free_count == eng.pool.total_usable
+        np.testing.assert_array_equal(
+            np.asarray(b.tokens),
+            _ref_greedy(model, params, _prompt(7, seed=131), 10))
+        assert a.tokens == p.tokens[:5]                 # nothing appended
+
+
 class TestSampling:
     def test_seeded_sampling_survives_rebatching(self, model, params):
         """Sampling is keyed by (request seed, absolute position) —

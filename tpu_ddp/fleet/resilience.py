@@ -198,6 +198,14 @@ class ServeFaultInjector(FaultInjector):
                 return True
         return False
 
+    def poison_due(self, step: int) -> bool:
+        """Whether :meth:`poison_fires` would fire at this engine step,
+        asked without announcing or marking it: ``ServeEngine`` runs one
+        decode step ahead of its readback and comes to rest before a
+        step that may corrupt pages from the host."""
+        return any(spec.kind == "nonfinite-logits"
+                   and self._fires(spec, step) for spec in self.specs)
+
     def poison_fires(self, step: int) -> bool:
         """True when this engine step must corrupt one live request's
         KV pages with NaN (the ``nonfinite-logits`` drill: the decode

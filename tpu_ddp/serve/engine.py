@@ -24,6 +24,14 @@ fused draft family; tpu_ddp/serve/speculative.py):
   masked by the causal position test). Chunking bounds how long a
   long prompt can stall the decode batch: one chunk per engine step.
 
+The plain engine (``spec_k == 0``) runs ONE DECODE STEP AHEAD of its
+readback: a step dispatches its prefill chunk and its decode program
+first and only then reads the step before it back, so the device goes
+from one program to the next while the host emits, schedules and builds
+tables beside it. The one datum a step needs from the step before, each
+slot's sampled token, stays on the device (``serve_feed``); docs/DESIGN.md
+§19 has the rules.
+
 Token positions are written BEFORE they are attended (the new token's
 K/V is scattered, then the pool or its gathered view is attended), so
 a query never reads an unwritten slot of its own sequence; everything
@@ -82,6 +90,7 @@ from tpu_ddp.serve.scheduler import (
 from tpu_ddp.utils.metrics import MetricsLogger
 from tpu_ddp.utils.profiling import (
     SERVE_DECODE,
+    SERVE_FEED,
     SERVE_PREFILL,
     burst,
     program,
@@ -278,6 +287,39 @@ def _build_prefill_step(model, block_size: int, blocks_per_seq: int):
         return pool_k, pool_v, tok, lp
 
     return jax.jit(step, donate_argnums=(1, 2))
+
+
+@jax.jit
+@program(SERVE_FEED)
+def _feed(host, sampled, first=None):
+    """Each slot's pending token, from where it is. ``host`` (2, S)
+    int32: row 0 the tokens the host has, row 1 where to look instead
+    (1: ``sampled``, the (S,) samples of the decode step before, still
+    unread; 2: ``first``, the first token of the final prefill chunk
+    dispatched this step, a scalar; given only on such a step). It
+    moves values and computes none, so the decode program sees the
+    token it would have been handed by the host."""
+    last, where = host
+    if first is not None:
+        last = jnp.where(where == 2, first, last)
+    return jnp.where(where == 1, sampled, last)
+
+
+@dataclasses.dataclass
+class _Unread:
+    """What one engine step sampled and left on the device: the first
+    tokens of its final prefill chunks and the rows of its decode step,
+    with the slot each belongs to (the ``SlotState`` itself: a slot
+    retired and refilled meanwhile is another object) and the parameter
+    version they were dispatched on."""
+
+    version: int
+    firsts: list = dataclasses.field(default_factory=list)  # (idx, slot, tok, lp)
+    rows: dict = dataclasses.field(default_factory=dict)    # idx -> slot
+    out: tuple = ()     # the decode step's (toks, lps, bad), on the device
+
+    def __bool__(self) -> bool:
+        return bool(self.firsts or self.rows)
 
 
 class ServeEngine:
@@ -497,6 +539,11 @@ class ServeEngine:
         if self.shed_ms < 0:
             raise ValueError("shed_ms must be >= 0")
         self._step_n = 0
+        # The step before's samples, dispatched and not yet read back
+        # (None: the engine is at rest), and the newest decode step's
+        # sampled tokens on the device, which the next step feeds from.
+        self._unread: _Unread | None = None
+        self._sampled = jnp.zeros(self.num_slots, jnp.int32)
         # Weight streaming (tpu_ddp/publish/): the served version id
         # and the subscriber that advances it. ``swap_params`` is the
         # ONLY mutation path for ``self.params`` after construction —
@@ -742,6 +789,11 @@ class ServeEngine:
         if req in self.sched.queue:
             self.sched.queue.remove(req)
         else:
+            # A live request may have a row in the step in flight: its
+            # tokens are handed out first, and may be its last.
+            self._rest()
+            if req.done:
+                return False
             for i, s in enumerate(self.sched.slots):
                 if s is not None and s.request is req:
                     self.sched.retire(i)
@@ -759,7 +811,14 @@ class ServeEngine:
 
     def step(self) -> bool:
         """One engine iteration: admit, prefill, one whole-batch
-        decode step. Returns whether any work ran.
+        decode step. Returns whether any work ran; ``False`` also
+        means that nothing is in flight.
+
+        At ``spec_k == 0`` the step dispatches its prefill chunk and
+        its decode program first and then hands out the tokens of the
+        step BEFORE it (:meth:`_harvest`), so a token reaches its
+        request one ``step()`` after the step that sampled it, and the
+        host's work runs beside the device's (docs/DESIGN.md §19).
 
         Prefill budget: at most one chunk per step at ``spec_k == 0``
         (the latency-smoothing default), ``spec_k + 1`` chunks when
@@ -783,12 +842,16 @@ class ServeEngine:
                 # mutation, so a router-harvested engine is always
                 # consistent.
                 self.chaos.replica_step(self._step_n)
+                # The non-finite drill corrupts pool pages from the
+                # host: it finds the engine at rest.
+                if self.chaos.poison_due(self._step_n):
+                    self._rest()
             if self.subscriber is not None:
                 # Weight streaming: stage at most one delta bucket, flip
                 # the version when an update completes — BETWEEN steps,
                 # so a flip is atomic at token granularity (the token
                 # this step samples is entirely on the flipped-to
-                # version).
+                # version; swap_params brings the engine to rest first).
                 self.subscriber.on_engine_step()
             self._shed_expired()
             admitted = self.sched.admit()
@@ -805,7 +868,12 @@ class ServeEngine:
                           cached_tokens=s.prefill_done):
                     pass
             pi = self.sched.prefill_slot()
-        did = False
+        # ``before``: what the step before left unread; ``mine``: what
+        # this step leaves. Nothing below reads ``before`` back until
+        # this step's programs are dispatched.
+        before = self._unread
+        mine = _Unread(self.param_version)
+        did = before is not None
 
         budget = self.spec_k + 1 if self.spec_k > 0 else 1
         for chunk in range(budget):
@@ -815,21 +883,38 @@ class ServeEngine:
             if pi is None:
                 break
             did = True
-            self._run_prefill_chunk(pi)
+            self._run_prefill_chunk(pi, mine)
 
+        if self.spec_k > 0:
+            # The speculative steps have synchronous bodies of their
+            # own, which read ``pending_token`` on the host: the first
+            # tokens of this step's chunks are handed out before them.
+            self._unread = mine or None
+            self._rest()
         with span("tpu_ddp.serve.schedule"):
             dslots = self.sched.decode_slots()
         if dslots:
             did = True
+            # ahead: the decode step before this one is still unread
+            # (a final chunk's first token alone does not count)
+            ahead = int(before is not None and bool(before.rows))
+            if self.spec_k == 0:
+                self.metrics.inc("serve_decode_ahead" if ahead
+                                 else "serve_decode_at_rest")
             with span("tpu_ddp.serve.decode", slots=len(dslots),
-                      context_tokens=sum(self.sched.slots[i].length
-                                         for i in dslots)):
+                      context_tokens=sum(
+                          self.sched.slots[i].length
+                          + self.sched.slots[i].ahead for i in dslots),
+                      ahead=ahead):
                 if self.spec_k > 0 and self._spec_kind == "chain":
                     self._run_chain_step(dslots)
                 elif self.spec_k > 0:
                     self._run_spec_step(dslots)
                 else:
-                    self._run_decode_step(dslots)
+                    self._run_decode_step(dslots, mine)
+                    self._turn_over(before, mine)
+        elif self.spec_k == 0:
+            self._turn_over(before, mine)
 
         self.metrics.observe("serve_queue_depth",
                              len(self.sched.queue))
@@ -845,6 +930,7 @@ class ServeEngine:
             if not self.step():
                 break
             n += 1
+        self._rest()    # max_steps can stop it with a step in flight
         return n
 
     def swap_params(self, params, version: int) -> None:
@@ -853,7 +939,10 @@ class ServeEngine:
         steps). The tree must match the current layout bitwise in
         shapes/dtypes — then both compiled step programs are reused
         as-is (params are a jit *argument*, pinned by the no-retrace
-        test), and the very next decode step samples on ``version``."""
+        test), and the very next decode step samples on ``version``.
+        The step in flight is read back first: its tokens carry the
+        version they were dispatched on."""
+        self._rest()
         self.params = params
         self.param_version = int(version)
         # Quantized serving re-derives the int8 decode tree from the
@@ -933,16 +1022,17 @@ class ServeEngine:
         t[:len(slot.blocks)] = slot.blocks
         return t
 
-    def _run_prefill_chunk(self, pi: int) -> None:
+    def _run_prefill_chunk(self, pi: int, mine: _Unread) -> None:
         s = self.sched.slots[pi]
         start = s.prefill_done
         end = min(start + self.prefill_chunk, int(s.request.prompt.size))
         with span("tpu_ddp.serve.prefill", rid=s.request.rid,
                   tokens=end - start, start=start,
                   final=int(end >= s.request.prompt.size)):
-            self._prefill_chunk(pi, s, start)
+            self._prefill_chunk(pi, s, start, mine)
 
-    def _prefill_chunk(self, pi: int, s, start: int) -> None:
+    def _prefill_chunk(self, pi: int, s, start: int,
+                       mine: _Unread) -> None:
         req = s.request
         C = self.prefill_chunk
         chunk = np.zeros((1, C), np.int32)
@@ -978,21 +1068,26 @@ class ServeEngine:
         s.prefill_done = min(start + C, int(req.prompt.size))
         s.length = s.prefill_done
         if s.prefill_done >= req.prompt.size:
-            # Register BEFORE emitting: _emit may retire the slot
-            # (max_new_tokens == 1), and the index must take its
-            # holder refs while the blocks are still live.
+            # Register at dispatch, so before the first token is
+            # emitted: _emit may retire the slot (max_new_tokens == 1),
+            # and the index must take its holder refs while the blocks
+            # are still live.
             if self.prefix is not None:
                 self.prefix.register(req.prompt, s.blocks,
                                      ns=tenant_of(req))
             s.phase = "decode"
-            self._emit(pi, int(tok), float(lp))  # the first token
+            # The first token stays on the device: this step's decode
+            # program is fed from there, the host reads it a step on.
+            s.first_unread = True
+            mine.firsts.append((pi, s, tok, lp))
 
     def _maybe_poison(self, dslots: list[int]) -> None:
         """The ``nonfinite-logits`` chaos drill: corrupt ONE live
         request's private KV pages with NaN host-side. The poison
         reaches the victim's logits through its own gathered cache
         view only (disjoint block tables), so the in-graph ``bad``
-        flag must isolate exactly that slot."""
+        flag must isolate exactly that slot. The step it is due on
+        began by bringing the engine to rest."""
         if self.chaos is None or not dslots \
                 or not self.chaos.poison_fires(self._step_n):
             return
@@ -1009,13 +1104,23 @@ class ServeEngine:
             blk = self.pool.hot_slot(blk)
         self.pool.v = self.pool.v.at[:, blk].set(jnp.nan)
 
-    def _run_decode_step(self, dslots: list[int]) -> None:
+    def _run_decode_step(self, dslots: list[int], mine: _Unread) -> None:
+        """Dispatch one token for every slot of ``dslots`` and leave
+        the samples on the device, recorded in ``mine``. Everything
+        here is built from what the host knows a step ahead: a slot
+        whose last decode row is still unread (``ahead``) writes at
+        ``length + 1`` and is fed that row's sample by ``serve_feed``;
+        one whose final prefill chunk went out this step is fed the
+        chunk's first token the same way."""
         S, BPS = self.num_slots, self.blocks_per_seq
         tiered = self.pool.tiers > 1
+        slots = [self.sched.slots[i] for i in dslots]
         with span("tpu_ddp.serve.decode.tables"):
             tables = np.zeros((S, BPS), np.int32)
             lengths = np.zeros(S, np.int32)
-            last = np.zeros(S, np.int32)
+            # row 0: the pending tokens the host has; row 1: where the
+            # others are (_feed)
+            last = np.zeros((2, S), np.int32)
             temps = np.zeros(S, np.float32)
             seeds = np.zeros(S, np.int32)
             if tiered:
@@ -1025,56 +1130,118 @@ class ServeEngine:
                 # batched call so no frontier evicts another.
                 self._maybe_poison(dslots)
                 allblocks, frontiers = [], []
-                for i in dslots:
-                    self.sched.ensure_block(i)
-                    s = self.sched.slots[i]
+                for i, s in zip(dslots, slots):
+                    self.sched.ensure_blocks(i, 1 + s.ahead)
                     allblocks.extend(s.blocks)
                     frontiers.append(
-                        s.blocks[s.length // self.block_size])
+                        s.blocks[(s.length + s.ahead) // self.block_size])
                 self.pool.ensure_device(allblocks)
                 self.pool.ensure_hot(frontiers, keep=allblocks)
                 cold_tables = np.zeros((S, BPS), np.int32)
-            for i in dslots:
-                if not tiered:
-                    self.sched.ensure_block(i)
-                s = self.sched.slots[i]
+            for i, s in zip(dslots, slots):
                 if tiered:
                     tables[i], cold_tables[i] = self.pool.slot_tables(
                         s.blocks, BPS)
                 else:
+                    # the block of the position this row writes: one
+                    # past ``length`` where the row before is unread
+                    self.sched.ensure_blocks(i, 1 + s.ahead)
                     tables[i] = self._table_for(s)
-                lengths[i] = s.length
-                last[i] = s.pending_token
+                lengths[i] = s.length + s.ahead
+                if s.ahead:
+                    last[1, i] = 1
+                elif s.first_unread:
+                    last[1, i] = 2
+                else:
+                    last[0, i] = s.pending_token
                 temps[i] = s.request.temperature
                 seeds[i] = s.request.seed
             if not tiered:
                 self._maybe_poison(dslots)
         with span("tpu_ddp.serve.decode.dispatch"):
+            first = ()
+            if 2 in last[1]:
+                # this step's one final chunk (the speculative steps,
+                # with their larger chunk budget, do not come here)
+                (_, _, tok, _), = mine.firsts
+                first = (tok,)
+            d_last = _feed(jnp.asarray(last), self._sampled, *first)
             if tiered:
                 k, v, toks, lps, bad = self._tiered_decode(
                     self._decode_params, self.pool.k, self.pool.v,
                     self.pool.cold_k, self.pool.cold_v,
                     self.pool.cold_sk, self.pool.cold_sv,
                     jnp.asarray(tables), jnp.asarray(cold_tables),
-                    jnp.asarray(lengths), jnp.asarray(last),
+                    jnp.asarray(lengths), d_last,
                     jnp.asarray(temps), jnp.asarray(seeds))
             else:
                 k, v, toks, lps, bad = self._decode(
                     self._decode_params, self.pool.k, self.pool.v,
                     jnp.asarray(tables), jnp.asarray(lengths),
-                    jnp.asarray(last), jnp.asarray(temps),
+                    d_last, jnp.asarray(temps),
                     jnp.asarray(seeds))
             self.pool.commit(k, v)
+            mine.rows = dict(zip(dslots, slots))
+            mine.out = (toks, lps, bad)
+            for out in mine.out:
+                out.copy_to_host_async()
+            self._sampled = toks
+            for s in slots:
+                s.ahead += 1
+
+    def _rest(self) -> None:
+        """Bring the engine to rest: read back and hand out what the
+        last step left on the device. Whatever touches slot or pool
+        state between steps calls this first (``cancel``, ``drain``,
+        ``swap_params``, the non-finite drill, the speculative steps);
+        ``run()`` ends with it."""
+        unread, self._unread = self._unread, None
+        if unread is not None:
+            self._harvest(unread)
+
+    def _turn_over(self, before: _Unread | None, mine: _Unread) -> None:
+        """The end of a plain step: what it dispatched is now the
+        unread work, and the step before's is read back."""
+        self._unread = mine or None
+        if before is not None:
+            self._harvest(before)
+
+    def _harvest(self, unread: _Unread) -> None:
+        """Read one step's samples back and hand them out in the order
+        the synchronous engine did: the first tokens of its final
+        chunks, then its decode rows. Inside a step this comes after
+        the step's own dispatches, so each wait is for a program that
+        is finishing while the next is queued behind it; a first token
+        is handed out when its chunk is done and does not wait for the
+        decode program behind the chunk.
+
+        A row whose slot is gone was dispatched past an end the host
+        could not see (EOS or non-finite logits on the token before):
+        it wrote at an in-budget position of that slot's own blocks,
+        which were freed or scrubbed in stream order after it, and its
+        sample is dropped (DESIGN.md §19)."""
+        if unread.firsts:
+            with span("tpu_ddp.serve.decode.fetch"):
+                firsts = [(int(tok), float(lp))
+                          for _, _, tok, lp in unread.firsts]
+            with span("tpu_ddp.serve.decode.emit"):
+                for (i, s, _, _), (tok, lp) in zip(unread.firsts, firsts):
+                    s.first_unread = False
+                    self._emit(i, tok, lp, unread.version)
+        if not unread.rows:
+            return
         with span("tpu_ddp.serve.decode.fetch"):
-            toks, lps, bad = (np.asarray(toks), np.asarray(lps),
-                              np.asarray(bad))
+            toks, lps, bad = map(np.asarray, unread.out)
         with span("tpu_ddp.serve.decode.emit"):
-            for i in dslots:
+            for i, s in unread.rows.items():
+                if self.sched.slots[i] is not s:
+                    continue
+                s.ahead -= 1
                 if bad[i]:
                     self._quarantine(i)
                     continue
-                self.sched.slots[i].length += 1
-                self._emit(i, int(toks[i]), float(lps[i]))
+                s.length += 1
+                self._emit(i, int(toks[i]), float(lps[i]), unread.version)
 
     def _run_chain_step(self, dslots: list[int]) -> None:
         """The "chain" speculative schedule (spec_draft="chain"): one
@@ -1089,22 +1256,22 @@ class ServeEngine:
         host/dispatch round trip and the output sync are paid once
         per window instead of once per token.
 
-        Freezing is two-phase. Budget exhaustion (``max_new_tokens``)
-        is HOST-PREDICTABLE, so the per-column ``act`` mask is
-        precomputed: a slot past its budget is frozen on device to
-        the idle pattern (zeroed table row, length/last 0 — writes
-        land in the null block, outputs discarded at harvest), which
-        also caps every write at position ``< prompt + max_new``,
-        inside the blocks ``ensure_blocks`` pre-allocated. EOS and
-        non-finite truncation are NOT predictable; their tail columns
-        compute discarded garbage into the slot's OWN pre-allocated
-        blocks at in-budget positions (beyond the final length —
-        causally masked, freed at harvest-time retire, scrubbed on
-        quarantine), never into anyone else's — the harvest loop
-        stops at the EOS/bad column exactly like the synced
-        column-at-a-time schedule would. Frozen rows cannot perturb
-        live rows: every bank op is row-independent at fixed shapes
-        (the property the migration/rebatching parity tests pin).
+        Freezing is two-phase, by the rules every step dispatched
+        ahead of its harvest follows (docs/DESIGN.md §19, "The step is
+        one ahead of its harvest"; the plain engine's one-step-ahead
+        dispatch is the same discipline with a window of one). Budget
+        exhaustion (``max_new_tokens``) is HOST-PREDICTABLE, so the
+        per-column ``act`` mask is precomputed: a slot past its budget
+        is frozen on device to the idle pattern (zeroed table row,
+        length/last 0). EOS and non-finite truncation are NOT
+        predictable: their tail columns are the rows "dispatched past
+        an end the host cannot see" of §19 (the slot's own in-budget
+        positions, output dropped, blocks freed or scrubbed in stream
+        order after them), and the harvest loop stops at the EOS/bad
+        column exactly like the synced column-at-a-time schedule
+        would. Frozen rows cannot perturb live rows: every bank op is
+        row-independent at fixed shapes (the property the
+        migration/rebatching parity tests pin).
 
         Acceptance is 1 by construction (no rollback); the ledger
         counts each emitted non-first column as an accepted proposal
@@ -1382,7 +1549,9 @@ class ServeEngine:
         state (slots retire, pages free back to THIS pool, queue
         clears) — the router's failure-migration hook. Returns the
         harvested requests in submit order so replay elsewhere
-        preserves FIFO fairness."""
+        preserves FIFO fairness. The step in flight is handed out
+        first, so no sampled token is lost or replayed."""
+        self._rest()
         reqs = []
         for i, s in enumerate(self.sched.slots):
             if s is not None:
@@ -1399,16 +1568,20 @@ class ServeEngine:
             self._tc(tenant_of(r))["submitted"] -= 1
         return harvested
 
-    def _emit(self, idx: int, tok: int, logprob: float) -> None:
+    def _emit(self, idx: int, tok: int, logprob: float,
+              version: int | None = None) -> None:
         """Record one sampled token for slot ``idx``'s request: stream
-        it, stamp TTFT on the first, retire on max_new_tokens/EOS."""
+        it, stamp TTFT on the first, retire on max_new_tokens/EOS.
+        ``version`` is the parameter version its step was dispatched
+        on (the current one where the step was read back at once)."""
         s = self.sched.slots[idx]
         req = s.request
         s.generated += 1
         s.pending_token = tok
         req.tokens.append(tok)
         req.logprobs.append(logprob)
-        req.token_versions.append(self.param_version)
+        req.token_versions.append(
+            self.param_version if version is None else version)
         now = time.perf_counter()
         req.token_times.append(now)
         if req.first_token_at is None:

@@ -152,6 +152,19 @@ class SlotState:
     pending_token: int = 0   # sampled but not yet fed through the model
     blocks: list = dataclasses.field(default_factory=list)
     reserved: int = 0        # worst-case TOTAL blocks for this request
+    # What the engine has dispatched for this slot and not yet read back
+    # (ServeEngine runs one decode step ahead of its harvest, §19): the
+    # final prefill chunk's first token, and decode steps past
+    # ``length`` (0 or 1). Both 0 whenever the engine is at rest.
+    first_unread: bool = False
+    ahead: int = 0
+
+    @property
+    def budget_left(self) -> int:
+        """Tokens the request may still have sampled for it, counting
+        the ones that are sampled already and wait on the device."""
+        return (self.request.max_new_tokens - self.generated
+                - self.ahead - self.first_unread)
 
 
 class Scheduler:
@@ -235,8 +248,13 @@ class Scheduler:
         return best
 
     def decode_slots(self) -> list[int]:
+        """The slots a decode step dispatched now has a row for: in
+        the decode phase with budget left once the unread tokens are
+        counted, so no step is dispatched past an end the host can
+        see (``max_new_tokens``)."""
         return [i for i, s in enumerate(self.slots)
-                if s is not None and s.phase == "decode"]
+                if s is not None and s.phase == "decode"
+                and s.budget_left > 0]
 
     # ---- lifecycle -----------------------------------------------------
 

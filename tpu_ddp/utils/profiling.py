@@ -56,23 +56,34 @@ SPANS = {
         ("rid", "waited_ms", "prompt_tokens", "cached_tokens")),
     "tpu_ddp.serve.prefill": (
         "serving", "one prefill chunk: build, upload, dispatch, "
-        "pool.commit and, on the final chunk, the first token's fetch "
-        "and emit", ("rid", "tokens", "start", "final")),
+        "pool.commit; the final chunk's first token stays on the device "
+        "and is read with the step's decode rows",
+        ("rid", "tokens", "start", "final")),
     "tpu_ddp.serve.decode": (
         "serving", "one whole-bank decode step (plain, chain or fused "
-        "speculative)", ("slots", "context_tokens")),
+        "speculative): its dispatch and, in the plain engine, the "
+        "harvest of the step BEFORE it. ahead: 1 where that step's "
+        "decode rows were still unread at the dispatch, 0 where the "
+        "engine was at rest (at spec_k == 0 also the counters "
+        "serve_decode_ahead / serve_decode_at_rest)",
+        ("slots", "context_tokens", "ahead")),
     "tpu_ddp.serve.decode.tables": (
-        "serving", "ensure_block(s), tier residency, the numpy tables "
+        "serving", "ensure_blocks, tier residency, the numpy tables "
         "and vectors", ()),
     "tpu_ddp.serve.decode.dispatch": (
         "serving", "jnp.asarray uploads, the jitted call(s), pool.commit",
         ()),
     "tpu_ddp.serve.decode.fetch": (
         "serving", "the blocking np.asarray of tokens, log-probabilities "
-        "and flags", ()),
+        "and flags: in the plain engine those of the step before, while "
+        "this step's programs are queued behind it (directly under "
+        "serve.step in a step that dispatches no decode). The first "
+        "tokens of that step's final chunks come first, in a fetch / "
+        "emit pair of their own, and do not wait for the decode rows",
+        ()),
     "tpu_ddp.serve.decode.emit": (
-        "serving", "the per-slot loop: quarantine, length += 1, _emit "
-        "(stamps, callbacks, retire)", ()),
+        "serving", "the per-slot loop over what fetch read: quarantine, "
+        "length += 1, _emit (stamps, callbacks, retire)", ()),
     "tpu_ddp.lm.put_batch": (
         "train loop", "LMTrainer / PipelineLMTrainer.put_batch: host "
         "arrays to sharded device arrays", ("tokens",)),
@@ -97,6 +108,7 @@ SPANS = {
 # (analysis/retrace.py) and on the trace's "XLA Modules" line.
 SERVE_DECODE = "serve_decode"
 SERVE_PREFILL = "serve_prefill"
+SERVE_FEED = "serve_feed"
 SERVE_SPEC = "serve_spec"
 SERVE_DECODE_TIERED = "serve_decode_tiered"
 SERVE_PREFILL_TIERED = "serve_prefill_tiered"
@@ -111,6 +123,9 @@ DDP_EVAL_STEP = "ddp_eval_step"
 PROGRAMS = {
     SERVE_DECODE: "serve/engine.py: one token for the whole slot bank",
     SERVE_PREFILL: "serve/engine.py: one prefill chunk of one prompt",
+    SERVE_FEED: "serve/engine.py: each slot's pending token for the "
+                "decode step, taken from the unread samples on the "
+                "device where the host does not have it yet",
     SERVE_SPEC: "serve/speculative.py: fused draft + verify",
     SERVE_DECODE_TIERED: "serve/long_context.py: decode over hot + cold "
                          "K/V tiers",
@@ -174,7 +189,7 @@ SCOPES = {
 # :func:`burst`). Every mean a reader takes "per step" is then a mean
 # over the annotated steps.
 BURST_STEPS = 24
-BURST_EVERY = 120
+BURST_EVERY = 240
 
 _thread = threading.local()     # .quiet: this thread's spans are dropped
 
@@ -198,8 +213,8 @@ def burst(n: int):
     :data:`BURST_EVERY`.
 
     Why: a trace's readers pair host spans with device operations, and
-    the serving engine on the chip runs some 55 steps a second of some
-    2,000 device operations each (PR 27), 3.4 times what it ran when the
+    the serving engine on the chip runs some 66 steps a second of some
+    2,000 device operations each (PR 30), 4.5 times what it ran when the
     spans went in. Consecutive whole steps, not one in five: the spans of
     one request (``admit``, its ``prefill`` chunks, the ``decode`` steps
     after them) stay next to each other, and a step is either all there
