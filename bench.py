@@ -719,56 +719,6 @@ def run_serve_probe(n_requests: int = 24) -> dict:
     return out
 
 
-def run_speculative_probe(n_requests: int = 16) -> dict:
-    """Speculative-decoding probe (tpu_ddp/serve/speculative.py):
-    decode tokens/sec at spec_k=0 vs the bitwise-exact "chain"
-    schedule (k=12) and the fused int8-draft step, on a decode-heavy
-    offline batch (short prompts, long generations — the regime
-    speculation targets). The recorded claims are the ORDERING
-    (chain >= baseline on tokens/sec) and chain's bitwise token
-    parity; the enforced >=2x + ledger + parity gates live in the
-    committed sweep (scripts/spec_sweep.py,
-    experiments/spec_sweep.json)."""
-    from scripts.serve_sweep import build_engine
-    from tpu_ddp.serve import make_workload
-
-    specs = make_workload(n_requests, vocab_size=1024, seed=7,
-                          prompt_len=(4, 9), max_new=(40, 41))
-
-    def run_cells(**knobs):
-        warm = build_engine(**knobs)
-        for sp in specs[:3]:
-            warm.submit(sp.prompt, sp.max_new_tokens,
-                        temperature=0.8, seed=11)
-        warm.run()
-        eng = build_engine(**knobs)
-        hs = [eng.submit(sp.prompt, sp.max_new_tokens,
-                         temperature=0.8, seed=i)
-              for i, sp in enumerate(specs)]
-        t0 = time.perf_counter()
-        eng.run()
-        dt = time.perf_counter() - t0
-        toks = sum(len(h.tokens) for h in hs)
-        cell = {"tokens_per_sec": round(toks / dt, 1),
-                "total_tokens": toks}
-        if getattr(eng, "spec_k", 0) > 0:
-            cell["speculative"] = eng.spec_stats()
-        return cell, [list(h.tokens) for h in hs]
-
-    out = {}
-    out["baseline"], base_streams = run_cells()
-    out["chain_k12"], chain_streams = run_cells(spec_k=12)
-    out["quant_draft_k4"], _ = run_cells(spec_k=4, spec_draft="quant",
-                                         decode_quant="int8")
-    out["chain_bitwise_parity"] = bool(base_streams == chain_streams)
-    base = out["baseline"]["tokens_per_sec"]
-    out["chain_speedup"] = round(
-        out["chain_k12"]["tokens_per_sec"] / base, 3) if base else None
-    out["chain_beats_baseline"] = bool(
-        out["chain_k12"]["tokens_per_sec"] > base)
-    return out
-
-
 def run_long_context_probe() -> dict:
     """Long-context probe (serve/long_context.py, DESIGN.md §27): TTFT
     per prompt token on a 512-token prompt whose KV footprint is 8x
@@ -1301,10 +1251,6 @@ def main() -> dict:
     # Serving probe (tpu_ddp/serve/): continuous-vs-static goodput at
     # 1.5x saturation — the serve subsystem's headline ordering.
     extra["serve"] = _sub(run_serve_probe)
-    # Speculative-decoding probe (serve/speculative.py): chain-vs-
-    # baseline decode tokens/sec ordering + chain bitwise parity; the
-    # enforced >=2x gate lives in scripts/spec_sweep.py.
-    extra["speculative"] = _sub(run_speculative_probe)
     # Long-context probe (serve/long_context.py): tiered-vs-resident
     # TTFT/token at 8x hot-tier oversubscription + bf16 cold-codec
     # bitwise parity; the enforced <=1.2x gate lives in

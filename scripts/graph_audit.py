@@ -197,12 +197,11 @@ def audit_serve_cells():
     model = _tiny_lm()
     params = model.init(jax.random.key(0))
     engine = ServeEngine(model, params, **GEOM)
-    # Speculative + int8 surfaces (DESIGN.md §26). The "chain" family
-    # adds NO program (it re-dispatches serve/decode — that absence IS
-    # its bitwise-parity argument); the fused families and the int8
-    # tree each compile distinct programs, audited here. A quantized
-    # params tree has a different treedef, so the int8 decode/prefill
-    # cells are separate jit cache entries, not retraces.
+    # Speculative + int8 surfaces (DESIGN.md §26): the fused families
+    # and the int8 tree each compile distinct programs, audited here.
+    # A quantized params tree has a different treedef, so the int8
+    # decode/prefill cells are separate jit cache entries, not
+    # retraces.
     spec = ServeEngine(model, params, spec_k=4, spec_draft="self-1",
                        **GEOM)
     specq = ServeEngine(model, params, spec_k=4, spec_draft="quant",

@@ -226,8 +226,8 @@ def test_serve_knobs_registered_under_goodput_objective():
         assert not knob_by_field(f).semantic, f
     # int8 decode rounds the served logits -> semantic like
     # publish_wire; speculation never changes the emitted stream (the
-    # chain family is bitwise, the fused families emit only target
-    # samples), so spec_k/spec_draft are pure scheduling.
+    # fused families emit only target samples), so spec_k/spec_draft
+    # are pure scheduling.
     assert knob_by_field("decode_quant").semantic
     assert not knob_by_field("spec_k").semantic
     assert not knob_by_field("spec_draft").semantic
@@ -246,7 +246,7 @@ def test_serve_knobs_registered_under_goodput_objective():
     # At the default config the coupled fleet knobs collapse to single
     # candidates (kv_wire needs a disagg edge, prefix-affinity needs a
     # cache, the publish wire and gate need a publish cadence, the
-    # scale cooldown needs a live autoscaler, a non-chain draft needs
+    # scale cooldown needs a live autoscaler, a non-default draft needs
     # spec_k > 0 — tune/space.py violations) and drop out of the
     # space; spec_k and decode_quant are live on a single engine.
     # (kv_cold_dtype likewise collapses: it is inert until kv_tiers

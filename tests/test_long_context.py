@@ -318,26 +318,6 @@ class TestPromoteBeforeTrim:
         assert len(s.blocks) == s.length // pool.block_size + 1
         assert pool.refcount_ok([s.blocks])
 
-    # The scheduler-level promote-before-trim test above pins the fix
-    # directly; this end-to-end spec-chain composition adds only the
-    # engine plumbing on top -> slow tier.
-    @pytest.mark.slow
-    def test_spec_chain_under_tiny_hbm_matches_oracle(self, model,
-                                                      params):
-        """Engine-level regression: spec_k > 0 with an HBM budget far
-        below the working set. The chain draft re-dispatches the
-        bitwise-exact decode program, so the stream must equal the
-        tiers=1 bf16 engine's plain greedy stream even while every
-        step's rollback trims through demoted blocks."""
-        cases = [(9, 10), (4, 12)]
-        want = _stream(model, params, cases, cache_dtype="bf16")
-        got = _stream(model, params, cases, cache_dtype="bf16",
-                      kv_tiers=3, kv_cold_dtype="bf16", hbm_blocks=9,
-                      cold_blocks=33, num_slots=2, spec_k=3,
-                      spec_draft="chain")
-        for w, g in zip(want, got):
-            np.testing.assert_array_equal(g, w)
-
 
 # ---------------------------------------------------------------------------
 # Tiered engine exactness + liveness
@@ -359,8 +339,8 @@ class TestTieredEngine:
             np.testing.assert_array_equal(
                 g, w, err_msg=f"request {i} diverged under tiering")
 
-    # The chain-spec tiered test above covers speculation x tiering;
-    # the fused family only adds the all-hot slot-translation case.
+    # Speculation x tiering: the fused step's all-hot slot translation
+    # (three engine builds -> slow tier).
     @pytest.mark.slow
     def test_fused_spec_all_hot_translation(self, model, params):
         # Fused drafts run the round-17 program against HOT SLOT ids:

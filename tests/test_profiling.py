@@ -146,7 +146,6 @@ def _epoch_losses(**cfg):
 RUNS = {
     "serve": _serve,
     "spec": lambda m, p: _serve(m, p, spec_k=2, spec_draft="self-1"),
-    "chain": lambda m, p: _serve(m, p, spec_k=2, spec_draft="chain"),
     "lm": lambda m, p: _lm_losses(m),
     "epoch": lambda m, p: _epoch_losses(),
     "epoch_multi": lambda m, p: _epoch_losses(steps_per_dispatch=2),
@@ -210,7 +209,7 @@ def _parent(spans, child):
     return min(around, key=lambda s: s[2] - s[1]) if around else None
 
 
-@pytest.mark.parametrize("key", ["serve", "spec", "chain"])
+@pytest.mark.parametrize("key", ["serve", "spec"])
 def test_serve_spans_nest_as_the_table_says(runs, key):
     spans = runs["spans"][key]
     want = {"tpu_ddp.serve.step": None,
@@ -405,7 +404,7 @@ def test_burst_restores_the_thread_after_an_exception():
 def test_the_tiny_runs_fit_in_one_burst(runs):
     """The span tests above see every step because their runs are shorter
     than a burst; if one grows past it, they miss spans for this reason."""
-    for key in ("serve", "spec", "chain"):
+    for key in ("serve", "spec"):
         assert runs["traced"][key]["steps"] + 1 <= profiling.BURST_STEPS
 
 

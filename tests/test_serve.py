@@ -585,7 +585,7 @@ class TestStepAhead:
     def test_ahead_means_a_decode_step_unread_at_k0(self, model, params):
         """``ahead`` is 1 where the DECODE step before is unread: a
         final chunk's first token alone on the device is the engine at
-        rest. The speculative steps read back at once and count
+        rest. The speculative step reads back at once and counts
         neither way."""
         eng = _engine(model, params)
         eng.submit(_prompt(5, seed=97), 1)      # ends on its first token
@@ -599,12 +599,12 @@ class TestStepAhead:
         eng.step()
         assert c["serve_decode_ahead"] == 1
         eng.run()
-        chain = _engine(model, params, spec_k=2, spec_draft="chain")
-        r = chain.submit(_prompt(6, seed=98), 4)
-        chain.run()
+        spec = _engine(model, params, spec_k=2, spec_draft="self-1")
+        r = spec.submit(_prompt(6, seed=98), 4)
+        spec.run()
         assert r.tokens == late.tokens
         assert not {"serve_decode_ahead", "serve_decode_at_rest"} \
-            & set(chain.metrics.counters)
+            & set(spec.metrics.counters)
 
     @pytest.mark.parametrize("busy", [False, True],
                              ids=["engine_at_rest", "decode_in_flight"])

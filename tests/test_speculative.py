@@ -6,11 +6,11 @@ What this file pins, by class:
   adversarial prefixes), and the engine-level ledger identity
   ``proposed == accepted + rejected`` per request and in aggregate,
   for both fused draft families.
-- **Chain parity** — the tentpole exactness claim: a ``spec_draft=
-  "chain"`` engine emits token AND logprob streams bitwise identical
-  to the k=0 engine, because every sample comes from the same
-  compiled decode program. Pinned across k values, rebatching,
-  replica-crash migration, weight hot-swap and the int8 family.
+- **Between steps** — a fused engine is at rest after every step,
+  so ``swap_params`` and ``drain`` mid-generation stamp every token
+  with the version that sampled it and lose or replay none. (What
+  holds for a stream dispatched ahead of its harvest is
+  tests/test_serve.py ``TestStepAhead``'s.)
 - **KV rollback** — the fused families' pool invariant: rejection
   returns tail blocks via ``trim_blocks`` and
   ``free + Σallocated == total`` holds after EVERY step, fuzzed over
@@ -21,7 +21,8 @@ What this file pins, by class:
 - **Knobs** — the four-surface convention for TPU_DDP_SPEC_K /
   TPU_DDP_SPEC_DRAFT / TPU_DDP_DECODE_QUANT: env flow into the
   engine, junk rejection at config, coupled-knob violations at the
-  engine door.
+  engine door, and the retired ``"chain"`` value refused by name on
+  every surface.
 - **TPOT bugfix** — loadgen inter-token percentiles come from the
   per-token emission stamps (``Request.token_times``), not the old
   uniform (finished-first)/(n-1) estimate that averaged speculative
@@ -40,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpu_ddp.fleet import ReplicaCrashError, Router
+from tpu_ddp.fleet.resilience import continuation_of
 from tpu_ddp.models.transformer import make_transformer
 from tpu_ddp.ops.quant import (
     QuantizedWeight,
@@ -75,16 +76,6 @@ def model():
 @pytest.fixture(scope="module")
 def params(model):
     return model.init(jax.random.key(0))
-
-
-@pytest.fixture(scope="module")
-def baseline(model, params):
-    """The k=0 engine's (token, logprob) streams for MIXED — the
-    bitwise reference every chain cell is judged against."""
-    eng = ServeEngine(model, params, **GEOM)
-    hs = _submit_mixed(eng)
-    eng.run()
-    return _streams(hs)
 
 
 def _prompt(L, seed=0):
@@ -156,152 +147,85 @@ class TestAcceptRule:
         assert st["acceptance"] == pytest.approx(
             st["accepted"] / st["proposed"])
 
-    def test_chain_accepts_everything_by_construction(self, model,
-                                                      params):
-        """The chain schedule has no separate draft to disagree with:
-        every proposal beyond column 0 is an accepted target sample,
-        so rejected == 0 unless a request finishes mid-window."""
-        eng = ServeEngine(model, params, **GEOM, spec_k=3)
-        h = eng.submit(_prompt(6, seed=9), 8, temperature=1.0, seed=4)
-        eng.run()
-        assert h.spec_rejected == 0
-        assert h.spec_proposed == h.spec_accepted
-        assert _ledger_ok(eng, [h])
-
 
 # ---------------------------------------------------------------------------
-# Chain bitwise parity — the exactness tentpole
+# Between steps: a fused engine is at rest, so swap and drain are exact
 # ---------------------------------------------------------------------------
 
-class TestChainParity:
-    @pytest.mark.parametrize("k", [1, 3, 7])
-    def test_bitwise_parity_vs_k0(self, model, params, baseline, k):
-        """Token AND logprob streams equal the k=0 engine's bitwise,
-        greedy and sampled alike — the structural claim spec_sweep
-        enforces on every committed chain cell."""
-        eng = ServeEngine(model, params, **GEOM, spec_k=k)
+SELF1 = dict(spec_k=3, spec_draft="self-1")
+
+
+class TestBetweenSteps:
+    @staticmethod
+    def _midway(engine, handles, n=2):
+        while min(len(h.tokens) for h in handles) < n:
+            engine.step()
+        assert not all(h.done for h in handles)
+
+    def test_swap_params_stamps_the_version_that_sampled(self, model,
+                                                         params):
+        """swap_params mid-generation on a self-1 engine: a token
+        carries the version of the weights that sampled it (one stamp
+        per token, bursts included), and what the new weights sample
+        is what a fresh engine built on them samples."""
+        params2 = model.init(jax.random.key(1))
+        same = ServeEngine(model, params, **GEOM, **SELF1)
+        ref = _submit_mixed(same)
+        same.run()
+        eng = ServeEngine(model, params, **GEOM, **SELF1)
         hs = _submit_mixed(eng)
-        eng.run()
-        assert _streams(hs) == baseline
-        assert eng.accounting_ok()
-        assert _ledger_ok(eng, hs)
-
-    def test_parity_survives_rebatching(self, model, params):
-        """The stateless fold_in(seed, position) keys make a request's
-        stream independent of its batch neighbors — with speculation
-        ALSO independent of which window column a position lands in."""
-        prompt = _prompt(6, seed=50)
-        alone = ServeEngine(model, params, **GEOM, spec_k=4)
-        r1 = alone.submit(prompt, 6, temperature=1.0, seed=7)
-        alone.run()
-        crowded = ServeEngine(model, params, **GEOM, spec_k=2)
-        for i in range(3):
-            crowded.submit(_prompt(5 + i, seed=60 + i), 4,
-                           temperature=1.0, seed=100 + i)
-        r2 = crowded.submit(prompt, 6, temperature=1.0, seed=7)
-        crowded.run()
-        assert r1.tokens == r2.tokens and r1.logprobs == r2.logprobs
-
-    def test_parity_survives_migration(self, model, params, baseline):
-        """A replica crash mid-window migrates in-flight requests to a
-        chain replica and the final streams still match the
-        undisturbed k=0 single engine — speculation composes with the
-        fleet's deterministic-replay contract."""
-        class _Crashy:
-            def __init__(self, engine, crash_at):
-                self.engine, self.crash_at, self.n = engine, crash_at, 0
-
-            def step(self):
-                self.n += 1
-                if self.n == self.crash_at:
-                    raise ReplicaCrashError(
-                        f"synthetic crash at step {self.n}")
-                return self.engine.step()
-
-            def __getattr__(self, name):
-                return getattr(self.engine, name)
-
-        crashy = _Crashy(
-            ServeEngine(model, params, **GEOM, spec_k=3), crash_at=3)
-        other = ServeEngine(model, params, **GEOM, spec_k=3)
-        router = Router([crashy, other], probe_backoff_ms=10_000.0)
-        hs = [router.submit(_prompt(L, seed=ps), n, temperature=t,
-                            seed=i)
-              for i, (ps, L, n, t) in enumerate(MIXED)]
-        with pytest.warns(UserWarning, match="marked unhealthy"):
-            router.run()
-        assert all(h.done for h in hs)
-        assert [list(h.tokens) for h in hs] == [t for t, _ in baseline]
-        assert router.accounting_ok()
-
-    def test_parity_survives_hot_swap(self, model, params):
-        """swap_params on a chain engine: version stamps stay
-        non-decreasing (one stamp per token, bursts included), and
-        post-swap requests match a fresh k=0 engine built on the new
-        weights — the subscriber's cutover contract under
-        speculation."""
-        params2 = model.init(jax.random.key(1))
-        eng = ServeEngine(model, params, **GEOM, spec_k=3)
-        h1 = eng.submit(_prompt(6, seed=3), 6, temperature=0.8, seed=2)
-        while len(h1.tokens) < 2:
-            eng.step()
+        self._midway(eng, hs)
+        had = [len(h.tokens) for h in hs]
         eng.swap_params(params2, version=2)
-        h2 = eng.submit(_prompt(7, seed=4), 5, temperature=0.8, seed=9)
+        late = eng.submit(_prompt(7, seed=4), 5, temperature=0.8, seed=9)
         eng.run()
-        assert len(h1.token_versions) == len(h1.tokens)
-        assert h1.token_versions == sorted(h1.token_versions)
-        assert set(h2.token_versions) == {2}
-        ref = ServeEngine(model, params2, **GEOM)
-        r2 = ref.submit(_prompt(7, seed=4), 5, temperature=0.8, seed=9)
-        ref.run()
-        assert h2.tokens == r2.tokens and h2.logprobs == r2.logprobs
-
-    @pytest.mark.slow  # three int8-engine compiles; f32 chain parity
-    # is pinned fast above (test_bitwise_parity_vs_k0) and the int8 x
-    # chain composition is enforced on every committed spec_sweep cell.
-    def test_parity_within_int8_family(self, model, params):
-        """decode_quant="int8" changes the sampled stream (quantized
-        logits) but chain parity holds WITHIN the family: int8 chain
-        == int8 k=0, and the swap re-quantizes (stream still matches
-        a fresh int8 engine on the new weights)."""
-        q0 = ServeEngine(model, params, **GEOM, decode_quant="int8")
-        ref = _submit_mixed(q0)
-        q0.run()
-        qc = ServeEngine(model, params, **GEOM, decode_quant="int8",
-                         spec_k=4)
-        hs = _submit_mixed(qc)
-        qc.run()
-        assert _streams(hs) == _streams(ref)
-        params2 = model.init(jax.random.key(1))
-        qc.swap_params(params2, version=2)
-        h = qc.submit(_prompt(6, seed=8), 5, temperature=0.5, seed=3)
-        qc.run()
-        fresh = ServeEngine(model, params2, **GEOM, decode_quant="int8")
-        r = fresh.submit(_prompt(6, seed=8), 5, temperature=0.5, seed=3)
+        for h, r, n0 in zip(hs, ref, had):
+            assert h.done and len(h.tokens) == h.max_new_tokens
+            assert h.token_versions == [0] * n0 \
+                + [2] * (len(h.tokens) - n0)
+            # up to the flip: the same program on the same weights
+            assert h.tokens[:n0] == r.tokens[:n0]
+            assert h.logprobs[:n0] == r.logprobs[:n0]
+        assert any(h.tokens != r.tokens for h, r in zip(hs, ref))
+        assert set(late.token_versions) == {2}
+        fresh = ServeEngine(model, params2, **GEOM, **SELF1)
+        want = fresh.submit(_prompt(7, seed=4), 5, temperature=0.8,
+                            seed=9)
         fresh.run()
-        assert h.tokens == r.tokens and h.logprobs == r.logprobs
+        assert late.tokens == want.tokens
+        assert _ledger_ok(eng, hs + [late]) and eng.accounting_ok()
 
-    def test_eos_mid_window_stops_exactly(self, model, params):
-        """A request hitting EOS inside a chain window emits exactly
-        the k=0 prefix — the overrun columns' garbage is discarded at
-        harvest, never emitted."""
-        ref = ServeEngine(model, params, **GEOM)
-        r = ref.submit(_prompt(6, seed=21), 10, seed=5)
-        ref.run()
-        # An EOS whose FIRST occurrence is mid-stream, so the stop
-        # lands inside the chain window whatever the sampler drew.
-        cut = next(i for i in range(2, len(r.tokens))
-                   if r.tokens[i] not in r.tokens[:i])
-        eos = r.tokens[cut]
-        a = ServeEngine(model, params, **GEOM)
-        ra = a.submit(_prompt(6, seed=21), 10, seed=5, eos_id=eos)
-        a.run()
-        b = ServeEngine(model, params, **GEOM, spec_k=6)
-        rb = b.submit(_prompt(6, seed=21), 10, seed=5, eos_id=eos)
-        b.run()
-        assert rb.tokens == ra.tokens == r.tokens[:cut + 1]
-        assert rb.logprobs == ra.logprobs
-        assert b.accounting_ok()
+    def test_drain_mid_generation_replays_elsewhere(self, model, params):
+        """drain() on a self-1 engine mid-generation, the harvested
+        requests resubmitted to a second one: every request ends with
+        its whole budget, no token lost or sampled twice, and both
+        pools balance."""
+        eng = ServeEngine(model, params, **GEOM, **SELF1)
+        hs = _submit_mixed(eng)
+        self._midway(eng, hs)
+        done_here = [h for h in hs if h.done]
+        had = {h.rid: len(h.tokens) for h in hs}
+        harvested = eng.drain()
+        assert [h.rid for h in harvested] \
+            == [h.rid for h in hs if h not in done_here]
+        assert {h.rid: len(h.tokens) for h in hs} == had
+        assert eng.pool.free_count == eng.pool.total_usable
+        assert eng.accounting_ok() and not eng.step()
+        other = ServeEngine(model, params, **GEOM, **SELF1)
+        rests = []
+        for h in harvested:
+            prompt, left = continuation_of(h)
+            assert left == h.max_new_tokens - len(h.tokens)
+            rests.append(other.submit(prompt, left,
+                                      temperature=h.temperature,
+                                      seed=h.seed))
+        other.run()
+        for h, rest in zip(harvested, rests):
+            assert rest.done
+            assert len(h.tokens) + len(rest.tokens) == h.max_new_tokens
+        assert all(len(h.tokens) == h.max_new_tokens for h in done_here)
+        assert other.accounting_ok() and _ledger_ok(other, rests)
+        assert _ledger_ok(eng, hs)
 
 
 # ---------------------------------------------------------------------------
@@ -433,8 +357,7 @@ class TestQuantizer:
 
     def test_nll_drift_within_quality_bar(self, model, params):
         """The committed bar: quantized decode within 0.25% of fp32
-        mean NLL on a seeded eval stream (spec_sweep enforces the
-        same bound on every run)."""
+        mean NLL on a seeded eval stream."""
         qparams = quantize_params(model, params)
         rng = np.random.default_rng(3)
         toks = jnp.asarray(
@@ -465,10 +388,10 @@ class TestQuantizer:
 
 class TestKnobs:
     def test_grammar(self):
-        assert parse_spec_draft("chain") == ("chain", None)
         assert parse_spec_draft("quant") == ("quant", None)
         assert parse_spec_draft("self-2") == ("self", 2)
-        for junk in ("self-0", "self-x", "draft", ""):
+        assert parse_spec_draft(" self-1 ") == ("self", 1)
+        for junk in ("self-0", "self-x", "self-", "draft", ""):
             with pytest.raises(ValueError, match="spec_draft"):
                 parse_spec_draft(junk)
         assert all(parse_spec_draft(s) for s in SPEC_DRAFTS)
@@ -482,6 +405,42 @@ class TestKnobs:
         assert eng.spec_k == 3
         assert eng.spec_draft == "self-1"
         assert eng.decode_quant == "int8"
+
+    def test_spec_k_alone_builds_the_fused_program(self, model, params):
+        """``spec_k`` with nothing else said is real draft-and-verify:
+        the default family is ``self-1``."""
+        eng = ServeEngine(model, params, **GEOM, spec_k=3)
+        assert eng.spec_stats()["spec_draft"] == "self-1"
+        assert eng._spec is not None
+        assert eng.lower_spec_step() is not None
+
+    @pytest.mark.parametrize("surface", ["parser", "engine", "env",
+                                         "launch"])
+    def test_chain_is_refused_by_name(self, surface, model, params,
+                                      monkeypatch, capsys):
+        """The retired schedule fails loudly wherever it can be asked
+        for, and the message says what to do instead."""
+        if surface == "launch":
+            from tpu_ddp import launch
+            with pytest.raises(SystemExit):
+                launch.main(["part1", "--nproc", "1",
+                             "--spec-draft", "chain"])
+            said = capsys.readouterr().err
+        else:
+            with pytest.raises(ValueError) as err:
+                if surface == "parser":
+                    parse_spec_draft("chain")
+                elif surface == "engine":
+                    ServeEngine(model, params, **GEOM, spec_k=2,
+                                spec_draft="chain")
+                else:
+                    from tpu_ddp.utils.config import TrainConfig
+                    monkeypatch.setenv("TPU_DDP_SPEC_DRAFT", "chain")
+                    TrainConfig()
+            said = str(err.value)
+        assert "'chain' is gone" in said
+        assert "spec_k=0 already dispatches" in said
+        assert "self-<j>" in said and "quant" in said
 
     @pytest.mark.parametrize("env,junk,match", [
         ("TPU_DDP_SPEC_K", "-1", "TPU_DDP_SPEC_K"),
@@ -512,9 +471,9 @@ class TestKnobs:
 
     def test_lower_spec_step_gates(self, model, params):
         """The audit surface exists exactly when a fused program does:
-        chain and k=0 engines have no spec program to lower."""
-        eng = ServeEngine(model, params, **GEOM, spec_k=2)
-        with pytest.raises(ValueError, match="chain"):
+        a k=0 engine has no spec program to lower."""
+        eng = ServeEngine(model, params, **GEOM)
+        with pytest.raises(ValueError, match="spec_k == 0"):
             eng.lower_spec_step()
         fused = ServeEngine(model, params, **GEOM, spec_k=2,
                             spec_draft="self-1")
@@ -527,10 +486,15 @@ class TestKnobs:
         ctx = Workload()
         # Coupled-knob pruning: an inert draft family and a
         # disagg-fleet speculation cell are both rejected.
-        assert violations({"spec_draft": "self-1", "spec_k": 0}, ctx)
+        assert violations({"spec_draft": "quant", "spec_k": 0}, ctx)
         assert violations({"spec_k": 4, "fleet_roles": "disagg"}, ctx)
-        assert violations({"spec_draft": "self-1", "spec_k": 4},
+        assert violations({"spec_draft": "quant", "spec_k": 4},
                           ctx) == []
+        # the default family at spec_k == 0 IS the default cell
+        assert violations({"spec_draft": "self-1", "spec_k": 0},
+                          ctx) == []
+        draft = next(k for k in KNOBS if k.name == "spec_draft")
+        assert draft.values == ("self-1", "quant")
 
 
 # ---------------------------------------------------------------------------
@@ -612,15 +576,24 @@ class TestTPOTFromStamps:
         assert out["tpot_p50_ms"] == pytest.approx(20.0, abs=1.0)
         assert out["tpot_p99_ms"] == pytest.approx(20.0, abs=1.0)
 
-    def test_real_chain_engine_stamps_every_token(self, model, params):
-        """End to end on the real engine: one stamp per token, stamps
+    @pytest.mark.parametrize("knobs", [{}, SELF1],
+                             ids=["plain", "self-1"])
+    def test_real_engine_stamps_every_token(self, model, params, knobs):
+        """End to end on the real engine, a step ahead of its harvest
+        or speculating in bursts: one stamp per token, stamps
         non-decreasing, and run_load's TPOT fields populate."""
-        eng = ServeEngine(model, params, **GEOM, spec_k=3)
+        eng = ServeEngine(model, params, **GEOM, **knobs)
+        got, submit = [], eng.submit
+        eng.submit = lambda *a, **kw: got.append(submit(*a, **kw)) \
+            or got[-1]
         specs = [RequestSpec(prompt=tuple(_prompt(5 + i, seed=i)),
                              max_new_tokens=4 + i, temperature=0.5,
                              seed=i)
                  for i in range(4)]
         out = run_load(eng, specs, rate=1000.0, seed=1)
         assert out["n_completed"] == 4
+        for h in got:
+            assert len(h.token_times) == len(h.tokens) == h.max_new_tokens
+            assert h.token_times == sorted(h.token_times)
         assert out["tpot_p50_ms"] is not None
         assert out["tpot_p99_ms"] >= out["tpot_p50_ms"]

@@ -37,6 +37,7 @@ from pathlib import Path
 
 from tpu_ddp.resilience.watchdog import (HEARTBEAT_ENV, STALL_EXIT_CODE,
                                          HeartbeatMonitor)
+from tpu_ddp.utils.config import parse_spec_draft
 
 PARTS_DIR = Path(__file__).resolve().parent.parent / "parts"
 PARTS = ("part1", "part2a", "part2b", "part3", "part4", "part5")
@@ -764,11 +765,11 @@ def main(argv=None) -> int:
                         "baseline; tpu_ddp/serve/speculative.py). Sets "
                         "TPU_DDP_SPEC_K for every rank")
     p.add_argument("--spec-draft", default=None,
-                   help="draft family for speculation: 'chain' "
-                        "(bitwise-exact same-program schedule), "
-                        "'self-<j>' (early exit over the target's "
-                        "first j blocks) or 'quant' (full-depth int8 "
-                        "twin). Sets TPU_DDP_SPEC_DRAFT for every rank")
+                   help="draft family for speculation: 'self-<j>' "
+                        "(early exit over the target's first j "
+                        "blocks; 'self-1' is the default) or 'quant' "
+                        "(full-depth int8 twin). Sets "
+                        "TPU_DDP_SPEC_DRAFT for every rank")
     p.add_argument("--decode-quant", default=None,
                    choices=("none", "int8"),
                    help="weight-only int8 decode compute "
@@ -930,13 +931,10 @@ def main(argv=None) -> int:
             p.error(f"--spec-k must be >= 0, got {args.spec_k}")
         env["TPU_DDP_SPEC_K"] = str(args.spec_k)
     if args.spec_draft is not None:
-        sd = args.spec_draft.strip()
-        if sd not in ("chain", "quant") and not (
-                sd.startswith("self-")
-                and sd[len("self-"):].isdigit()
-                and int(sd[len("self-"):]) >= 1):
-            p.error(f"--spec-draft {args.spec_draft!r}: expected "
-                    "chain, self-<j> (j >= 1) or quant")
+        try:
+            parse_spec_draft(args.spec_draft)
+        except ValueError as e:
+            p.error(f"--spec-draft: {e}")
         env["TPU_DDP_SPEC_DRAFT"] = args.spec_draft
     if args.decode_quant is not None:
         env["TPU_DDP_DECODE_QUANT"] = args.decode_quant

@@ -35,8 +35,7 @@ its memoized program caches on the treedef, which differs from the fp
 tree's, giving quantized programs their own jit cache entries for
 free. The quality bar is the compress-sweep convention: mean NLL of a
 seeded eval stream within 0.25% of the fp32 model
-(:func:`nll_drift`, enforced by scripts/spec_sweep.py and
-tests/test_speculative.py).
+(:func:`nll_drift`, enforced by tests/test_speculative.py).
 """
 
 from __future__ import annotations

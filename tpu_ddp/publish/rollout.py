@@ -28,10 +28,7 @@ an int8 draft or target the engine's ``swap_params`` re-derives the
 quantized tree on every publisher flip — the draft-distill-and-push
 loop: each round's draft is re-quantized FROM the weights that round
 trained, so the draft never serves a stale version (the per-round
-report pins ``speculative.draft_version == engine_version``). With
-the default "chain" family the rollout streams stay bitwise what the
-non-speculative engine would have sampled, so speculation changes
-the loop's wall-clock, never its trajectory.
+report pins ``speculative.draft_version == engine_version``).
 """
 
 from __future__ import annotations

@@ -3,8 +3,8 @@ continuous-batching scheduler.
 
 One ``ServeEngine`` owns a dense model + params, a ``PagedKVPool``, a
 ``Scheduler`` and TWO jitted programs, compiled once each (a third —
-the fused speculative step — joins only under ``spec_k > 0`` with a
-fused draft family; tpu_ddp/serve/speculative.py):
+the fused speculative step, tpu_ddp/serve/speculative.py — joins
+only under ``spec_k > 0``):
 
 - ``decode step`` — one token for the ENTIRE slot bank per call.
   Static (num_slots, blocks_per_seq) shapes; idle slots ride along with
@@ -485,7 +485,7 @@ class ServeEngine:
             raise ValueError("spec_k must be >= 0")
         self.spec_draft = str(
             spec_draft if spec_draft is not None
-            else getattr(config, "spec_draft", "chain"))
+            else getattr(config, "spec_draft", "self-1"))
         kind, j = parse_spec_draft(self.spec_draft)
         if kind == "self" and j > model.num_layers:
             raise ValueError(
@@ -512,9 +512,8 @@ class ServeEngine:
                 "decode_quant='none'")
         self._refresh_quant()
         self._spec = None
-        if self.spec_k > 0 and kind != "chain":
-            # The fused draft+verify program. "chain" adds NO program:
-            # its schedule is k+1 calls of self._decode.
+        if self.spec_k > 0:
+            # The fused draft+verify program.
             self._spec = build_spec_step(
                 model, self.block_size, self.blocks_per_seq,
                 self.spec_k, j if kind == "self" else model.num_layers)
@@ -615,13 +614,11 @@ class ServeEngine:
 
     def lower_spec_step(self):
         """``jit.lower`` the fused speculative step (same audit
-        surface). Raises unless a fused draft family is configured —
-        the "chain" schedule adds NO program (it reuses the compiled
-        decode step; that is its exactness argument)."""
+        surface). Raises at ``spec_k == 0`` — no speculative program
+        exists there."""
         if self._spec is None:
             raise ValueError(
-                "no fused speculative program: spec_k == 0 or "
-                "spec_draft == 'chain'")
+                "no fused speculative program: spec_k == 0")
         S, BPS = self.num_slots, self.blocks_per_seq
         sds = jax.ShapeDtypeStruct
         return self._spec.lower(
@@ -814,11 +811,14 @@ class ServeEngine:
         decode step. Returns whether any work ran; ``False`` also
         means that nothing is in flight.
 
-        At ``spec_k == 0`` the step dispatches its prefill chunk and
-        its decode program first and then hands out the tokens of the
-        step BEFORE it (:meth:`_harvest`), so a token reaches its
-        request one ``step()`` after the step that sampled it, and the
-        host's work runs beside the device's (docs/DESIGN.md §19).
+        The step has two bodies. At ``spec_k == 0`` it dispatches its
+        prefill chunk and its decode program first and then hands out
+        the tokens of the step BEFORE it (:meth:`_harvest`), so a token
+        reaches its request one ``step()`` after the step that sampled
+        it, and the host's work runs beside the device's
+        (docs/DESIGN.md §19). At ``spec_k > 0`` it comes to rest after
+        its chunks and runs the fused draft+verify step
+        (:meth:`_run_spec_step`), which reads its samples back at once.
 
         Prefill budget: at most one chunk per step at ``spec_k == 0``
         (the latency-smoothing default), ``spec_k + 1`` chunks when
@@ -886,9 +886,9 @@ class ServeEngine:
             self._run_prefill_chunk(pi, mine)
 
         if self.spec_k > 0:
-            # The speculative steps have synchronous bodies of their
-            # own, which read ``pending_token`` on the host: the first
-            # tokens of this step's chunks are handed out before them.
+            # The speculative step is synchronous and reads
+            # ``pending_token`` on the host: the first tokens of this
+            # step's chunks are handed out before it.
             self._unread = mine or None
             self._rest()
         with span("tpu_ddp.serve.schedule"):
@@ -906,9 +906,7 @@ class ServeEngine:
                           self.sched.slots[i].length
                           + self.sched.slots[i].ahead for i in dslots),
                       ahead=ahead):
-                if self.spec_k > 0 and self._spec_kind == "chain":
-                    self._run_chain_step(dslots)
-                elif self.spec_k > 0:
+                if self.spec_k > 0:
                     self._run_spec_step(dslots)
                 else:
                     self._run_decode_step(dslots, mine)
@@ -1161,8 +1159,8 @@ class ServeEngine:
         with span("tpu_ddp.serve.decode.dispatch"):
             first = ()
             if 2 in last[1]:
-                # this step's one final chunk (the speculative steps,
-                # with their larger chunk budget, do not come here)
+                # this step's one final chunk (the speculative step,
+                # with its larger chunk budget, does not come here)
                 (_, _, tok, _), = mine.firsts
                 first = (tok,)
             d_last = _feed(jnp.asarray(last), self._sampled, *first)
@@ -1193,7 +1191,7 @@ class ServeEngine:
         """Bring the engine to rest: read back and hand out what the
         last step left on the device. Whatever touches slot or pool
         state between steps calls this first (``cancel``, ``drain``,
-        ``swap_params``, the non-finite drill, the speculative steps);
+        ``swap_params``, the non-finite drill, the speculative step);
         ``run()`` ends with it."""
         unread, self._unread = self._unread, None
         if unread is not None:
@@ -1243,181 +1241,6 @@ class ServeEngine:
                 s.length += 1
                 self._emit(i, int(toks[i]), float(lps[i]), unread.version)
 
-    def _run_chain_step(self, dslots: list[int]) -> None:
-        """The "chain" speculative schedule (spec_draft="chain"): one
-        engine step runs ``spec_k + 1`` sequential dispatches of the
-        SAME compiled decode program the k=0 engine runs, each column
-        feeding the token the previous column sampled — on device,
-        with NO host sync inside the window. Every emitted sample
-        comes from that one program with bit-identical inputs, so the
-        (token, logprob) stream is bitwise identical to the
-        non-speculative stream by construction — the exactness family
-        (speculative.py). The win: batch assembly, the per-step
-        host/dispatch round trip and the output sync are paid once
-        per window instead of once per token.
-
-        Freezing is two-phase, by the rules every step dispatched
-        ahead of its harvest follows (docs/DESIGN.md §19, "The step is
-        one ahead of its harvest"; the plain engine's one-step-ahead
-        dispatch is the same discipline with a window of one). Budget
-        exhaustion (``max_new_tokens``) is HOST-PREDICTABLE, so the
-        per-column ``act`` mask is precomputed: a slot past its budget
-        is frozen on device to the idle pattern (zeroed table row,
-        length/last 0). EOS and non-finite truncation are NOT
-        predictable: their tail columns are the rows "dispatched past
-        an end the host cannot see" of §19 (the slot's own in-budget
-        positions, output dropped, blocks freed or scrubbed in stream
-        order after them), and the harvest loop stops at the EOS/bad
-        column exactly like the synced column-at-a-time schedule
-        would. Frozen rows cannot perturb live rows: every bank op is
-        row-independent at fixed shapes (the property the
-        migration/rebatching parity tests pin).
-
-        Acceptance is 1 by construction (no rollback); the ledger
-        counts each emitted non-first column as an accepted proposal
-        (rejected on the quarantine column), so
-        ``proposed == accepted + rejected`` stays exact."""
-        with span("tpu_ddp.serve.decode.tables"):
-            *inputs, active = self._chain_tables(dslots)
-        with span("tpu_ddp.serve.decode.dispatch"):
-            cols = self._chain_dispatch(dslots, *inputs, active)
-        with span("tpu_ddp.serve.decode.fetch"):
-            toks = np.stack([np.asarray(t) for t, _, _ in cols])  # (W', S)
-            lps = np.stack([np.asarray(l) for _, l, _ in cols])
-            bad = np.stack([np.asarray(b) for _, _, b in cols])
-        with span("tpu_ddp.serve.decode.emit"):
-            self._chain_emit(dslots, active, toks, lps, bad)
-
-    def _chain_tables(self, dslots: list[int]) -> tuple:
-        """Host half of a chain window: blocks for the whole window,
-        tier residency, the tables and per-slot vectors, and the
-        per-column ``active`` mask (W, S)."""
-        S, BPS = self.num_slots, self.blocks_per_seq
-        W = self.spec_k + 1
-        tables = np.zeros((S, BPS), np.int32)
-        lengths = np.zeros(S, np.int32)
-        last = np.zeros(S, np.int32)
-        temps = np.zeros(S, np.float32)
-        seeds = np.zeros(S, np.int32)
-        remaining = np.zeros(S, np.int32)
-        tiered = self.pool.tiers > 1
-        if tiered:
-            self._maybe_poison(dslots)
-        for i in dslots:
-            self.sched.ensure_blocks(i, W)
-        if tiered:
-            # The whole write WINDOW must be hot (the W columns
-            # scatter with tables fixed for the window); older pages
-            # may sit cold and are read through the dequant.
-            allblocks, hotset = [], []
-            for i in dslots:
-                s = self.sched.slots[i]
-                allblocks.extend(s.blocks)
-                hotset.extend(s.blocks[s.length // self.block_size:])
-            self.pool.ensure_device(allblocks)
-            self.pool.ensure_hot(hotset, keep=allblocks)
-        cold_tables = np.zeros((S, BPS), np.int32)
-        for i in dslots:
-            s = self.sched.slots[i]
-            if tiered:
-                tables[i], cold_tables[i] = self.pool.slot_tables(
-                    s.blocks, BPS)
-            else:
-                tables[i] = self._table_for(s)
-            lengths[i] = s.length
-            last[i] = s.pending_token
-            temps[i] = s.request.temperature
-            seeds[i] = s.request.seed
-            remaining[i] = s.request.max_new_tokens - s.generated
-        if not tiered:
-            self._maybe_poison(dslots)
-        active = np.arange(W)[:, None] < remaining[None, :]  # (W, S)
-        return tables, cold_tables, lengths, last, temps, seeds, active
-
-    def _chain_dispatch(self, dslots, tables, cold_tables, lengths, last,
-                        temps, seeds, active) -> list:
-        """Device half: one upload, then a dispatch per live column;
-        returns each column's (tokens, logprobs, bad) device arrays."""
-        tiered = self.pool.tiers > 1
-        # Fast-path test per column: every LIVE slot still in budget
-        # (idle rows are never active — judging them would force the
-        # masked path on any partially-full bank; on the fast path
-        # they just advance harmlessly into the null block).
-        full = active[:, dslots].all(axis=1)                 # (W,)
-        d_tables = jnp.asarray(tables)
-        d_cold = jnp.asarray(cold_tables)
-        d_lengths = jnp.asarray(lengths)
-        d_last = jnp.asarray(last)
-        d_temps = jnp.asarray(temps)
-        d_seeds = jnp.asarray(seeds)
-        cols = []
-        pk, pv = self.pool.k, self.pool.v
-        ncols = int(active.any(axis=1).sum())  # drop all-frozen tail
-        for c in range(ncols):
-            if c:
-                # Column-to-column advance, on device. Fast path (no
-                # slot freezes this column — the steady state): reuse
-                # the previous column's sample array as-is and bump
-                # lengths with one eager add; the per-step device_put
-                # storm the profiler blames on the k=0 path (five
-                # host->device transfers per dispatch) happens once
-                # per WINDOW here, not once per column.
-                if full[c]:
-                    d_lengths = d_lengths + 1
-                    d_last = cols[-1][0]
-                else:
-                    # Wind-down: some slot exhausted its budget —
-                    # mask it to the idle pattern (null-block table
-                    # row, length/last 0).
-                    act = jnp.asarray(active[c])
-                    d_tables = jnp.where(act[:, None], d_tables, 0)
-                    d_cold = jnp.where(act[:, None], d_cold, 0)
-                    d_lengths = jnp.where(act, d_lengths + 1, 0)
-                    d_last = jnp.where(act, cols[-1][0], 0)
-            # Thread the pool buffers column to column locally — each
-            # dispatch consumes (donates) the previous column's output
-            # buffers directly; one commit per window, not per column.
-            if tiered:
-                pk, pv, toks, lps, bad = self._tiered_decode(
-                    self._decode_params, pk, pv,
-                    self.pool.cold_k, self.pool.cold_v,
-                    self.pool.cold_sk, self.pool.cold_sv,
-                    d_tables, d_cold, d_lengths, d_last, d_temps,
-                    d_seeds)
-            else:
-                pk, pv, toks, lps, bad = self._decode(
-                    self._decode_params, pk, pv,
-                    d_tables, d_lengths, d_last, d_temps, d_seeds)
-            cols.append((toks, lps, bad))
-        self.pool.commit(pk, pv)
-        return cols
-
-    def _chain_emit(self, dslots, active, toks, lps, bad) -> None:
-        live = set(dslots)
-        for c in range(toks.shape[0]):
-            for i in sorted(live):
-                if not active[c, i]:
-                    continue
-                s = self.sched.slots[i]
-                req = s.request
-                if c > 0:
-                    req.spec_proposed += 1
-                    self.spec_proposed += 1
-                if bad[c, i]:
-                    if c > 0:
-                        req.spec_rejected += 1
-                        self.spec_rejected += 1
-                    self._quarantine(i)
-                    live.discard(i)
-                    continue
-                if c > 0:
-                    req.spec_accepted += 1
-                    self.spec_accepted += 1
-                s.length += 1
-                self._emit(i, int(toks[c, i]), float(lps[c, i]))
-                if req.done:
-                    live.discard(i)
-
     def _run_spec_step(self, dslots: list[int]) -> None:
         """The fused draft+verify speculative step (spec_draft
         "self-<j>" / "quant"): ONE dispatch drafts k proposals per
@@ -1448,7 +1271,7 @@ class ServeEngine:
                 # untouched round-17 one, which is the exactness argument.
                 # The cost: a sequence's whole table must fit hot during
                 # its spec step (ensure_hot raises otherwise) — spec decode
-                # does not stream cold pages; the chain schedule does.
+                # does not stream cold pages.
                 allb = []
                 for i in dslots:
                     allb.extend(self.sched.slots[i].blocks)

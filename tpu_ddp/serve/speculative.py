@@ -1,38 +1,21 @@
 """Speculative decoding: up to ``k+1`` tokens per engine step instead
 of one (DESIGN.md §26).
 
-The non-speculative engine advances one token per engine step — each
-step pays the full host-side batch assembly, one dispatch, one host
-sync, and one pass over the weights to produce ONE token per live
-slot. Speculation multiplies tokens per step. Three draft families,
-selected by the ``spec_draft`` knob, split along the exactness axis:
+The plain engine (``spec_k == 0``) emits one token per live slot per
+decode program, dispatched a step ahead of its harvest (DESIGN.md
+§19). Speculation multiplies tokens per program: classic
+draft-then-verify, fused into ONE jitted program
+(``build_spec_step``). The ``spec_draft`` knob picks the draft:
+``"self-<j>"`` is an early exit over the target's first j blocks
+sharing ln_f/head, ``"quant"`` a full-depth int8 twin — the natural
+pairing with a quantized target, ops/quant.py. The draft proposes k
+tokens by ``lax.scan``; the target then evaluates all k+1 columns
+inside the same program and samples its own token at every position.
+One dispatch and one host sync per step for up to k+1 tokens, where
+the draft's shallow/int8 steps cost a fraction of the full-depth
+steps they stand in for.
 
-``"chain"`` (the default) — the k+1-dispatch schedule. One engine
-step runs k+1 *sequential* calls of the engine's OWN compiled decode
-program, each feeding the token the previous call sampled. Because
-every emitted sample comes from the SAME compiled program the k=0
-engine runs, the emitted (token, logprob) stream is **bitwise
-identical** to the non-speculative stream — structurally, not
-probabilistically. There is no separate draft, so every "proposal"
-is accepted by construction and no KV rollback can occur; block
-allocation is per column, exactly the baseline's lazy `ensure_block`.
-What it buys: the per-step host work (admission, shedding, batch
-assembly, metrics, per-token Python bookkeeping) amortizes over k+1
-tokens — measured >2x tokens/sec on the CPU sweep (the regime where
-host overhead rivals the dispatch; experiments/spec_sweep.json).
-
-``"self-<j>"`` / ``"quant"`` — classic draft-then-verify, fused into
-ONE jitted program (``build_spec_step``): a draft (early exit over
-the target's first j blocks sharing ln_f/head, or a full-depth int8
-twin — the natural pairing with a quantized target, ops/quant.py)
-proposes k tokens by ``lax.scan``; the target then evaluates all k+1
-columns inside the same program and samples its own token at every
-position. One dispatch and one host sync per step for up to k+1
-tokens — the accelerator-targeted schedule, where the draft's
-shallow/int8 steps cost a fraction of the full-depth steps they
-stand in for.
-
-**The accept rule** (fused families; isolated in ``accept_length``):
+**The accept rule** (isolated in ``accept_length``):
 the host emits the longest prefix of *target* samples whose inputs
 the draft guessed right — column ``c`` is valid iff the draft's
 proposal for position ``c`` equals the target's own sample at
@@ -48,7 +31,7 @@ Every position samples with the same stateless
 categorical draw whenever its logits are close, which is what buys
 the acceptance rate at temperature > 0.
 
-**KV rollback** (fused families): draft and verify both scatter K/V
+**KV rollback**: draft and verify both scatter K/V
 into the paged pool at positions ``L..L+k`` (verify overwrites every
 layer with target values BEFORE attending, so accepted positions end
 bitwise correct regardless of the draft's arithmetic). On rejection
@@ -61,20 +44,19 @@ request's ``prompt + max_new`` budget are masked to the null block,
 so speculation never allocates beyond the admission-time worst-case
 reservation.
 
-**Why the fused families do not claim bitwise parity on CPU** (and
-why "chain" exists): the verify columns are unrolled inside the one
-program with per-column shapes identical to the one-token decode
+**Why the fused program does not claim bitwise parity with the
+one-token step on CPU**: the verify columns are unrolled inside the
+one program with per-column shapes identical to the one-token decode
 bank, but XLA is free to re-tile or horizontally fuse across
 columns — on the CPU backend this drifts individual logits by an ulp
 relative to the standalone decode program, occasionally flipping a
 categorical draw. (A W-wide batched verify drifts the same way via
 gemm M-extent tiling, and ``lax.scan`` column bodies via loop-region
-fusion; ``optimization_barrier`` does not prevent it.) The only
-structural cross-step guarantee is *reusing the same compiled
-program object for every emitted sample* — which is exactly the
-"chain" schedule. The sweep therefore enforces bitwise parity on
-chain cells and reports token agreement + max logprob deviation on
-fused cells (experiments/spec_sweep.json).
+fusion; ``optimization_barrier`` does not prevent it.) Only samples
+of the same compiled program are bitwise comparable, so the tests
+hold the fused stream to its own invariants (budgets, the ledger
+identity, pool accounting, version stamps: tests/test_speculative.py),
+not to equality with the one-token stream.
 """
 
 from __future__ import annotations
@@ -92,40 +74,15 @@ from tpu_ddp.models.decode import (
     sample_token,
 )
 from tpu_ddp.serve.kv_pool import PagedKVPool, gather_view, rows
+from tpu_ddp.utils.config import parse_spec_draft
 from tpu_ddp.utils.profiling import SERVE_SPEC, program
 
 __all__ = ["parse_spec_draft", "draft_bank", "verify_bank",
            "build_spec_step", "accept_length", "SPEC_DRAFTS"]
 
-# The draft-family grammar for the spec_draft knob: "chain" is the
-# exact same-program schedule (no separate draft), "self-<j>" the
-# early-exit draft (first j target blocks + the shared ln_f/head),
-# "quant" the full-depth int8-quantized twin.
-SPEC_DRAFTS = ("chain", "self-1", "self-2", "quant")
-
-
-def parse_spec_draft(spec: str) -> tuple[str, int | None]:
-    """Validate + parse the ``spec_draft`` grammar: ``"chain"`` (the
-    exact k+1-dispatch schedule), ``"self-<j>"`` (early-exit over the
-    target's first j blocks, j >= 1) or ``"quant"`` (full-depth int8
-    draft). Returns ("chain", None), ("self", j) or ("quant", None);
-    raises ValueError on junk — the config env surface routes through
-    this (knob_audit check 6)."""
-    s = str(spec).strip()
-    if s == "chain":
-        return "chain", None
-    if s == "quant":
-        return "quant", None
-    if s.startswith("self-"):
-        try:
-            j = int(s[len("self-"):])
-        except ValueError:
-            j = 0
-        if j >= 1:
-            return "self", j
-    raise ValueError(
-        f"spec_draft={spec!r}: expected 'chain', 'self-<j>' (j >= 1) "
-        "or 'quant' (TPU_DDP_SPEC_DRAFT)")
+# Values of the spec_draft knob for callers that enumerate; the grammar
+# is utils/config.py's parse_spec_draft.
+SPEC_DRAFTS = ("self-1", "self-2", "quant")
 
 
 def draft_bank(model, num_layers: int, block_size: int,
@@ -186,9 +143,8 @@ def verify_bank(model, block_size: int, blocks_per_seq: int, params,
     program, each column the one-token ``serve.engine.decode_bank``
     math at the same per-column shapes — the closest a fused program
     gets to the standalone decode step (see the module docstring for
-    why cross-program bitwise parity still isn't guaranteed on CPU,
-    and the "chain" family for the structural guarantee). Every
-    column scatters its K/V into the pool —
+    why cross-program bitwise parity still isn't guaranteed on CPU).
+    Every column scatters its K/V into the pool —
     overwriting whatever the draft wrote there with target values —
     before attending, and positions at or beyond ``limits`` scatter
     to the null block. Samples the target's own token at every

@@ -320,26 +320,23 @@ KNOBS: tuple[Knob, ...] = (
              "admission from FIFO to weighted fair queueing with "
              "lowest-class-first shedding; empty = single-tenant"),
     # Speculative decoding + quantized decode (serve/speculative.py,
-    # ops/quant.py — DESIGN.md §26): raw tokens/sec multipliers,
-    # measured by scripts/spec_sweep.py.
+    # ops/quant.py — DESIGN.md §26): no cell measures them yet
+    # (ROADMAP W5).
     Knob("spec_k", "spec_k", "TPU_DDP_SPEC_K",
          values=(0, 4, 12), flag="--spec-k",
          objective="goodput",
          doc="speculative proposals verified per engine step "
              "(serve/speculative.py); 0 = the one-token baseline. "
-             "Larger k amortizes more per-step host/dispatch overhead "
-             "per emitted token but wastes compute past the draft's "
-             "acceptance horizon (fused families) or stretches the "
-             "emission burst (chain)"),
+             "Larger k emits more tokens per weight read while the "
+             "draft is right but wastes compute past the draft's "
+             "acceptance horizon"),
     Knob("spec_draft", "spec_draft", "TPU_DDP_SPEC_DRAFT",
-         values=("chain", "self-1", "quant"), flag="--spec-draft",
+         values=("self-1", "quant"), flag="--spec-draft",
          objective="goodput",
-         doc="draft family for speculation: 'chain' re-dispatches the "
-             "engine's own compiled decode program k+1 times "
-             "(bitwise-exact stream — NOT semantic), 'self-<j>' "
-             "early-exits over the target's first j blocks, 'quant' "
-             "runs a full-depth int8 twin; the fused families trade "
-             "exactness on CPU for one dispatch per step"),
+         doc="draft family for speculation: 'self-<j>' early-exits "
+             "over the target's first j blocks, 'quant' runs a "
+             "full-depth int8 twin; both emit only the target's own "
+             "samples, one dispatch per step"),
     Knob("decode_quant", "decode_quant", "TPU_DDP_DECODE_QUANT",
          values=("none", "int8"), flag="--decode-quant",
          objective="goodput", semantic=True,
@@ -645,7 +642,7 @@ def violations(assignment: Mapping, ctx: Workload) -> list[str]:
             bad.append("steps_per_dispatch>1 with an in-loop cadence — "
                        "the engine falls back to the per-step path")
     # Speculative-decoding knobs (serve/speculative.py §26).
-    if get("spec_draft", "chain") != "chain" and get("spec_k", 0) == 0:
+    if get("spec_draft", "self-1") != "self-1" and get("spec_k", 0) == 0:
         bad.append(
             f"spec_draft={get('spec_draft')!r} with spec_k=0 — no "
             "speculative step ever runs, so the draft family is inert "

@@ -48,6 +48,30 @@ def _env_num(name: str, conv, default):
                          f"{conv.__name__}") from None
 
 
+def parse_spec_draft(spec: str) -> tuple[str, int | None]:
+    """The ``spec_draft`` grammar, written here once (``TrainConfig``,
+    ``tpu_ddp.launch`` and ``ServeEngine`` all call this): ``"self-<j>"``
+    (early exit over the target's first j blocks, j >= 1) or ``"quant"``
+    (full-depth int8 draft). Returns ("self", j) or ("quant", None);
+    raises ValueError on anything else."""
+    s = str(spec).strip()
+    if s == "quant":
+        return "quant", None
+    if s.startswith("self-"):
+        j = s[len("self-"):]
+        if j.isdigit() and int(j) >= 1:
+            return "self", int(j)
+    if s == "chain":
+        raise ValueError(
+            "spec_draft='chain' is gone: spec_k=0 already dispatches a "
+            "decode step ahead of its harvest (unset TPU_DDP_SPEC_K and "
+            "TPU_DDP_SPEC_DRAFT); the drafts for spec_k > 0 are "
+            "'self-<j>' and 'quant'")
+    raise ValueError(
+        f"spec_draft={spec!r}: expected 'self-<j>' (j >= 1) or 'quant' "
+        "(TPU_DDP_SPEC_DRAFT)")
+
+
 @dataclasses.dataclass
 class TrainConfig:
     """One training run's configuration (defaults = the reference's)."""
@@ -263,13 +287,11 @@ class TrainConfig:
     # docs/DESIGN.md §26): proposals verified per engine step
     # (0 = off, the one-token baseline). Env: TPU_DDP_SPEC_K.
     spec_k: int = 0
-    # Draft family for speculation: "chain" (same-program schedule,
-    # bitwise-exact stream), "self-<j>" (early exit over the target's
-    # first j blocks) or "quant" (full-depth int8 twin). Mirrors
-    # serve/speculative.py parse_spec_draft (the source of truth,
-    # which re-validates at engine construction). Env:
-    # TPU_DDP_SPEC_DRAFT.
-    spec_draft: str = "chain"
+    # Draft family for speculation: "self-<j>" (early exit over the
+    # target's first j blocks) or "quant" (full-depth int8 twin);
+    # parse_spec_draft above holds the grammar. Inert at spec_k == 0.
+    # Env: TPU_DDP_SPEC_DRAFT.
+    spec_draft: str = "self-1"
     # Weight-only int8 decode compute (tpu_ddp/ops/quant.py): "none"
     # serves fp, "int8" quantizes every decode-path projection
     # per-output-channel at engine construction (re-derived on each
@@ -657,17 +679,7 @@ class TrainConfig:
         env_sd = os.environ.get("TPU_DDP_SPEC_DRAFT")
         if env_sd:
             self.spec_draft = env_sd
-        # Mirrors serve/speculative.py parse_spec_draft (the source of
-        # truth, which re-validates at engine construction): "chain",
-        # "self-<j>" (j >= 1) or "quant".
-        sd = str(self.spec_draft).strip()
-        ok = sd in ("chain", "quant")
-        if not ok and sd.startswith("self-"):
-            ok = sd[len("self-"):].isdigit() and int(sd[5:]) >= 1
-        if not ok:
-            raise ValueError(
-                f"spec_draft={self.spec_draft!r}: expected "
-                "chain|self-<j>|quant (TPU_DDP_SPEC_DRAFT)")
+        parse_spec_draft(self.spec_draft)
         env_dq = os.environ.get("TPU_DDP_DECODE_QUANT")
         if env_dq:
             self.decode_quant = env_dq

@@ -60,7 +60,7 @@ SPANS = {
         "and is read with the step's decode rows",
         ("rid", "tokens", "start", "final")),
     "tpu_ddp.serve.decode": (
-        "serving", "one whole-bank decode step (plain, chain or fused "
+        "serving", "one whole-bank decode step (plain or fused "
         "speculative): its dispatch and, in the plain engine, the "
         "harvest of the step BEFORE it. ahead: 1 where that step's "
         "decode rows were still unread at the dispatch, 0 where the "
