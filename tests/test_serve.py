@@ -13,6 +13,8 @@ the two memoized jitted step programs (engine.py) — the whole file
 compiles the decode/prefill steps once.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,6 +22,7 @@ import pytest
 
 from tpu_ddp.models.generate import generate
 from tpu_ddp.models.transformer import make_transformer, rope
+from tpu_ddp.ops.pallas import paged_attention
 from tpu_ddp.serve import (
     PagedKVPool,
     Request,
@@ -310,6 +313,174 @@ class TestPagedDecodeKernel:
                 assert list(h.tokens) == list(w.tokens)
                 assert h.logprobs == w.logprobs
         assert eng.sched.accounting_ok()
+
+
+class TestOneGatherOnThePool:
+    """Every program that gathers K/V pages reads them from the whole
+    pool in one gather (``pool[li, tables]``). Slicing a layer out
+    first, ``pool[li]`` then ``[tables]``, gives the same values, but
+    XLA:TPU materialises the slice: all N pages of the layer copied,
+    for K and for V in every layer, to read the few a table names."""
+
+    # Block counts unlike every other dimension of these programs
+    # (slots 4, blocks a slot 8, block 8, chunk 8, widths 32-1024), so
+    # a value shaped (N, ...) or (1, N, ...) can only be a pool layer.
+    HOT, COLD = 37, 41
+    LAYER = re.compile(rf"tensor<(?:1x)?(?:{HOT}|{COLD})x[^>]*>")
+    TIERED = dict(kv_tiers=2, hbm_blocks=HOT, cold_blocks=COLD)
+    # program -> (engine arguments, the method that lowers it)
+    PROGRAMS = {
+        "serve_prefill": ({}, "lower_prefill_step"),
+        "serve_decode": ({}, "lower_decode_step"),
+        "serve_spec": (dict(spec_k=2, spec_draft="self-1"),
+                       "lower_spec_step"),
+        "serve_decode_tiered": (TIERED, "lower_tiered_decode_step"),
+        "serve_prefill_tiered": (TIERED, "lower_tiered_prefill_step"),
+        "serve_prefill_cp": (dict(cp_prefill="ring"),
+                             "lower_prefill_step"),
+        "serve_adopt_decode": ({}, "lower_adopt_decode"),
+    }
+
+    def _lowered(self, program, model, params):
+        kw, lower = self.PROGRAMS[program]
+        kw = dict(GEOM, num_blocks=self.HOT, **kw)
+        if program == "serve_prefill_cp":
+            from tpu_ddp.parallel.mesh import (make_mesh,
+                                               replicated_sharding)
+            kw["mesh"] = make_mesh(jax.devices()[:2], dp=1, sp=2)
+            params = jax.device_put(params,
+                                    replicated_sharding(kw["mesh"]))
+        if program == "serve_adopt_decode":
+            from tpu_ddp.fleet.disagg import DisaggEngine as cls
+        else:
+            cls = ServeEngine
+        return getattr(cls(model, params, **kw), lower)().as_text()
+
+    @pytest.mark.parametrize("program", list(PROGRAMS))
+    def test_no_value_of_a_layers_shape_and_pools_donated(
+            self, program, model, params):
+        # The fixture's model is outside the paged kernel's predicate:
+        # the decode bodies here are the ones that gather.
+        assert not paged_attention.supports(
+            model.head_dim, GEOM["block_size"], jnp.float32,
+            model.compute_dtype)
+        text = self._lowered(program, model, params)
+        assert "stablehlo.gather" in text
+        layer = self.LAYER.findall(text)
+        assert not layer, (program, sorted(set(layer)))
+        # pool_k and pool_v (the hot pair where tiered) are arguments
+        # of the pool's whole shape, still donated.
+        main = next(ln for ln in text.splitlines()
+                    if "func.func public @main" in ln)
+        donated = re.findall(
+            rf"tensor<{model.num_layers}x{self.HOT}x[^>]*> "
+            r"\{[^%]*(?:tf\.aliasing_output|jax\.buffer_donor)",
+            main.split(") -> ")[0])
+        assert len(donated) == 2, (program, main[:400])
+
+    def test_the_check_catches_a_sliced_layer(self, model, params,
+                                              monkeypatch):
+        """The parent's form, put back: the same check must see it."""
+        from tpu_ddp.serve import engine as engine_mod
+
+        def sliced(pool, li, tables, m):
+            return pool[li][tables].reshape(
+                tables.shape[0], -1, m.kv_heads, m.head_dim)
+
+        monkeypatch.setattr(engine_mod, "gather_view", sliced)
+        engine_mod._build_prefill_step.cache_clear()
+        try:
+            text = self._lowered("serve_prefill", model, params)
+        finally:
+            engine_mod._build_prefill_step.cache_clear()
+        assert self.LAYER.findall(text)
+
+    @pytest.mark.parametrize("tables_shape", [(1, 8), (4, 8)])
+    def test_gather_view_is_the_sliced_gather_bit_for_bit(
+            self, model, tables_shape):
+        from tpu_ddp.serve.kv_pool import gather_view
+        rng = np.random.default_rng(5)
+        L, N, bs = 3, self.HOT, 8
+        width = model.kv_heads * model.head_dim
+        # bf16 bit patterns drawn whole, NaNs and infinities among
+        # them: compared as bits, so a value changed in any way shows.
+        bits = rng.integers(0, 2 ** 16, size=(L, N, bs, width),
+                            dtype=np.uint16)
+        pool = jax.lax.bitcast_convert_type(jnp.asarray(bits),
+                                            jnp.bfloat16)
+        tables = rng.integers(0, N, size=tables_shape).astype(np.int32)
+        # the null block, a repeated id and the last block id
+        tables[0, :4] = [PagedKVPool.NULL_BLOCK, N - 1, 5, 5]
+        for li in range(L):
+            got = jax.jit(gather_view, static_argnums=(1, 3))(
+                pool, li, jnp.asarray(tables), model)
+            want = bits[li][tables].reshape(
+                tables_shape[0], -1, model.kv_heads, model.head_dim)
+            assert got.dtype == jnp.bfloat16
+            np.testing.assert_array_equal(
+                np.asarray(jax.lax.bitcast_convert_type(
+                    got, jnp.uint16)), want, err_msg=f"layer {li}")
+
+
+class TestAttendChunk:
+    """A prefill chunk attends in equal parts where the float32 scores
+    of all its queries would pass engine.PREFILL_SCORES_BYTES (what
+    keeps them in on-chip memory on the v5e; DESIGN.md §19)."""
+
+    def test_parts_follow_the_scores_size(self, monkeypatch):
+        from tpu_ddp.serve import engine as engine_mod
+        calls = []
+        monkeypatch.setattr(
+            engine_mod, "attend_cached",
+            lambda model, q, ck, cv, p: calls.append(q.shape[1]) or q)
+        # The benchmark's chunk: 24 heads x 256 queries x 4096 keys in
+        # float32 is 100 MB, two parts; a fast-tier chunk is one.
+        for heads, c, t, want in [(24, 256, 4096, [128, 128]),
+                                  (24, 128, 4096, [128]),
+                                  (4, 8, 64, [8]),
+                                  (64, 256, 8192, [32] * 8)]:
+            calls.clear()
+            q = jnp.zeros((1, c, heads, 2))
+            out = engine_mod.attend_chunk(
+                None, q, jnp.zeros((1, t, 1, 2)), None, jnp.arange(c))
+            assert calls == want and out.shape == q.shape
+
+    def test_parts_give_what_the_whole_gives(self, model, params,
+                                             monkeypatch):
+        from tpu_ddp.models.decode import attend_cached
+        from tpu_ddp.serve import engine as engine_mod
+        rng = np.random.default_rng(11)
+        q = jnp.asarray(rng.standard_normal(
+            (1, 16, model.num_heads, model.head_dim)), jnp.float32)
+        ck, cv = (jnp.asarray(rng.standard_normal(
+            (1, 64, model.kv_heads, model.head_dim)), jnp.float32)
+            for _ in range(2))
+        p = 20 + jnp.arange(16)
+        whole = attend_cached(model, q, ck, cv, p)
+        monkeypatch.setattr(engine_mod, "PREFILL_SCORES_BYTES",
+                            4 * model.num_heads * 4 * 64)   # 4 rows
+        np.testing.assert_array_equal(
+            np.asarray(engine_mod.attend_chunk(model, q, ck, cv, p)),
+            np.asarray(whole))
+
+    def test_engine_in_parts_matches_generate(self, model, params,
+                                              monkeypatch):
+        from tpu_ddp.serve import engine as engine_mod
+        monkeypatch.setattr(engine_mod, "PREFILL_SCORES_BYTES",
+                            4 * model.num_heads * 2 * 64)   # 2 rows
+        engine_mod._build_prefill_step.cache_clear()
+        try:
+            eng = _engine(model, params)
+            cases = [(19, 6), (8, 5), (3, 4)]
+            reqs = [eng.submit(_prompt(L, seed=70 + i), n)
+                    for i, (L, n) in enumerate(cases)]
+            eng.run()
+        finally:
+            engine_mod._build_prefill_step.cache_clear()
+        for i, ((L, n), req) in enumerate(zip(cases, reqs)):
+            np.testing.assert_array_equal(
+                np.asarray(req.tokens),
+                _ref_greedy(model, params, _prompt(L, seed=70 + i), n))
 
 
 class TestLifecycle:

@@ -1,0 +1,108 @@
+"""``serve_prefill`` at the benchmark's serving geometry (StarCoder2-3B
+widths, 30 layers, 32 slots x 4096 positions, block 16, chunk 256),
+compiled HERE for a described TPU v5e: no chip, no arrays, about half a
+minute. It holds what PR 32 learned on the chip (docs/DESIGN.md §19,
+PERF.md section 6) and what no CPU lowering can show:
+
+- no value of one pool layer's shape is left in the program (the
+  ``pool[li]`` copy, 67 MB a time, 60 a chunk);
+- the float32 attention scores, the program's largest temporaries, are
+  assigned to the on-chip memory beside HBM (``S(1)`` in the compiled
+  layouts) and not to HBM. Taking the layer copies out alone sent all
+  30 of the 100 MB scores to HBM and made the chunk 26% slower;
+  ``engine.attend_chunk`` halves them so that they stay.
+
+The compiler decides both for the whole program, so the whole program
+is what is compiled. One file, the topology described inside a fixture:
+only the worker that runs this file loads the TPU's library.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from tpu_ddp.models.transformer import TransformerLM
+from tpu_ddp.serve import engine
+
+SLOTS, MAX_SEQ, BLOCK, CHUNK = 32, 4096, 16, 256
+BPS = MAX_SEQ // BLOCK
+NUM_BLOCKS = SLOTS * BPS + 1
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever libtpu raises here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compiled(one_chip):
+    """(model, compiled text of the ENTRY computation)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    model = TransformerLM(
+        name="starcoder2-3b", vocab_size=49152, num_layers=30,
+        num_heads=24, num_kv_heads=2, d_model=3072, d_ff=12288,
+        max_seq_len=MAX_SEQ, param_dtype=jnp.bfloat16)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(model.init, jax.random.key(0)))
+    pool = sds((model.num_layers, NUM_BLOCKS, BLOCK,
+                model.kv_heads * model.head_dim), jnp.bfloat16)
+    args = (params, pool, pool, sds((BPS,), jnp.int32),
+            sds((1, CHUNK), jnp.int32), sds((), jnp.int32),
+            sds((), jnp.int32), sds((), jnp.float32), sds((), jnp.int32))
+    step = engine._build_prefill_step(model, BLOCK, BPS)
+    # A compile for a described chip is written to the persistent cache
+    # and cannot be read back without the chip: keep it out.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = step.trace(*args).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    return model, text[text.index("ENTRY "):]
+
+
+def test_no_value_of_a_pool_layers_shape(compiled):
+    _, entry = compiled
+    layer = re.findall(rf"bf16\[(?:1,)?{NUM_BLOCKS},{BLOCK},\d+\]", entry)
+    assert not layer, sorted(set(layer))
+    # ... and the pool itself is only ever a parameter or the in-place
+    # result of kv_write's scatters: nothing else makes one.
+    pool = rf"bf16\[30,{NUM_BLOCKS},{BLOCK},\d+\]\{{[^}}]*\}}"
+    makers = set(re.findall(rf"= {pool} ([\w\-]+)\(", entry))
+    assert makers <= {"parameter", "fusion", "bitcast"}, makers
+    fusions = re.findall(rf"= {pool} fusion\(([^\n]*)", entry)
+    assert fusions and all("kv_write/scatter" in f for f in fusions)
+
+
+def test_scores_are_assigned_to_on_chip_memory(compiled):
+    model, entry = compiled
+    # (1, KV, G, queries of one part, keys) float32, as attend_cached
+    # makes them; attend_chunk decides the queries of a part.
+    shape = (rf"f32\[1,{model.kv_heads},"
+             rf"{model.num_heads // model.kv_heads},(\d+),{MAX_SEQ}\]")
+    made = re.findall(rf"= \(?[^=\n]*{shape}\{{([^}}]*)\}}[^=\n]* fusion\(",
+                      entry)
+    rows = {int(q) for q, _ in made}
+    assert rows == {CHUNK // 2}, rows       # two parts of 128 rows
+    assert len(made) == 2 * 30
+    on_chip = sum("S(1)" in layout for _, layout in made)
+    assert on_chip >= 0.9 * len(made), (
+        f"{on_chip} of {len(made)} score tensors are in on-chip memory: "
+        "the rest are written to HBM and read back twice, 0.37 ms a "
+        "layer each (PERF.md section 6, PR 32)")

@@ -23,6 +23,10 @@ only under ``spec_k > 0``):
   padded positions scatter to the null block and their outputs are
   masked by the causal position test). Chunking bounds how long a
   long prompt can stall the decode batch: one chunk per engine step.
+  Each layer gathers the slot's pages from the whole pool in one
+  gather (kv_pool.gather_view) and attends over the view in as many
+  equal parts of the chunk as keep a part's float32 scores inside the
+  chip's on-chip memory (:func:`attend_chunk`; one part at most sizes).
 
 The plain engine (``spec_k == 0``) runs ONE DECODE STEP AHEAD of its
 readback: a step dispatches its prefill chunk and its decode program
@@ -161,9 +165,13 @@ def kv_write(pool_k, pool_v, li: int, bidx, off, k, v):
 
 def paged_kv(model, pool_k, pool_v, li: int, bidx, off, k, v, tables):
     """Layer ``li``'s half of the paged cache, the gather form: write
-    the new rows (:func:`kv_write`), then gather the layer's pool
+    the new rows (:func:`kv_write`), then gather the layer's pages
     through ``tables`` (S, BPS) into the contiguous (S, BPS*block_size,
     KV, hd) view the decode core attends over (scope ``kv_gather``).
+    Both halves index the whole pool, a scatter at ``[li, bidx, off]``
+    and one gather at ``[li, tables]``: the pool stays the donated
+    argument updated in place, and no copy of a layer is made to read
+    ``BPS`` pages of it (kv_pool.gather_view).
     The prefill chunk runs it, and the decode step where the paged
     kernel does not take the shapes, so a trace names the two halves
     the same way in both."""
@@ -172,6 +180,37 @@ def paged_kv(model, pool_k, pool_v, li: int, bidx, off, k, v, tables):
         ck = gather_view(pool_k, li, tables, model)
         cv = gather_view(pool_v, li, tables, model)
     return pool_k, pool_v, ck, cv
+
+
+# What one ``attend_cached`` call of a prefill chunk may hold as float32
+# scores, (heads, queries, keys): half of the 128 MiB of on-chip memory
+# beside a v5e's HBM. Whether the compiler keeps the scores there or in
+# HBM is its own choice, made for the whole program, and at a size near
+# the whole of that memory it flips with the program's depth (the
+# benchmark's chunk, 24 heads x 256 x 4096, is 100 MB: on-chip in a
+# 28-layer program, in HBM, written once and read twice, in a 29-layer
+# one; DESIGN.md §19). At half of it the scores stay on-chip.
+PREFILL_SCORES_BYTES = 64 << 20
+
+
+def attend_chunk(model, q, ck, cv, p):
+    """``attend_cached`` for a prefill chunk's queries ``q`` (1, C, H,
+    hd) at positions ``p`` (C,), over equal parts of the chunk where the
+    scores of all of it would pass ``PREFILL_SCORES_BYTES``: a query
+    row's result does not depend on the rows beside it, so the parts
+    give what the whole gives."""
+    C = q.shape[1]
+    scores_bytes = 4 * q.shape[0] * q.shape[2] * C * ck.shape[1]
+    parts = 1
+    while (scores_bytes > parts * PREFILL_SCORES_BYTES
+           and C % (2 * parts) == 0):
+        parts *= 2
+    if parts == 1:
+        return attend_cached(model, q, ck, cv, p)
+    n = C // parts
+    return jnp.concatenate(
+        [attend_cached(model, q[:, i:i + n], ck, cv, p[i:i + n])
+         for i in range(0, C, n)], axis=1)
 
 
 def decode_bank(model, block_size: int, params, pool_k, pool_v, tables,
@@ -277,7 +316,7 @@ def _build_prefill_step(model, block_size: int, blocks_per_seq: int):
                 pool_k, pool_v, ck, cv = paged_kv(
                     model, pool_k, pool_v, li, blk_idx, off, k[0], v[0],
                     table[None])
-                o = attend_cached(model, q, ck, cv, p)
+                o = attend_chunk(model, q, ck, cv, p)
             x = block_finish(model, blkp, x, o)
         logits = model.head_apply(params, x)[0]               # (C, V)
         last = jnp.clip(prompt_len - 1 - start, 0, C - 1)

@@ -12,10 +12,14 @@ is columns ``[h*hd, (h+1)*hd)``), so a page is one contiguous
 ``(block_size, KV*hd)`` tile run that a single DMA moves and the
 paged decode kernel (ops/pallas/paged_attention.py) reads in place;
 :func:`rows` / :func:`gather_view` convert at the write and gather
-sites. Blocks are allocated lazily as a sequence grows and returned
-on retirement, so cache memory tracks the LIVE token count, not the
-worst case, and the same HBM serves many more concurrent sequences
-(the vLLM PagedAttention argument).
+sites. The programs that gather take a layer's pages from the WHOLE
+pool in one gather (``pool[li, tables]``, :func:`gather_view`) and
+never slice the layer out first: a static slice of a program's
+argument is a copy on XLA:TPU, one whole layer of pages each time
+(DESIGN.md §19). Blocks are allocated lazily as a sequence grows and
+returned on retirement, so cache memory tracks the LIVE token count,
+not the worst case, and the same HBM serves many more concurrent
+sequences (the vLLM PagedAttention argument).
 
 Accounting is host-side and exact, and deliberately simple: a free
 list of block ids plus a per-block REFCOUNT. Block 0 is the NULL block
@@ -113,8 +117,16 @@ def rows(x):
 def gather_view(pool, li: int, tables, model):
     """Layer ``li``'s pages gathered through ``tables`` (S, BPS) into
     the contiguous (S, BPS*block_size, KV, hd) view
-    ``decode.attend_cached`` reads."""
-    pages = pool[li][tables]
+    ``decode.attend_cached`` reads.
+
+    ``pool[li, tables]`` is ONE gather on the whole pool. Slicing the
+    layer out first (``pool[li]``, then ``[tables]``) gives the same
+    values, but a static slice of a program's argument is a copy on
+    XLA:TPU: it materialised all N pages of the layer (67 MB of the
+    benchmark's pool, once for K and once for V in each of 30 layers)
+    to read the BPS that ``tables`` names. No step program slices a
+    layer out of a pool (tests/test_serve.py holds them to that)."""
+    pages = pool[li, tables]
     return pages.reshape(pages.shape[0], -1, model.kv_heads,
                          model.head_dim)
 

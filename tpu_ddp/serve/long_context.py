@@ -50,7 +50,7 @@ from jax import lax
 from tpu_ddp.models.decode import (attend_cached, block_finish,
                                    project_qkv, sample_token)
 from tpu_ddp.parallel.compress import page_dequantize
-from tpu_ddp.serve.kv_pool import PagedKVPool, rows
+from tpu_ddp.serve.kv_pool import PagedKVPool, gather_view, rows
 from tpu_ddp.utils.profiling import (
     SERVE_DECODE_TIERED,
     SERVE_PREFILL_CP,
@@ -66,9 +66,11 @@ def _mixed_view(hot_buf, cold_buf, cold_scale, li, hot_tables,
     block. ``hot_tables``/``cold_tables`` (S, BPS) int32, slot 0 =
     not in that tier (both null pages are zeros, kept so by scrub).
     Returns (S, BPS, bs, KV*hd) in the hot dtype."""
-    hk = hot_buf[li][hot_tables]
-    ck = page_dequantize(cold_buf[li][cold_tables],
-                         cold_scale[li][cold_tables], hot_buf.dtype)
+    # One gather on each whole buffer, as kv_pool.gather_view: a layer
+    # sliced out first (``buf[li]``) would be copied whole.
+    hk = hot_buf[li, hot_tables]
+    ck = page_dequantize(cold_buf[li, cold_tables],
+                         cold_scale[li, cold_tables], hot_buf.dtype)
     is_hot = (hot_tables > 0)[..., None, None]
     return jnp.where(is_hot, hk, ck)
 
@@ -205,11 +207,10 @@ def build_cp_prefill_step(model, block_size: int, blocks_per_seq: int,
         cache_valid = jnp.arange(cache_len) < start
         x = params["embed"][tokens].astype(cd)   # (1, lc, dm)
         ks, vs = [], []
-        view = (1, cache_len, model.kv_heads, model.head_dim)
         for li, blkp in enumerate(params["blocks"]):
             q, k, v = project_qkv(model, blkp, x, p)
-            ck = pool_k[li][table].reshape(view).astype(cd)
-            cv = pool_v[li][table].reshape(view).astype(cd)
+            ck = gather_view(pool_k, li, table[None], model).astype(cd)
+            cv = gather_view(pool_v, li, table[None], model).astype(cd)
             if mode == "ulysses":
                 o = ulysses_attention(q, k, v, "sp", sp, causal=True,
                                       q_offset=start, cache_k=ck,
