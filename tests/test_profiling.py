@@ -71,6 +71,16 @@ def test_every_kernel_and_scope_name_is_in_its_table_and_used():
     assert set(scopes) == set(SCOPES)
 
 
+def test_every_gauge_of_the_table_is_observed_by_the_engine_and_printed():
+    observed = _calls(re.compile(r'metrics\.observe\(\s*"(\w+)"'),
+                      [ROOT / "tpu_ddp/serve/engine.py"])
+    assert set(profiling.GAUGES) <= set(observed)
+    section = (ROOT / "docs" / "DESIGN.md").read_text().split(
+        "Tracing", 1)[1]
+    for name in profiling.GAUGES:
+        assert f"`{name}`" in section, name
+
+
 def test_every_program_name_is_used_once_per_builder():
     used = _calls(re.compile(r"@program\((\w+)\)"))
     constants = {k: v for k, v in vars(profiling).items()

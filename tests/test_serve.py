@@ -1283,3 +1283,61 @@ class TestTrainServeRoundTrip:
         np.testing.assert_array_equal(
             np.asarray(req.tokens),
             _ref_greedy(model, dense, prompt, 4))
+
+
+class TestDenseProgramsPinned:
+    """``serve_decode`` and ``serve_prefill`` for the dense model at
+    ``sc2-serve-gen``'s geometry (StarCoder2-3B widths, 30 layers, 32
+    slots x 4096 positions, block 16, chunk 256), lowered from shapes
+    alone. The lowered text (no source locations in it) is what it was
+    before the engine learned to carry a second model family's recurrent
+    state (PR 33 made both hashes on the parent, commit a0a6f36, and on
+    the change: the same): the dense cell's programs did not move. A PR
+    that means to change the dense programs updates the hashes, and says
+    so; one that does not mean to has found out that it did."""
+
+    PINNED = {
+        "serve_decode": "8c2e2a407528bdef7124c5c8371c3204"
+                        "ad9f80097077bd58da066fb9759b7630",
+        "serve_prefill": "c719994826e0241107446259f665cbfe"
+                         "2381502cef14b703a47a482dedbe6f26",
+    }
+
+    def test_lowered_text_is_the_parents(self):
+        import hashlib
+        import json
+        from pathlib import Path
+
+        from tpu_ddp.models.transformer import TransformerLM
+        from tpu_ddp.serve import engine as engine_mod
+
+        root = Path(__file__).resolve().parents[1]
+        cfg = json.loads((root / "benchmark/configs/starcoder2-3b.json")
+                         .read_text())
+        geo = cfg["serve"]
+        S, B, C = geo["num_slots"], geo["block_size"], geo["prefill_chunk"]
+        bps = geo["max_seq_len"] // B
+        m = TransformerLM(
+            name=cfg["name"], vocab_size=cfg["vocab_size"],
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_key_value_heads"],
+            d_model=cfg["hidden_size"], d_ff=cfg["intermediate_size"],
+            max_seq_len=geo["max_seq_len"], param_dtype=jnp.bfloat16)
+        sds = jax.ShapeDtypeStruct
+        i32, f32 = jnp.int32, jnp.float32
+        p = jax.eval_shape(m.init, jax.random.key(0))
+        pool = sds((m.num_layers, S * bps + 1, B, m.kv_heads * m.head_dim),
+                   jnp.bfloat16)
+        lowered = {
+            "serve_decode": engine_mod._build_decode_step(m, B, bps).lower(
+                p, pool, pool, sds((S, bps), i32), sds((S,), i32),
+                sds((S,), i32), sds((S,), f32), sds((S,), i32)),
+            "serve_prefill": engine_mod._build_prefill_step(
+                m, B, bps).lower(
+                p, pool, pool, sds((bps,), i32), sds((1, C), i32),
+                sds((), i32), sds((), i32), sds((), f32), sds((), i32)),
+        }
+        got = {name: hashlib.sha256(low.as_text().encode()).hexdigest()
+               for name, low in lowered.items()}
+        assert got == self.PINNED

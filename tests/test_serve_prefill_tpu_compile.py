@@ -106,3 +106,92 @@ def test_scores_are_assigned_to_on_chip_memory(compiled):
         f"{on_chip} of {len(made)} score tensors are in on-chip memory: "
         "the rest are written to HBM and read back twice, 0.37 ms a "
         "layer each (PERF.md section 6, PR 32)")
+
+
+# ---- the hybrid cell's two programs (PR 33) -----------------------------------
+
+@pytest.fixture(scope="module")
+def hybrid_compiled(one_chip):
+    """``serve_decode`` and ``serve_prefill`` of ``gr4h-serve-chat``'s
+    configuration (published widths, 10 layers, 36 of 72 experts, 64
+    slots x 4096 positions, block 16, chunk 256), compiled for the v5e:
+    {program: (memory analysis, compiled text)}."""
+    import json
+    from pathlib import Path
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from benchmark.lib.families import granite_hybrid
+    from tpu_ddp.ops import pallas as pallas_ops
+
+    root = Path(__file__).resolve().parents[1]
+    cfg = json.loads((root / "benchmark/configs/"
+                      "granite-4.0-h-small-l10e36.json").read_text())
+    geo = cfg["serve"]
+    S, B, C = geo["num_slots"], geo["block_size"], geo["prefill_chunk"]
+    bps = geo["max_seq_len"] // B
+    model = granite_hybrid.build(cfg, max_seq_len=geo["max_seq_len"],
+                                 param_dtype=jnp.bfloat16)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(model.init, jax.random.key(0)))
+    pool = sds((1, S * bps + 1, B, model.kv_heads * model.head_dim),
+               jnp.bfloat16)
+    state = {k: sds(v.shape, v.dtype)
+             for k, v in model.state_shapes(S).items()}
+    i32, f32 = jnp.int32, jnp.float32
+    programs = {
+        "serve_decode": (
+            engine._build_decode_step(model, B, bps),
+            (params, pool, pool, state, sds((S, bps), i32), sds((S,), i32),
+             sds((S,), i32), sds((S,), f32), sds((S,), i32))),
+        "serve_prefill": (
+            engine._build_prefill_step(model, B, bps),
+            (params, pool, pool, state, sds((bps,), i32), sds((1, C), i32),
+             sds((), i32), sds((), i32), sds((), f32), sds((), i32),
+             sds((), i32))),
+    }
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    interpret = pallas_ops.interpret_mode
+    pallas_ops.interpret_mode = lambda: False   # the kernel, for the chip
+    out = {}
+    try:
+        for name, (step, args) in programs.items():
+            comp = step.trace(*args).lower(
+                lowering_platforms=("tpu",)).compile()
+            out[name] = (comp.memory_analysis(), comp.as_text())
+    finally:
+        pallas_ops.interpret_mode = interpret
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    return out
+
+
+@pytest.mark.parametrize("program,temp_gb", [("serve_decode", 0.1),
+                                             ("serve_prefill", 0.3)])
+def test_hybrid_programs_fit_and_update_state_and_pool_in_place(
+        hybrid_compiled, program, temp_gb):
+    """13.44 GB of arguments (weights 9.93, state 2.45, K/V pool 1.07) on
+    a 16 GB chip leave no room for a copy of the state: the 3.52 GB of
+    state and pool are aliased to the outputs, and the temporaries stay
+    small (0.04 and 0.14 GB when this was written)."""
+    mem, _ = hybrid_compiled[program]
+    assert mem.argument_size_in_bytes == pytest.approx(13.44e9, rel=5e-3)
+    assert mem.alias_size_in_bytes == pytest.approx(3.52e9, rel=5e-3)
+    assert mem.temp_size_in_bytes < temp_gb * 1e9
+
+
+def test_hybrid_decode_holds_the_grouped_products_and_the_paged_kernel(
+        hybrid_compiled):
+    """Twenty grouped products (two a layer) as the TPU's own ragged
+    dot, not a (T, E, C) dispatch; attention reads the pool in place."""
+    _, text = hybrid_compiled["serve_decode"]
+    entry = text[text.index("ENTRY "):]
+    assert len(re.findall(r"= [^=]*custom-call\([^\n]*ragged_dot", entry)) \
+        == 20 or entry.count(" %ragged-dot-none") >= 20
+    assert "paged_decode_attn" in text

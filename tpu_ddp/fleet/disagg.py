@@ -64,7 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpu_ddp.models.decode import check_decodable
+from tpu_ddp.models.decode import check_decodable, check_state_servable
 from tpu_ddp.parallel.compress import EdgeCodec
 from tpu_ddp.serve.engine import (
     Request,
@@ -195,6 +195,7 @@ class DisaggEngine:
                  metrics: MetricsLogger | None = None,
                  config=None):
         check_decodable(model)
+        check_state_servable(model, disagg=True)
         if config is None:
             from tpu_ddp.utils.config import TrainConfig
             config = TrainConfig()

@@ -23,9 +23,26 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from tpu_ddp.models.transformer import layer_norm
-
 _NEG_INF = -1e30
+
+# The serving features that assume every layer's cache is a list of
+# positions, and why each cannot take a model with recurrent state as it
+# stands (check_state_servable).
+_STATE_REFUSALS = {
+    "prefix_cache": "a shared prefix's K/V pages can be adopted, the "
+                    "recurrent state at the end of the prefix cannot: "
+                    "no snapshot of it is kept",
+    "kv_tiers": "the tiered step programs carry no state pool",
+    "spec_k": "a rejected draft token has already advanced the state, "
+              "and a state cannot be rolled back like a block table",
+    "cp_prefill": "the chunk's scan is sequential over positions; the "
+                  "context-parallel step shards them",
+    "decode_quant": "ops/quant.quantize_params knows the dense block's "
+                    "matrices only",
+    "mesh": "the state pool and the expert layer have no sharding rules",
+    "disagg": "the edge ships K/V blocks only; the state of a finished "
+              "prefill would stay behind",
+}
 
 
 def check_decodable(model) -> None:
@@ -43,8 +60,38 @@ def check_decodable(model) -> None:
             "canonical checkpoint path")
 
 
+def check_state_servable(model, **features) -> None:
+    """Refuse, with the reason, a model that keeps recurrent state
+    (``model.state_shapes``) under a serving feature that cannot carry
+    it. ``features``: keys of ``_STATE_REFUSALS``, true where the caller
+    has the feature in use. One place, so that such a model is never
+    half served."""
+    on = [k for k, v in features.items() if v]
+    if on and model.state_shapes(1):
+        raise ValueError(
+            f"{model.name} keeps recurrent state per sequence, which "
+            "these serving features cannot carry yet: " + "; ".join(
+                f"{k} ({_STATE_REFUSALS[k]})" for k in on))
+
+
+def gated_mlp(model, y, w1, w2):
+    """SiLU-gated MLP: ``(SiLU(u) * v) @ w2`` with ``[u, v] = y @ w1``."""
+    cd = model.compute_dtype
+    uv = jnp.einsum("...d,de->...e", y, w1.astype(cd),
+                    preferred_element_type=jnp.float32)
+    u, v = jnp.split(uv, 2, axis=-1)
+    act = (jax.nn.silu(u) * v).astype(cd)
+    return jnp.einsum("...e,ed->...d", act, w2.astype(cd),
+                      preferred_element_type=jnp.float32)
+
+
 def mlp(model, blk, y):
     """Block MLP on a decode/prefill activation bank ``y`` (B, L, dm).
+
+    A model that says which experts it holds (``model.held``) runs the
+    dropless expert layer over its share (parallel/moe.py
+    ``dropless_moe``, scope ``moe``) plus the shared gated MLP (scope
+    ``shared_mlp``), summed in float32.
 
     Dense models run the two qdot matmuls (fp or fused int8). MoE
     models run the routed layer (tpu_ddp/parallel/moe.py) with the
@@ -63,6 +110,16 @@ def mlp(model, blk, y):
     """
     from tpu_ddp.ops.quant import qdot
     cd = model.compute_dtype
+    if getattr(model, "held", None) is not None:
+        from tpu_ddp.parallel.moe import dropless_moe
+        with jax.named_scope("moe"):
+            out = dropless_moe(
+                y.reshape(-1, y.shape[-1]), blk["router"], blk["w1"],
+                blk["w2"], top_k=model.top_k, held=model.held)
+        with jax.named_scope("shared_mlp"):
+            out = out.reshape(y.shape) + gated_mlp(
+                model, y, blk["shared_w1"], blk["shared_w2"])
+        return out.astype(cd)
     if model.moe_experts:
         from tpu_ddp.parallel.moe import moe_mlp
         out, _ = moe_mlp(
@@ -74,6 +131,13 @@ def mlp(model, blk, y):
     y = qdot(y, blk["w1"], cd)
     y = jax.nn.gelu(y.astype(jnp.float32)).astype(cd)
     return qdot(y, blk["w2"], cd).astype(cd)
+
+
+def attn_scale(model) -> float:
+    """What attention scores are multiplied by: the model's own
+    ``attn_scale`` if it states one, else ``1/sqrt(head_dim)``."""
+    scale = getattr(model, "attn_scale", None)
+    return 1.0 / (model.head_dim ** 0.5) if scale is None else scale
 
 
 def attend_cached(model, q, ck, cv, q_pos):
@@ -88,7 +152,7 @@ def attend_cached(model, q, ck, cv, q_pos):
     (B, Lq, KV, G, hd) directly against the KV-width cache — the
     expansion is never materialized, preserving the smaller cache's
     bandwidth win (decode is KV-read-bound)."""
-    scale = 1.0 / (model.head_dim ** 0.5)
+    scale = attn_scale(model)
     b, lq, h, hd = q.shape
     kv = ck.shape[2]
     qg = q.reshape(b, lq, kv, h // kv, hd)
@@ -110,8 +174,22 @@ def project_qkv(model, blk, x, pos):
     """Pre-attention half of a block: LN1 + the training-path QKV
     projection with RoPE at ``pos`` ((L,) or (B, L)). The caller owns
     writing k/v into ITS cache layout before attending."""
-    y = layer_norm(x, blk["ln1"]["scale"], blk["ln1"]["bias"])
+    y = model.norm(x, blk["ln1"])
     return model.qkv_proj(blk, y, pos)
+
+
+def residual(model, x, o):
+    """``x + o``, times the model's ``residual_multiplier`` if it has
+    one."""
+    m = getattr(model, "residual_multiplier", 1.0)
+    return x + o if m == 1.0 else x + o * jnp.asarray(m, o.dtype)
+
+
+def mlp_half(model, blk, x):
+    """The MLP half of a block of either mixer: norm, MLP, residual."""
+    with jax.named_scope("mlp"):
+        y = model.norm(x, blk["ln2"])
+        return residual(model, x, mlp(model, blk, y))
 
 
 def block_finish(model, blk, x, o):
@@ -123,10 +201,27 @@ def block_finish(model, blk, x, o):
     with jax.named_scope("attn"):
         o = qdot(o.reshape(b, L, -1), blk["wo"], cd,
                  reshape=(-1, model.d_model)).astype(cd)
-        x = x + o
-    with jax.named_scope("mlp"):
-        y = layer_norm(x, blk["ln2"]["scale"], blk["ln2"]["bias"])
-        return x + mlp(model, blk, y)
+        x = residual(model, x, o)
+    return mlp_half(model, blk, x)
+
+
+def ssm_mix(model, blk, x, ssm, conv, n_valid=None):
+    """The state-space mixer's half of a block, the twin of
+    project_qkv + attention + output projection: norm, the Mamba-2 mixer
+    from the given state, residual. One token for each of S sequences
+    (``x`` (S, 1, dm), ``ssm`` / ``conv`` with a leading S) or, with
+    ``n_valid``, a run of ONE sequence (``x`` (1, C, dm)) of which the
+    first ``n_valid`` rows count. Returns (x, ssm, conv); the caller
+    owns reading and writing ITS state layout."""
+    from tpu_ddp.models.hybrid import ssm_chunk, ssm_step
+    h = model.norm(x, blk["ln1"])
+    if n_valid is None:
+        o, ssm, conv = ssm_step(model, blk, h[:, 0], ssm, conv)
+        o = o[:, None]
+    else:
+        o, ssm, conv = ssm_chunk(model, blk, h[0], ssm, conv, n_valid)
+        o = o[None]
+    return residual(model, x, o), ssm, conv
 
 
 def forward_cached(model, params, tokens, caches, start: int):

@@ -145,6 +145,24 @@ class TransformerLM:
     def is_gqa(self) -> bool:
         return self.kv_heads != self.num_heads
 
+    # What the decode core (models/decode.py) and the serving engine ask
+    # of any model: its norm, its embedding, what each layer keeps
+    # between steps, and the recurrent state of ``num_slots`` sequences
+    # (none here: every layer's mixer is attention over cached K/V).
+
+    @property
+    def mixers(self) -> tuple:
+        return ("attention",) * self.num_layers
+
+    def state_shapes(self, num_slots: int) -> dict:
+        return {}
+
+    def norm(self, x, p):
+        return layer_norm(x, p["scale"], p["bias"])
+
+    def embed(self, params, tokens):
+        return params["embed"][tokens].astype(self.compute_dtype)
+
     @property
     def remat_policy(self) -> str:
         """Effective remat mode, honoring the deprecated

@@ -165,14 +165,14 @@ def _kernel(layer_ref, len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
     lax.fori_loop(0, S, slot, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_impl(layer, q4, pool_k, pool_v, tables, lengths, *, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret", "scale"))
+def _paged_impl(layer, q4, pool_k, pool_v, tables, lengths, *, interpret,
+                scale):
     kvh, hd = q4.shape[1], q4.shape[3]
     bs = pool_k.shape[2]
     whole = lambda *_: (0, 0, 0, 0)  # noqa: E731
     return pl.pallas_call(
-        functools.partial(_kernel, scale=1.0 / (hd ** 0.5),
-                          bps=tables.shape[1]),
+        functools.partial(_kernel, scale=scale, bps=tables.shape[1]),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(1,),
@@ -195,6 +195,7 @@ def _paged_impl(layer, q4, pool_k, pool_v, tables, lengths, *, interpret):
 
 def paged_decode_attention(q, pool_k, pool_v, tables, lengths, *,
                            layer: int, kv_heads: int,
+                           scale: float | None = None,
                            interpret: bool | None = None):
     """Attention of one query token per slot over the paged pool.
 
@@ -204,7 +205,9 @@ def paged_decode_attention(q, pool_k, pool_v, tables, lengths, *,
     step are ONE traced, lowered and compiled kernel, which is what
     keeps the step's set-up time where it was); ``tables``: (S, BPS)
     int32 block ids; ``lengths``: (S,) int32, the positions each slot
-    attends (``0..length-1``). Returns (S, H, hd) in ``q``'s dtype. Query head ``h`` reads K/V head
+    attends (``0..length-1``); ``scale``: what the scores are multiplied
+    by (the model's ``attn_scale``; ``1/sqrt(hd)`` if not given).
+    Returns (S, H, hd) in ``q``'s dtype. Query head ``h`` reads K/V head
     ``h // (H / KV)``, the grouping of ``attend_cached``."""
     if interpret is None:
         from tpu_ddp.ops.pallas import interpret_mode
@@ -222,5 +225,7 @@ def paged_decode_attention(q, pool_k, pool_v, tables, lengths, *,
             f"query dtype {q.dtype} (see supports())")
     q4 = q.reshape(S, kv_heads, H // kv_heads, hd)
     out = _paged_impl(jnp.full((1,), layer, jnp.int32), q4, pool_k, pool_v,
-                      tables, lengths, interpret=bool(interpret))
+                      tables, lengths, interpret=bool(interpret),
+                      scale=1.0 / (hd ** 0.5) if scale is None
+                      else float(scale))
     return out.reshape(S, H, hd)

@@ -183,3 +183,52 @@ def moe_mlp(y, router_w, w1, w2, *, num_experts: int,
     y_out = jnp.einsum("tec,ecd->td", combine.astype(cd), out,
                        preferred_element_type=jnp.float32).astype(cd)
     return y_out.reshape(b, L, dm), aux
+
+
+def dropless_moe(x, router_w, w1, w2, *, top_k: int, held: tuple):
+    """Dropless top-k routed gated MLP, for a chip that holds a share of
+    the experts: (T, dm) -> (T, dm) float32.
+
+    ``router_w`` (dm, E) routes over ALL ``E`` experts; ``held = (lo,
+    hi)`` says which of them ``w1`` (hi-lo, dm, 2 ff) and ``w2`` (hi-lo,
+    ff, dm) are. Each token takes its ``top_k`` largest router logits,
+    gates ``g`` = softmax over those chosen logits in float32, and the
+    result is the sum of ``g_e * (SiLU(u) * v) @ w2[e]``, ``[u, v] = x @
+    w1[e]``, over the chosen experts THAT ARE HELD: what the absent
+    experts would have added is left out (the other chips of the layer
+    compute it; on one chip there is no exchange). Every assignment is
+    computed, none dropped, and every shape is static whatever the
+    routing: the ``T * top_k`` assignments are sorted by expert (those of
+    absent experts last, in no group) and the two products are grouped
+    ones (``lax.ragged_dot``) over the sorted rows, not a (T, E, C)
+    dispatch tensor. The capacity form above stays for the models that
+    train with it."""
+    T = x.shape[0]
+    lo, hi = held
+    n = hi - lo
+    cd = x.dtype
+    with jax.named_scope("route"):
+        logits = jnp.dot(x, router_w.astype(cd),
+                         preferred_element_type=jnp.float32)    # (T, E)
+        vals, idx = lax.top_k(logits, top_k)
+        gates = jax.nn.softmax(vals, axis=-1)                   # (T, k)
+        local = idx - lo
+        mine = (local >= 0) & (local < n)
+        group = jnp.where(mine, local, n).reshape(-1)           # (T*k,)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.zeros((n + 1,), jnp.int32).at[group].add(1)[:n]
+        weight = jnp.where(mine, gates, 0.0).reshape(-1)[order]
+        # where each assignment went, to bring the results back
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+    with jax.named_scope("experts"):
+        rows = x[order // top_k]                                # (T*k, dm)
+        uv = lax.ragged_dot(rows, w1.astype(cd), sizes,
+                            preferred_element_type=jnp.float32)
+        u, v = jnp.split(uv, 2, axis=-1)
+        act = (jax.nn.silu(u) * v).astype(cd)
+        out = lax.ragged_dot(act, w2.astype(cd), sizes,
+                             preferred_element_type=jnp.float32)
+        # rows past the last group (absent experts) hold nothing defined
+        out = jnp.where((weight > 0)[:, None], out * weight[:, None], 0.0)
+        return out[back].reshape(T, top_k, -1).sum(axis=1)

@@ -42,6 +42,13 @@ a query never reads an unwritten slot of its own sequence; everything
 beyond a query's position gets an exact zero weight (the kernel's
 length mask, decode.attend_cached's causal mask).
 
+A model may keep, in some layers, a fixed-size recurrent state per
+sequence and no K/V (``model.mixers``): the paged pool then holds pages
+for the attention layers only, and a ``StatePool`` indexed by slot lives
+beside it, donated through both step programs and updated in place like
+the pool (docs/DESIGN.md §31 has the three rules that take the place of
+the null block there).
+
 Sampling is per-request and stateless (decode.sample_token): keyed by
 (request seed, absolute position), so a request replayed after
 cancellation or across engines reproduces its tokens exactly.
@@ -65,18 +72,24 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from tpu_ddp.models.decode import (
     attend_cached,
+    attn_scale,
     block_finish,
     check_decodable,
+    check_state_servable,
     dense_params_from_checkpoint,
+    mlp_half,
     project_qkv,
     sample_token,
+    ssm_mix,
 )
 from tpu_ddp.ops.pallas import paged_attention
 from tpu_ddp.serve.kv_pool import (
     PagedKVPool,
+    StatePool,
     gather_view,
     pin_committed,
     rows,
@@ -182,6 +195,50 @@ def paged_kv(model, pool_k, pool_v, li: int, bidx, off, k, v, tables):
     return pool_k, pool_v, ck, cv
 
 
+def state_step(model, blk, x, state, si: int, active):
+    """A state-space layer of the decode step: state layer ``si`` of the
+    pool for the whole bank, advanced by one token where ``active`` (S,)
+    and left as it was elsewhere (rule 1 of three, module docstring of
+    kv_pool.StatePool: a slot that is idle, or between two prefill
+    chunks, rides along as a row and must not be touched). Scope ``ssm``,
+    the pool's reads and writes under ``ssm/state``."""
+    with jax.named_scope("ssm"):
+        with jax.named_scope("state"):
+            old = {k: a[si] for k, a in state.items()}
+        x, ssm, conv = ssm_mix(model, blk, x, old["ssm"], old["conv"])
+        with jax.named_scope("state"):
+            new = {"ssm": ssm, "conv": conv}
+            state = {
+                k: a.at[si].set(jnp.where(
+                    active.reshape((-1,) + (1,) * (old[k].ndim - 1)),
+                    new[k].astype(a.dtype), old[k]))
+                for k, a in state.items()}
+    return mlp_half(model, blk, x), state
+
+
+def state_chunk(model, blk, x, state, si: int, slot, fresh, n_valid):
+    """A state-space layer of a prefill chunk: slot ``slot``'s state in
+    state layer ``si``, from zero where ``fresh`` (rule 2: a request's
+    first chunk, whatever the slot's last tenant left there), advanced
+    by the chunk's first ``n_valid`` rows and not by its padding (rule
+    3, models/hybrid.py ``ssm_chunk``)."""
+    with jax.named_scope("ssm"):
+        with jax.named_scope("state"):
+            at = {k: (si, slot) + (0,) * (a.ndim - 2)
+                  for k, a in state.items()}
+            old = {k: jnp.where(fresh, 0, lax.dynamic_slice(
+                a, at[k], (1, 1) + a.shape[2:])[0, 0]).astype(a.dtype)
+                for k, a in state.items()}
+        x, ssm, conv = ssm_mix(model, blk, x, old["ssm"], old["conv"],
+                               n_valid)
+        with jax.named_scope("state"):
+            new = {"ssm": ssm, "conv": conv}
+            state = {k: lax.dynamic_update_slice(
+                a, new[k].astype(a.dtype)[None, None], at[k])
+                for k, a in state.items()}
+    return mlp_half(model, blk, x), state
+
+
 # What one ``attend_cached`` call of a prefill chunk may hold as float32
 # scores, (heads, queries, keys): half of the 128 MiB of on-chip memory
 # beside a v5e's HBM. Whether the compiler keeps the scores there or in
@@ -214,7 +271,7 @@ def attend_chunk(model, q, ck, cv, p):
 
 
 def decode_bank(model, block_size: int, params, pool_k, pool_v, tables,
-                lengths, last_tokens, temps, seeds):
+                lengths, last_tokens, temps, seeds, state=None):
     """The traced body of the whole-bank decode step — one token for
     every live slot. Module-level (not a closure) so the disagg fused
     adopt+decode program (tpu_ddp/fleet/disagg.py) can prepend its
@@ -227,17 +284,28 @@ def decode_bank(model, block_size: int, params, pool_k, pool_v, tables,
     dtypes takes this model, block size and pool — a step's K/V bytes
     then follow the live context — and through the gathered
     ``max_seq_len`` view otherwise. The choice is made here, as the
-    program is traced; both bodies attend positions ``0..lengths``."""
+    program is traced; both bodies attend positions ``0..lengths``.
+
+    ``state``: the state pool's arrays, for a model with layers that keep
+    recurrent state; it is then returned last, advanced for the slots
+    that have a row. Those are the slots whose table row is not all null:
+    an idle slot's, and one's between two prefill chunks, is zeros."""
     cd = model.compute_dtype
     in_place = paged_attention.supports(model.head_dim, block_size,
                                         pool_k.dtype, cd)
     with jax.named_scope("embed"):
-        x = params["embed"][last_tokens[:, None]].astype(cd)  # (S, 1, dm)
+        x = model.embed(params, last_tokens[:, None])     # (S, 1, dm)
     pos = lengths[:, None]                                # (S, 1)
     bidx = jnp.take_along_axis(
         tables, (lengths // block_size)[:, None], axis=1)[:, 0]
     off = lengths % block_size
-    for li, blk in enumerate(params["blocks"]):
+    active = tables[:, 0] != PagedKVPool.NULL_BLOCK
+    li = si = 0     # the layer's place among those of its kind
+    for blk, mixer in zip(params["blocks"], model.mixers):
+        if mixer != "attention":
+            x, state = state_step(model, blk, x, state, si, active)
+            si += 1
+            continue
         with jax.named_scope("attn"):
             q, k, v = project_qkv(model, blk, x, pos)
             if in_place:
@@ -245,13 +313,15 @@ def decode_bank(model, block_size: int, params, pool_k, pool_v, tables,
                                           k[:, 0], v[:, 0])
                 o = paged_attention.paged_decode_attention(
                     q[:, 0], pool_k, pool_v, tables, lengths + 1,
-                    layer=li, kv_heads=model.kv_heads)[:, None]
+                    layer=li, kv_heads=model.kv_heads,
+                    scale=attn_scale(model))[:, None]
             else:
                 pool_k, pool_v, ck, cv = paged_kv(
                     model, pool_k, pool_v, li, bidx, off, k[:, 0],
                     v[:, 0], tables)
                 o = attend_cached(model, q, ck, cv, pos)
         x = block_finish(model, blk, x, o)
+        li += 1
     logits = model.head_apply(params, x)[:, 0]            # (S, V)
     with jax.named_scope("sample"):
         toks, lps = jax.vmap(
@@ -265,7 +335,9 @@ def decode_bank(model, block_size: int, params, pool_k, pool_v, tables,
         # isolated Inf the sampled position might miss.
         bad = ~(jnp.all(jnp.isfinite(logits), axis=-1)
                 & jnp.isfinite(lps))
-    return pool_k, pool_v, toks, lps, bad
+    if state is None:
+        return pool_k, pool_v, toks, lps, bad
+    return pool_k, pool_v, toks, lps, bad, state
 
 
 # Both step builders are memoized on (model, block_size, blocks_per_seq)
@@ -278,6 +350,17 @@ def _build_decode_step(model, block_size: int, blocks_per_seq: int):
     (S, BPS) int32 block tables (zeros = null for idle slots),
     ``lengths`` (S,) cache positions written so far, ``last_tokens``
     (S,) the pending token each slot feeds at position ``lengths``."""
+
+    if model.state_shapes(1):
+        # the state pool's arrays ride beside the K/V pool's, donated
+        @program(SERVE_DECODE)
+        def step(params, pool_k, pool_v, state, tables, lengths,
+                 last_tokens, temps, seeds):
+            return decode_bank(model, block_size, params, pool_k, pool_v,
+                               tables, lengths, last_tokens, temps, seeds,
+                               state=state)
+
+        return jax.jit(step, donate_argnums=(1, 2, 3))
 
     @program(SERVE_DECODE)
     def step(params, pool_k, pool_v, tables, lengths, last_tokens,
@@ -296,12 +379,15 @@ def _build_prefill_step(model, block_size: int, blocks_per_seq: int):
     null block and never influence a valid query (causal mask). The
     sampled (token, logprob) pair is meaningful only on the final
     chunk (the one containing position ``prompt_len - 1``); earlier
-    chunks compute and discard it so every chunk is ONE program."""
+    chunks compute and discard it so every chunk is ONE program.
 
-    @program(SERVE_PREFILL)
-    def step(params, pool_k, pool_v, table, tokens, start, prompt_len,
-             temp, seed):
-        cd = model.compute_dtype
+    For a model with layers that keep recurrent state the program also
+    takes the state pool's arrays (donated, returned last) and the
+    ``slot`` the chunk belongs to: the state has no block table to find
+    it by."""
+
+    def chunk(params, pool_k, pool_v, table, tokens, start, prompt_len,
+              temp, seed, state=None, slot=None):
         C = tokens.shape[1]
         p = start + jnp.arange(C)                             # (C,)
         valid = p < prompt_len
@@ -309,8 +395,15 @@ def _build_prefill_step(model, block_size: int, blocks_per_seq: int):
         blk_idx = jnp.where(valid, table[safe], PagedKVPool.NULL_BLOCK)
         off = p % block_size
         with jax.named_scope("embed"):
-            x = params["embed"][tokens].astype(cd)            # (1, C, dm)
-        for li, blkp in enumerate(params["blocks"]):
+            x = model.embed(params, tokens)                   # (1, C, dm)
+        li = si = 0
+        for blkp, mixer in zip(params["blocks"], model.mixers):
+            if mixer != "attention":
+                x, state = state_chunk(
+                    model, blkp, x, state, si, slot, start == 0,
+                    jnp.clip(prompt_len - start, 0, C))
+                si += 1
+                continue
             with jax.named_scope("attn"):
                 q, k, v = project_qkv(model, blkp, x, p)
                 pool_k, pool_v, ck, cv = paged_kv(
@@ -318,12 +411,30 @@ def _build_prefill_step(model, block_size: int, blocks_per_seq: int):
                     table[None])
                 o = attend_chunk(model, q, ck, cv, p)
             x = block_finish(model, blkp, x, o)
+            li += 1
         logits = model.head_apply(params, x)[0]               # (C, V)
         last = jnp.clip(prompt_len - 1 - start, 0, C - 1)
         with jax.named_scope("sample"):
             tok, lp = sample_token(model, logits[last], temp, seed,
                                    prompt_len)
-        return pool_k, pool_v, tok, lp
+        if state is None:
+            return pool_k, pool_v, tok, lp
+        return pool_k, pool_v, tok, lp, state
+
+    if model.state_shapes(1):
+        @program(SERVE_PREFILL)
+        def step(params, pool_k, pool_v, state, table, tokens, start,
+                 prompt_len, temp, seed, slot):
+            return chunk(params, pool_k, pool_v, table, tokens, start,
+                         prompt_len, temp, seed, state, slot)
+
+        return jax.jit(step, donate_argnums=(1, 2, 3))
+
+    @program(SERVE_PREFILL)
+    def step(params, pool_k, pool_v, table, tokens, start, prompt_len,
+             temp, seed):
+        return chunk(params, pool_k, pool_v, table, tokens, start,
+                     prompt_len, temp, seed)
 
     return jax.jit(step, donate_argnums=(1, 2))
 
@@ -362,7 +473,9 @@ class _Unread:
 
 
 class ServeEngine:
-    """Continuous-batching serving over one dense TransformerLM.
+    """Continuous-batching serving over one dense single-device model:
+    whatever offers what the decode core asks (models/decode.py), K/V
+    in every layer or recurrent state in some.
 
     Knob defaults come from ``TrainConfig`` (``TPU_DDP_SERVE_SLOTS``,
     ``TPU_DDP_SERVE_BLOCK``, ``TPU_DDP_SERVE_PREFILL_CHUNK``,
@@ -429,6 +542,9 @@ class ServeEngine:
                                 cold_dtype=self.kv_cold_dtype,
                                 hbm_blocks=hbm_blocks,
                                 cold_blocks=cold_blocks)
+        # The recurrent state of the layers that keep one, by slot
+        # (no array at all for a model whose layers all keep K/V).
+        self.state = StatePool(model, self.num_slots)
         # Tensor-parallel serving: params arrive pre-sharded over
         # ``mesh``'s model axis (parallel/tensor_parallel.py
         # shard_decode_params); the pool and every host-built input
@@ -471,6 +587,9 @@ class ServeEngine:
         self.tenant_counts: dict[str, dict[str, int]] = {}
         self.metrics = metrics if metrics is not None \
             else MetricsLogger(None)
+        self.metrics.observe("serve_kv_pool_bytes",
+                             self.pool.k.nbytes + self.pool.v.nbytes)
+        self.metrics.observe("serve_state_pool_bytes", self.state.nbytes)
         self._decode = _build_decode_step(model, self.block_size,
                                           self.blocks_per_seq)
         self._prefill = _build_prefill_step(model, self.block_size,
@@ -538,6 +657,11 @@ class ServeEngine:
             raise ValueError(
                 f"decode_quant={self.decode_quant!r}: expected 'none'"
                 " or 'int8' (TPU_DDP_DECODE_QUANT)")
+        check_state_servable(
+            model, prefix_cache=prefix_cache, kv_tiers=self.kv_tiers > 1,
+            spec_k=self.spec_k > 0, cp_prefill=self.cp_prefill != "off",
+            decode_quant=self.decode_quant != "none",
+            mesh=mesh is not None)
         if model.moe_experts and (self.decode_quant == "int8"
                                   or kind == "quant"):
             # The routed MoE layer contracts stacked expert weights in
@@ -636,6 +760,7 @@ class ServeEngine:
         sds = jax.ShapeDtypeStruct
         return self._decode.lower(
             self._decode_params, self.pool.k, self.pool.v,
+            *self._state_args(),
             sds((S, BPS), jnp.int32), sds((S,), jnp.int32),
             sds((S,), jnp.int32), sds((S,), jnp.float32),
             sds((S,), jnp.int32))
@@ -646,10 +771,18 @@ class ServeEngine:
         sds = jax.ShapeDtypeStruct
         return self._prefill.lower(
             self._decode_params, self.pool.k, self.pool.v,
+            *self._state_args(),
             sds((self.blocks_per_seq,), jnp.int32),
             sds((1, self.prefill_chunk), jnp.int32),
             sds((), jnp.int32), sds((), jnp.int32),
-            sds((), jnp.float32), sds((), jnp.int32))
+            sds((), jnp.float32), sds((), jnp.int32),
+            *((sds((), jnp.int32),) if self.state.arrays else ()))
+
+    def _state_args(self) -> tuple:
+        """The state pool's arrays as the step programs take them:
+        one more donated argument after the K/V pool's, or none for a
+        model that keeps no recurrent state."""
+        return (self.state.arrays,) if self.state.arrays else ()
 
     def lower_spec_step(self):
         """``jit.lower`` the fused speculative step (same audit
@@ -940,11 +1073,16 @@ class ServeEngine:
             if self.spec_k == 0:
                 self.metrics.inc("serve_decode_ahead" if ahead
                                  else "serve_decode_at_rest")
+            context = sum(self.sched.slots[i].length
+                          + self.sched.slots[i].ahead for i in dslots)
+            # every step's, where the span's counts are a burst's only
+            self.metrics.observe("serve_decode_rows", len(dslots))
+            self.metrics.observe("serve_decode_context_tokens", context)
             with span("tpu_ddp.serve.decode", slots=len(dslots),
-                      context_tokens=sum(
-                          self.sched.slots[i].length
-                          + self.sched.slots[i].ahead for i in dslots),
-                      ahead=ahead):
+                      context_tokens=context,
+                      ahead=ahead,
+                      state_slots=len(dslots) if self.state.arrays
+                      else 0):
                 if self.spec_k > 0:
                     self._run_spec_step(dslots)
                 else:
@@ -1065,7 +1203,9 @@ class ServeEngine:
         end = min(start + self.prefill_chunk, int(s.request.prompt.size))
         with span("tpu_ddp.serve.prefill", rid=s.request.rid,
                   tokens=end - start, start=start,
-                  final=int(end >= s.request.prompt.size)):
+                  final=int(end >= s.request.prompt.size),
+                  state_reset=int(bool(self.state.arrays)
+                                  and start == 0)):
             self._prefill_chunk(pi, s, start, mine)
 
     def _prefill_chunk(self, pi: int, s, start: int,
@@ -1096,11 +1236,16 @@ class ServeEngine:
                 jnp.int32(start), jnp.int32(req.prompt.size),
                 jnp.float32(req.temperature), jnp.int32(req.seed))
         else:
-            k, v, tok, lp = self._prefill(
-                self._decode_params, self.pool.k, self.pool.v,
+            # a model with recurrent state: its arrays go in after the
+            # pool's and come back last, and the program is told the slot
+            state = self._state_args()
+            k, v, tok, lp, *state = self._prefill(
+                self._decode_params, self.pool.k, self.pool.v, *state,
                 jnp.asarray(self._table_for(s)), jnp.asarray(chunk),
                 jnp.int32(start), jnp.int32(req.prompt.size),
-                jnp.float32(req.temperature), jnp.int32(req.seed))
+                jnp.float32(req.temperature), jnp.int32(req.seed),
+                *((jnp.int32(pi),) if state else ()))
+            self.state.commit(*state)
         self.pool.commit(k, v)
         s.prefill_done = min(start + C, int(req.prompt.size))
         s.length = s.prefill_done
@@ -1212,11 +1357,13 @@ class ServeEngine:
                     jnp.asarray(lengths), d_last,
                     jnp.asarray(temps), jnp.asarray(seeds))
             else:
-                k, v, toks, lps, bad = self._decode(
+                k, v, toks, lps, bad, *state = self._decode(
                     self._decode_params, self.pool.k, self.pool.v,
+                    *self._state_args(),
                     jnp.asarray(tables), jnp.asarray(lengths),
                     d_last, jnp.asarray(temps),
                     jnp.asarray(seeds))
+                self.state.commit(*state)
             self.pool.commit(k, v)
             mine.rows = dict(zip(dslots, slots))
             mine.out = (toks, lps, bad)
@@ -1394,6 +1541,8 @@ class ServeEngine:
         req = s.request
         self.pool.scrub([b for b in s.blocks
                          if self.pool.refcount(b) == 1])
+        if self.state.arrays:
+            self.state.scrub(idx)
         self.sched.retire(idx)
         req.quarantined = True
         req.done = True

@@ -206,6 +206,9 @@ class PagedKVPool:
         self.tiers = tiers
         self.cold_dtype_name = cold_dtype
         self.dtype = resolve_act_dtype(cache_dtype, model.compute_dtype)
+        # Pages for the layers that keep K/V only: a layer whose mixer
+        # keeps recurrent state has none (its state is in the StatePool).
+        self.layers = sum(m == "attention" for m in model.mixers)
         page = (block_size, model.kv_heads * model.head_dim)
         # Hot buffers: at tiers == 1 the logical id IS the hot slot
         # (identity map, num_blocks slots) — the round-12 layout,
@@ -222,17 +225,17 @@ class PagedKVPool:
         if tiers > 1 and self.cold_blocks < 2:
             raise ValueError("cold_blocks must be >= 2 (slot 0 is the "
                              f"cold null page), got {self.cold_blocks}")
-        shape = (model.num_layers, self.hbm_blocks) + page
+        shape = (self.layers, self.hbm_blocks) + page
         self.k = pin_committed(jnp.zeros(shape, self.dtype))
         self.v = pin_committed(jnp.zeros(shape, self.dtype))
         self.cold_k = self.cold_v = None
         self.cold_sk = self.cold_sv = None
         if tiers > 1:
-            cshape = (model.num_layers, self.cold_blocks) + page
+            cshape = (self.layers, self.cold_blocks) + page
             cdt = COLD_DTYPES[cold_dtype]
             self.cold_k = pin_committed(jnp.zeros(cshape, cdt))
             self.cold_v = pin_committed(jnp.zeros(cshape, cdt))
-            sshape = (model.num_layers, self.cold_blocks, block_size)
+            sshape = (self.layers, self.cold_blocks, block_size)
             self.cold_sk = pin_committed(jnp.zeros(sshape, jnp.float32))
             self.cold_sv = pin_committed(jnp.zeros(sshape, jnp.float32))
         # LIFO free list: recently-freed (still-hot) pages are reused
@@ -735,3 +738,43 @@ class PagedKVPool:
         """Store the jitted step's updated (hot) buffers (the old ones
         were donated into the step)."""
         self.k, self.v = k, v
+
+
+class StatePool:
+    """The recurrent state of every slot, beside the paged K/V: for a
+    model whose layers keep a fixed-size state per sequence and not a
+    list of positions (``model.state_shapes``), one array per kind of
+    state, ``(state layers, num_slots, ...)``, indexed by SLOT. There is
+    no allocator: a slot's state exists for as long as the slot does,
+    and there is no null slot to send a masked write to, so the step
+    programs keep three rules themselves (serve/engine.py): a decode step
+    leaves untouched the state of every slot it has no row for, a
+    request's first chunk starts from zero whatever the slot's last
+    tenant left, and padding does not advance it.
+
+    Like the K/V pool the arrays are FUNCTIONAL state: ``pool.arrays``
+    goes into the jitted steps (donated, updated in place) and comes back
+    through :meth:`commit`. For a model without such layers ``arrays`` is
+    an empty dict, which adds no argument to a program."""
+
+    def __init__(self, model, num_slots: int):
+        self.arrays = pin_committed({
+            name: jnp.zeros(s.shape, s.dtype)
+            for name, s in model.state_shapes(num_slots).items()})
+
+    @property
+    def nbytes(self) -> int:
+        return sum(a.nbytes for a in self.arrays.values())
+
+    def commit(self, arrays=None) -> None:
+        """Store what a jitted step returned for the donated arrays
+        (nothing, from a program of a model without such state)."""
+        if arrays is not None:
+            self.arrays = arrays
+
+    def scrub(self, slot: int) -> None:
+        """Zero slot ``slot``'s state in every layer (quarantine: a
+        non-finite state is not left where a reader could find it, though
+        the next tenant's first chunk would not read it)."""
+        self.arrays = {name: a.at[:, slot].set(0)
+                       for name, a in self.arrays.items()}

@@ -57,16 +57,20 @@ SPANS = {
     "tpu_ddp.serve.prefill": (
         "serving", "one prefill chunk: build, upload, dispatch, "
         "pool.commit; the final chunk's first token stays on the device "
-        "and is read with the step's decode rows",
-        ("rid", "tokens", "start", "final")),
+        "and is read with the step's decode rows. state_reset: 1 on a "
+        "request's first chunk of a model that keeps recurrent state "
+        "(the slot's state starts from zero), else 0",
+        ("rid", "tokens", "start", "final", "state_reset")),
     "tpu_ddp.serve.decode": (
         "serving", "one whole-bank decode step (plain or fused "
         "speculative): its dispatch and, in the plain engine, the "
         "harvest of the step BEFORE it. ahead: 1 where that step's "
         "decode rows were still unread at the dispatch, 0 where the "
         "engine was at rest (at spec_k == 0 also the counters "
-        "serve_decode_ahead / serve_decode_at_rest)",
-        ("slots", "context_tokens", "ahead")),
+        "serve_decode_ahead / serve_decode_at_rest). state_slots: the "
+        "slots whose recurrent state the step advances (its rows, for a "
+        "model that keeps such state; else 0)",
+        ("slots", "context_tokens", "ahead", "state_slots")),
     "tpu_ddp.serve.decode.tables": (
         "serving", "ensure_blocks, tier residency, the numpy tables "
         "and vectors", ()),
@@ -174,13 +178,49 @@ SCOPES = {
                  "shapes (they then have no such scope). serve_spec and "
                  "the tiered and context-parallel programs gather "
                  "without the scope",
-    "mlp": "a block's MLP half: LayerNorm, MLP, residual",
+    "mlp": "a block's MLP half: norm, MLP, residual",
+    "ssm": "a state-space block's mixer half: norm, projections, "
+           "convolution, the recurrence (one token a slot in "
+           "serve_decode, the chunked scan in serve_prefill), gated "
+           "norm, output projection, residual",
+    "state": "inside ssm, serving: the reads and writes of the state "
+             "pool (the path ssm/state)",
+    "moe": "inside mlp: the dropless expert layer over the experts "
+           "held",
+    "route": "inside moe: router, top-k, gates, and the sort of the "
+             "assignments by expert (the path moe/route)",
+    "experts": "inside moe: the gather of the sorted rows, the two "
+               "grouped products and the weighted sum back to tokens "
+               "(the path moe/experts). XLA:TPU runs each grouped "
+               "product (lax.ragged_dot) as a custom call of its own "
+               "whose operations are named ragged-dot-none and carry NO "
+               "scope path: a reader counts them by that name",
+    "shared_mlp": "inside mlp: the shared gated MLP, every token",
     "head": "final LayerNorm and output head",
     "sample": "serving: token sampling and the non-finite check",
     "loss": "trainers: cross-entropy on the logits",
     "grad_sync": "trainers: gradient mean over the data axes",
     "clip": "trainers: global-norm gradient clipping",
     "optimizer": "trainers: the optimizer update",
+}
+
+
+# Gauges the serving engine keeps in its ``MetricsLogger`` whether or not
+# a trace is being taken (``metrics.gauges[name]``: count, total, max,
+# last). Not in a trace: a benchmark's runner reads them around its
+# window. The two per-step ones repeat counts of the ``serve.decode``
+# span for EVERY step, where the spans are a burst's only (below).
+GAUGES = {
+    "serve_kv_pool_bytes": "set once: what the paged K/V pool holds on "
+                           "the device",
+    "serve_state_pool_bytes": "set once: what the state pool holds (0 "
+                              "for a model without recurrent state)",
+    "serve_decode_rows": "every decode step: its rows (the span's "
+                         "slots; for a model with recurrent state also "
+                         "its state_slots)",
+    "serve_decode_context_tokens": "every decode step: the K/V "
+                                   "positions it reads (the span's "
+                                   "context_tokens)",
 }
 
 
