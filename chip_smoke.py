@@ -7,12 +7,12 @@ models the repo benchmarks (VGG-11 at the reference's global batch 256;
 TransformerLM-large, 12 layers, d_model 2048, ~740M parameters), with
 random weights made from a seed, and checks the results by the repo's
 own means: the step guard's finite-loss check, the retrace sentinel, the
-``jax.numpy`` references of the five Pallas kernels, ``generate()``.
+``jax.numpy`` references of the six Pallas kernels, ``generate()``.
 
     P0  device: platform pinned to tpu, device_kind in both peak tables,
         compile-cache directory, native libraries rebuilt from source
     P1  the ladder: run_part("part1") and run_part("part3") on VGG-11
-    P2  the five Pallas kernels, compiled, against their references
+    P2  the six Pallas kernels, compiled, against their references
     P3  LMTrainer on TransformerLM-large with the flash kernel, 5 steps
     P4  ServeEngine on TransformerLM-large, bf16 then int8 decode, the
         paged decode kernel in the compiled step (and the gather body
@@ -82,6 +82,8 @@ INT8_SHAPES = ((2048, 6144), (2048, 8192), (8192, 2048), (2048, 32000))
 # table, one a single token, the rest on and around page boundaries.
 PAGED_SHAPE = (2, 8 * 64 + 1, 16, 2, 128, 8, 24, 64)
 PAGED_LENGTHS = (1024, 1, 16, 17, 129, 511, 700, 33)
+# state layers, slots, heads, head_dim, N, groups: two 2 MB tiles a slot
+STATE_SHAPE = (2, 4, 128, 64, 128, 1)
 VGG_LEAF = (3, 3, 256, 512)          # a VGG-11 conv kernel
 VGG_ACTIVATION = (256, 32, 32, 64)   # first conv output at batch 256
 
@@ -300,7 +302,9 @@ def p2_kernels() -> dict:
     from tpu_ddp.models.decode import attend_cached
     from tpu_ddp.ops.pallas import (batch_norm_relu, flash_attention,
                                     int8_matmul)
+    from tpu_ddp.models.hybrid import advance_state
     from tpu_ddp.ops.pallas.paged_attention import paged_decode_attention
+    from tpu_ddp.ops.pallas.ssm_state_step import ssm_state_step
     from tpu_ddp.ops.quant import quantize_weight
     from tpu_ddp.parallel.ring_attention import full_attention
 
@@ -374,6 +378,32 @@ def p2_kernels() -> dict:
               lambda q, pk, pv, t, n: paged_decode_attention(
                   q, pk, pv, t, n, layer=1, kv_heads=kvh),
               gathered, (q, *pools, tables, lengths), TOL_BF16)
+
+        layers, slots, heads, hd, n, groups = STATE_SHAPE
+        pool = jax.random.normal(next(keys), (layers, slots, heads, hd, n))
+        decay = jax.random.uniform(next(keys), (slots, heads))
+        dtx = jax.random.normal(next(keys), (slots, heads, hd))
+        b, c = (jax.random.normal(next(keys), (slots, groups, n))
+                for _ in range(2))
+        active = jnp.arange(slots) != 2       # slot 2 rides along
+
+        def plain(pool, *args):
+            y, new = advance_state(pool[1], *args)
+            return (jnp.where(active[:, None, None], y, 0.0),
+                    pool.at[1].set(jnp.where(
+                        active[:, None, None, None], new, pool[1])))
+
+        def in_pool(pool, *args):
+            return ssm_state_step(pool, *args, layer=1, active=active)
+
+        # The state bit for bit (the lane broadcast through the MXU is
+        # exact, ops/pallas/ssm_state_step.py); y by the order of a sum
+        # over N.
+        check("ssm_state_step state", lambda *a: in_pool(*a)[1],
+              lambda *a: plain(*a)[1], (pool, decay, dtx, b, c), 0.0)
+        check("ssm_state_step y", lambda *a: in_pool(*a)[0],
+              lambda *a: plain(*a)[0], (pool, decay, dtx, b, c),
+              TOL_F32_ELEMENTWISE)
 
         tree = {"w": jax.random.normal(next(keys), VGG_LEAF, jnp.float32),
                 "b": jax.random.normal(next(keys), VGG_LEAF[-1:],

@@ -25,6 +25,7 @@ if str(ROOT) not in sys.path:
 from benchmark.reference import granite_hybrid as reference  # noqa: E402
 from tpu_ddp.models import decode, hybrid  # noqa: E402
 from tpu_ddp.models.hybrid import HybridLM  # noqa: E402
+from tpu_ddp.ops.pallas import ssm_state_step  # noqa: E402
 from tpu_ddp.parallel.moe import dropless_moe  # noqa: E402
 from tpu_ddp.serve import ServeEngine  # noqa: E402
 from tpu_ddp.serve import engine as engine_mod  # noqa: E402
@@ -67,6 +68,18 @@ def params(model):
     return model.init(jax.random.key(7))
 
 
+@pytest.fixture(scope="module", params=[16, 128], ids=["plain", "kernel"])
+def served(request, model, params):
+    """(model, params) by how the decode step advances the state: N 16,
+    which the kernel's predicate refuses (the plain body), and N 128,
+    which it takes (ops/pallas/ssm_state_step.py, interpreted here)."""
+    if request.param == model.ssm_state:
+        return model, params
+    m = _model(ssm_state=request.param)
+    assert ssm_state_step.supports(m.ssm_head_dim, m.ssm_state, jnp.float32)
+    return m, m.init(jax.random.key(7))
+
+
 def _prompt(n, seed):
     return np.random.default_rng(seed).integers(0, 256, size=n)
 
@@ -96,13 +109,14 @@ def _serve(model, params, cases=CASES, **kw):
     return eng, prompts, reqs
 
 
-def test_engine_logprobs_match_the_reference(model, params):
+def test_engine_logprobs_match_the_reference(served):
+    model, params = served
     eng, prompts, reqs = _serve(model, params)
     for p, r, (_, new) in zip(prompts, reqs, CASES):
         assert r.done and len(r.tokens) == new
         assert _worst(model, params, p, r) < TOL
     assert eng.pool.k.shape[0] == 1         # pages for one layer of four
-    assert eng.state.arrays["ssm"].shape == (3, 3, 4, 8, 16)
+    assert eng.state.arrays["ssm"].shape == (3, 3, 4, 8, model.ssm_state)
     assert eng.state.arrays["ssm"].dtype == jnp.float32
     assert eng.sched.accounting_ok()
     assert eng.pool.free_count == eng.pool.total_usable
@@ -253,8 +267,9 @@ RULES = {
 
 @pytest.mark.parametrize("rule", sorted(RULES))
 @pytest.mark.parametrize("broken", [False, True])
-def test_each_state_rule_holds_and_is_needed(model, params, monkeypatch,
-                                             rule, broken):
+def test_each_state_rule_holds_and_is_needed(served, monkeypatch, rule,
+                                             broken):
+    model, params = served
     breaker, cases, look = RULES[rule]
     slots = 1 if rule == "zero_at_the_first_chunk" else 2
     if broken:
@@ -355,6 +370,13 @@ def test_a_model_without_state_is_refused_nothing():
 
 # ---- the lowered programs ------------------------------------------------------
 
+# The state kernel in a lowered program: its ``pallas_call`` under its
+# ``name=``, inside the jitted wrapper that the program calls from
+# ``ssm/state`` (the compiled program spells the path in one piece:
+# tests/test_serve_prefill_tpu_compile.py).
+KERNEL_CALL = '"ssm_state_step/pallas_call"'
+
+
 def test_programs_slice_no_layer_out_of_a_pool_and_donate_the_state():
     """37 blocks, unlike every other dimension: a value shaped (37, ...)
     or (1, 37, ...) can only be one layer sliced out of the K/V pool
@@ -378,3 +400,24 @@ def test_programs_slice_no_layer_out_of_a_pool_and_donate_the_state():
     for scope in ("ssm/state", "mlp/moe/route", "mlp/moe/experts",
                   "mlp/shared_mlp", "attn/kv_write"):
         assert f"jit(serve_decode)/{scope}/" in text, scope
+    assert KERNEL_CALL not in text      # N 16: the plain body
+
+
+def test_decode_names_the_state_kernel_under_ssm_state_and_prefill_does_not():
+    m = _model(ssm_state=128,
+               layer_types=("mamba", "attention", "mamba", "attention"))
+    eng = ServeEngine(m, m.init(jax.random.key(0)), num_blocks=37, **GEOM)
+    decode = eng.lower_decode_step().as_text(debug_info=True)
+    assert KERNEL_CALL in decode
+    assert "jit(serve_decode)/ssm/state/jit(_impl)" in decode
+    # the kernel is called once a state layer, on the whole pool; no
+    # layer of the recurrence's state is sliced out or scattered back
+    main = decode[decode.index("func.func public @main"):]
+    main = main[:main.index("\n  }")]
+    calls = re.findall(r"call @_impl[^\n]*", main)
+    assert len(calls) == 2 and all("tensor<2x3x4x8x128xf32>" in c
+                                   for c in calls)
+    assert not re.findall(r"tensor<(?:1x)?3x4x8x128xf32>", main)
+    prefill = eng.lower_prefill_step().as_text(debug_info=True)
+    assert "jit(serve_prefill)/ssm/state/" in prefill
+    assert KERNEL_CALL not in prefill and "@_impl" not in prefill

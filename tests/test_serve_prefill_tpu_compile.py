@@ -195,3 +195,44 @@ def test_hybrid_decode_holds_the_grouped_products_and_the_paged_kernel(
     assert len(re.findall(r"= [^=]*custom-call\([^\n]*ragged_dot", entry)) \
         == 20 or entry.count(" %ragged-dot-none") >= 20
     assert "paged_decode_attn" in text
+
+
+def test_hybrid_decode_advances_the_state_in_the_pool_by_the_kernel(
+        hybrid_compiled):
+    """PR 34: one ``ssm_state_step`` call a state layer, under
+    ``ssm/state``, each taking the whole state pool and giving it back
+    in place. Nothing else in the program makes a value of one bank's
+    state (268 MB: the slice, the select, the read-out's operand) or of
+    the pool's shape: no ``select_dynamic-update-slice`` on it, no copy.
+    (In the file PR 33 made for this program, not one of its own: a
+    second file that describes the topology goes to another worker, and
+    only one process may hold the TPU's library.)"""
+    mem, text = hybrid_compiled["serve_decode"]
+    entry = text[text.index("ENTRY "):]
+    bank, pool = r"f32\[64,128,64,128\]", r"f32\[9,64,128,64,128\]"
+    calls = re.findall(
+        rf"\n\s*%ssm_state_step[.\d]* = \([^=\n]*{pool}[^=\n]*\) "
+        r"custom-call\(([^\n]*)", entry)
+    assert len(calls) == 9
+    for call in calls:
+        assert 'custom_call_target="tpu_custom_call"' in call
+        assert "output_to_operand_aliasing={{1}: (6, {})}" in call
+        assert ('op_name="jit(serve_decode)/ssm/state/jit(_impl)/'
+                'ssm_state_step/pallas_call"') in call
+    made = re.findall(
+        rf"= \(?[^=\n]*(?:{bank}|{pool})[^=\n]*? ([\w\-]+)\(", entry)
+    assert sorted(set(made)) == ["custom-call", "get-tuple-element",
+                                 "parameter"], sorted(set(made))
+    assert made.count("custom-call") == 9 and made.count("parameter") == 1
+    assert "select_dynamic-update-slice" not in entry
+    # the state pool, argument to output: the parameter that holds it is
+    # one of the four aliased ones
+    param = re.search(rf"= {pool}\{{[^}}]*\}} parameter\((\d+)\)", entry)
+    head = text[:text.index("\n")]
+    assert f"({param.group(1)}, {{}}, may-alias)" in head
+    assert mem.temp_size_in_bytes < 0.25e9
+
+
+def test_hybrid_prefill_holds_no_state_kernel(hybrid_compiled):
+    _, text = hybrid_compiled["serve_prefill"]
+    assert "ssm_state_step" not in text

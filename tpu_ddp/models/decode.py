@@ -205,18 +205,19 @@ def block_finish(model, blk, x, o):
     return mlp_half(model, blk, x)
 
 
-def ssm_mix(model, blk, x, ssm, conv, n_valid=None):
+def ssm_mix(model, blk, x, ssm, conv, n_valid=None, advance=None):
     """The state-space mixer's half of a block, the twin of
     project_qkv + attention + output projection: norm, the Mamba-2 mixer
     from the given state, residual. One token for each of S sequences
     (``x`` (S, 1, dm), ``ssm`` / ``conv`` with a leading S) or, with
     ``n_valid``, a run of ONE sequence (``x`` (1, C, dm)) of which the
-    first ``n_valid`` rows count. Returns (x, ssm, conv); the caller
-    owns reading and writing ITS state layout."""
+    first ``n_valid`` rows count. ``advance`` is ``ssm_step``'s. Returns
+    (x, ssm, conv); the caller owns reading and writing ITS state
+    layout."""
     from tpu_ddp.models.hybrid import ssm_chunk, ssm_step
     h = model.norm(x, blk["ln1"])
     if n_valid is None:
-        o, ssm, conv = ssm_step(model, blk, h[:, 0], ssm, conv)
+        o, ssm, conv = ssm_step(model, blk, h[:, 0], ssm, conv, advance)
         o = o[:, None]
     else:
         o, ssm, conv = ssm_chunk(model, blk, h[0], ssm, conv, n_valid)

@@ -259,17 +259,20 @@ def _project(model, blk, h):
 
 
 def _split_xbc(model, xbc):
-    """x (..., heads, head_dim), B and C (..., heads, N) in float32, the
-    ``ssm_groups`` groups' B and C repeated over their heads."""
+    """x (..., heads, head_dim), B and C (..., groups, N) in float32, at
+    the width of the ``ssm_groups`` groups that share them."""
     di, gn = model.ssm_inner, model.ssm_groups * model.ssm_state
     lead = xbc.shape[:-1]
     xbc = xbc.astype(jnp.float32)
     x = xbc[..., :di].reshape(lead + (model.ssm_heads, model.ssm_head_dim))
-    per = model.ssm_heads // model.ssm_groups
-    b, c = (jnp.repeat(
-        part.reshape(lead + (model.ssm_groups, model.ssm_state)), per,
-        axis=-2) for part in (xbc[..., di:di + gn], xbc[..., di + gn:]))
+    b, c = (part.reshape(lead + (model.ssm_groups, model.ssm_state))
+            for part in (xbc[..., di:di + gn], xbc[..., di + gn:]))
     return x, b, c
+
+
+def _per_head(a, heads: int):
+    """A group's (..., groups, N) repeated over its heads."""
+    return jnp.repeat(a, heads // a.shape[-2], axis=-2)
 
 
 def _finish(model, blk, y, z):
@@ -282,10 +285,27 @@ def _finish(model, blk, y, z):
                       preferred_element_type=jnp.float32).astype(cd)
 
 
-def ssm_step(model, blk, h, ssm, conv):
+def advance_state(ssm, decay, dtx, b, c):
+    """The recurrence's one-token step, the plain body: ``ssm`` (S,
+    heads, head_dim, N) times ``decay`` (S, heads) plus the outer product
+    of ``dtx`` (S, heads, head_dim), the token's ``dt * x``, and ``b``;
+    ``y = S c`` is read out of the NEW state. ``b`` / ``c`` (S, groups,
+    N). Returns (y (S, heads, head_dim), ssm), float32. The definition
+    that ops/pallas/ssm_state_step.py is tested against."""
+    b, c = (_per_head(a, ssm.shape[1]) for a in (b, c))
+    ssm = (decay[..., None, None] * ssm.astype(jnp.float32)
+           + dtx[..., None] * b[..., None, :])
+    return jnp.einsum("shpn,shn->shp", ssm, c), ssm
+
+
+def ssm_step(model, blk, h, ssm, conv, advance=None):
     """One token for each of S sequences. ``h`` (S, dm) normalised
     input; ``ssm`` (S, heads, head_dim, N) and ``conv`` (S, K-1,
-    conv_dim) the sequences' state. Returns (out (S, dm), ssm, conv)."""
+    conv_dim) the sequences' state. ``advance``: what steps the
+    recurrence, :func:`advance_state` unless given: something with its
+    signature and result that keeps the state elsewhere (the serve
+    step's kernel over the pool; ``ssm`` is then whatever it takes and
+    gives back). Returns (out (S, dm), ssm, conv)."""
     z, xbc, dt = _project(model, blk, h)
     window = jnp.concatenate([conv, xbc[:, None].astype(conv.dtype)],
                              axis=1)                       # (S, K, cdim)
@@ -296,9 +316,9 @@ def ssm_step(model, blk, h, ssm, conv):
     x, b, c = _split_xbc(model, xbc)
     dt = jax.nn.softplus(dt + blk["dt_bias"])               # (S, heads)
     decay = jnp.exp(dt * -jnp.exp(blk["A_log"]))
-    ssm = (decay[..., None, None] * ssm.astype(jnp.float32)
-           + (dt[..., None] * x)[..., None] * b[..., None, :])
-    y = jnp.einsum("shpn,shn->shp", ssm, c) + blk["D"][:, None] * x
+    y, ssm = (advance or advance_state)(ssm, decay, dt[..., None] * x, b,
+                                        c)
+    y = y + blk["D"][:, None] * x
     return _finish(model, blk, y, z), ssm, window[:, 1:]
 
 
@@ -322,6 +342,7 @@ def ssm_chunk(model, blk, h, ssm, conv, n_valid):
     # inputs is rows n_valid .. n_valid + K - 2
     tail = jax.lax.dynamic_slice_in_dim(padded, n_valid, K - 1, axis=0)
     x, b, c = _split_xbc(model, jax.nn.silu(out).astype(model.compute_dtype))
+    b, c = (_per_head(a, model.ssm_heads) for a in (b, c))
     valid = jnp.arange(C) < n_valid
     dt = jnp.where(valid[:, None],
                    jax.nn.softplus(dt + blk["dt_bias"]), 0.0)  # (C, heads)
@@ -350,5 +371,5 @@ def ssm_chunk(model, blk, h, ssm, conv, n_valid):
     return _finish(model, blk, y, z), state, tail
 
 
-__all__ = ["ATTENTION", "MAMBA", "HybridLM", "rms_norm", "ssm_chunk",
-           "ssm_step"]
+__all__ = ["ATTENTION", "MAMBA", "HybridLM", "advance_state", "rms_norm",
+           "ssm_chunk", "ssm_step"]

@@ -16,6 +16,11 @@ Pallas:
 - :mod:`quant_matmul` — weight-only int8 matmul with the dequant scale
                     fused into the epilogue (quantized decode compute,
                     ops/quant.py).
+- :mod:`paged_attention` — decode attention over the paged K/V pool,
+                    read where it lies (serve/engine.py).
+- :mod:`ssm_state_step` — the one-token Mamba-2 state step over the
+                    serving state pool: the state read once, written
+                    once, and ``y`` summed out of the tile held.
 
 Every kernel runs compiled on TPU and falls back to interpreter mode on
 CPU (tests force the host platform, conftest.py), selected automatically.

@@ -86,7 +86,8 @@ from tpu_ddp.models.decode import (
     sample_token,
     ssm_mix,
 )
-from tpu_ddp.ops.pallas import paged_attention
+from tpu_ddp.models.hybrid import advance_state
+from tpu_ddp.ops.pallas import paged_attention, ssm_state_step
 from tpu_ddp.serve.kv_pool import (
     PagedKVPool,
     StatePool,
@@ -201,19 +202,41 @@ def state_step(model, blk, x, state, si: int, active):
     and left as it was elsewhere (rule 1 of three, module docstring of
     kv_pool.StatePool: a slot that is idle, or between two prefill
     chunks, rides along as a row and must not be touched). Scope ``ssm``,
-    the pool's reads and writes under ``ssm/state``."""
+    the pool's reads and writes under ``ssm/state``.
+
+    The recurrence's ``S`` is advanced where it lies, read once and
+    written once, by the kernel (ops/pallas/ssm_state_step.py) where its
+    predicate over shapes and dtype takes the pool; otherwise by the
+    plain body, which slices the layer out, advances it, and writes it
+    back through a select. The choice is made here, as the program is
+    traced."""
+    ssm, conv = state["ssm"], state["conv"]
+
+    def keep(new, old):
+        return jnp.where(active.reshape((-1,) + (1,) * (old.ndim - 1)),
+                         new.astype(old.dtype), old)
+
+    def in_pool(pool, *step):
+        with jax.named_scope("state"):
+            return ssm_state_step.ssm_state_step(pool, *step, layer=si,
+                                                 active=active)
+
+    def plain(pool, *step):
+        with jax.named_scope("state"):
+            old = pool[si]
+        y, new = advance_state(old, *step)
+        with jax.named_scope("state"):
+            return y, pool.at[si].set(keep(new, old))
+
+    takes = ssm_state_step.supports(ssm.shape[3], ssm.shape[4], ssm.dtype)
     with jax.named_scope("ssm"):
         with jax.named_scope("state"):
-            old = {k: a[si] for k, a in state.items()}
-        x, ssm, conv = ssm_mix(model, blk, x, old["ssm"], old["conv"])
+            old_conv = conv[si]
+        x, ssm, new_conv = ssm_mix(model, blk, x, ssm, old_conv,
+                                   advance=in_pool if takes else plain)
         with jax.named_scope("state"):
-            new = {"ssm": ssm, "conv": conv}
-            state = {
-                k: a.at[si].set(jnp.where(
-                    active.reshape((-1,) + (1,) * (old[k].ndim - 1)),
-                    new[k].astype(a.dtype), old[k]))
-                for k, a in state.items()}
-    return mlp_half(model, blk, x), state
+            conv = conv.at[si].set(keep(new_conv, old_conv))
+    return mlp_half(model, blk, x), {"ssm": ssm, "conv": conv}
 
 
 def state_chunk(model, blk, x, state, si: int, slot, fresh, n_valid):

@@ -161,6 +161,10 @@ KERNELS = {
     "bn_relu_bwd_dx": "bn_relu.py: backward dx",
     "paged_decode_attn": "paged_attention.py: one token per slot against "
                          "the K/V pool read in place, one call a layer",
+    "ssm_state_step": "ssm_state_step.py: one token per slot of a "
+                      "state-space layer, the recurrent state advanced in "
+                      "the state pool and y read out of the tile held, one "
+                      "call a state layer",
 }
 
 # jax.named_scope at the layer boundaries of the programs the cells run.
@@ -184,7 +188,8 @@ SCOPES = {
            "serve_decode, the chunked scan in serve_prefill), gated "
            "norm, output projection, residual",
     "state": "inside ssm, serving: the reads and writes of the state "
-             "pool (the path ssm/state)",
+             "pool (the path ssm/state); in serve_decode the "
+             "ssm_state_step kernel where it takes the pool",
     "moe": "inside mlp: the dropless expert layer over the experts "
            "held",
     "route": "inside moe: router, top-k, gates, and the sort of the "
