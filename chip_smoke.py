@@ -7,12 +7,12 @@ models the repo benchmarks (VGG-11 at the reference's global batch 256;
 TransformerLM-large, 12 layers, d_model 2048, ~740M parameters), with
 random weights made from a seed, and checks the results by the repo's
 own means: the step guard's finite-loss check, the retrace sentinel, the
-``jax.numpy`` references of the six Pallas kernels, ``generate()``.
+``jax.numpy`` references of the seven Pallas kernels, ``generate()``.
 
     P0  device: platform pinned to tpu, device_kind in both peak tables,
         compile-cache directory, native libraries rebuilt from source
     P1  the ladder: run_part("part1") and run_part("part3") on VGG-11
-    P2  the six Pallas kernels, compiled, against their references
+    P2  the seven Pallas kernels, compiled, against their references
     P3  LMTrainer on TransformerLM-large with the flash kernel, 5 steps
     P4  ServeEngine on TransformerLM-large, bf16 then int8 decode, the
         paged decode kernel in the compiled step (and the gather body
@@ -84,6 +84,12 @@ PAGED_SHAPE = (2, 8 * 64 + 1, 16, 2, 128, 8, 24, 64)
 PAGED_LENGTHS = (1024, 1, 16, 17, 129, 511, 700, 33)
 # state layers, slots, heads, head_dim, N, groups: two 2 MB tiles a slot
 STATE_SHAPE = (2, 4, 128, 64, 128, 1)
+# The expert layer's two grouped products at the hybrid cell's widths
+# (rows, k, n), over four experts: one empty, one of more rows than a
+# trip takes, one that starts inside a packed register, and 441 rows in
+# no group.
+GROUPED_SHAPES = ((640, 4096, 1536), (640, 768, 4096))
+GROUPED_SIZES = (9, 0, 150, 40)
 VGG_LEAF = (3, 3, 256, 512)          # a VGG-11 conv kernel
 VGG_ACTIVATION = (256, 32, 32, 64)   # first conv output at batch 256
 
@@ -303,6 +309,7 @@ def p2_kernels() -> dict:
     from tpu_ddp.ops.pallas import (batch_norm_relu, flash_attention,
                                     int8_matmul)
     from tpu_ddp.models.hybrid import advance_state
+    from tpu_ddp.ops.pallas.grouped_matmul import grouped_matmul
     from tpu_ddp.ops.pallas.paged_attention import paged_decode_attention
     from tpu_ddp.ops.pallas.ssm_state_step import ssm_state_step
     from tpu_ddp.ops.quant import quantize_weight
@@ -404,6 +411,20 @@ def p2_kernels() -> dict:
         check("ssm_state_step y", lambda *a: in_pool(*a)[0],
               lambda *a: plain(*a)[0], (pool, decay, dtx, b, c),
               TOL_F32_ELEMENTWISE)
+
+        sizes = jnp.asarray(GROUPED_SIZES, jnp.int32)
+        for m, kdim, ndim in GROUPED_SHAPES:
+            rows = jax.random.normal(next(keys), (m, kdim), jnp.bfloat16)
+            w = (jax.random.normal(next(keys), (len(GROUPED_SIZES), kdim,
+                                                ndim), jnp.float32)
+                 * kdim ** -0.5).astype(jnp.bfloat16)
+            in_group = (jnp.arange(m) < sum(GROUPED_SIZES))[:, None]
+            # lax.ragged_dot leaves the rows past the last group
+            # undefined; the kernel writes zeros there
+            check(f"grouped_matmul {kdim}->{ndim}", grouped_matmul,
+                  lambda r, w, s: jnp.where(in_group, jax.lax.ragged_dot(
+                      r, w, s, preferred_element_type=jnp.float32), 0.0),
+                  (rows, w, sizes), TOL_F32_DOT)
 
         tree = {"w": jax.random.normal(next(keys), VGG_LEAF, jnp.float32),
                 "b": jax.random.normal(next(keys), VGG_LEAF[-1:],

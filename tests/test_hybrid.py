@@ -421,3 +421,65 @@ def test_decode_names_the_state_kernel_under_ssm_state_and_prefill_does_not():
     prefill = eng.lower_prefill_step().as_text(debug_info=True)
     assert "jit(serve_prefill)/ssm/state/" in prefill
     assert KERNEL_CALL not in prefill and "@_impl" not in prefill
+
+
+# ---- the grouped products through the kernel (PR 37) --------------------------
+
+# bf16 compute at widths the grouped-matmul predicate takes (d_model and
+# d_ff whole lane tiles), on a geometry whose sorted rows are whole
+# packed registers: 4 slots x top-4 = 16 a decode step, 8 x 4 = 32 a chunk.
+KERNEL_GEOM = dict(num_slots=4, block_size=8, prefill_chunk=8)
+GROUPED_CALL = '"grouped_matmul/pallas_call"'
+BF16_TOL = 0.06     # bf16 through four layers against the float32 reference
+
+
+def _kernel_model(**kw):
+    return _model(d_model=128, d_ff=128, shared_ff=64, top_k=4,
+                  compute_dtype=jnp.bfloat16, **kw)
+
+
+def test_dropless_moe_through_the_kernel_matches_the_per_expert_reference():
+    """The expert layer alone, bf16, both products through the kernel,
+    against the reference's loop over experts in float32 on the same
+    (bf16-rounded) weights."""
+    from tpu_ddp.ops.pallas import grouped_matmul
+    m = _kernel_model()
+    blk = jax.tree.map(lambda a: a.astype(jnp.bfloat16).astype(jnp.float32),
+                       m.init(jax.random.key(5))["blocks"][1])
+    x = jax.random.normal(jax.random.key(6), (12, m.d_model)) * 3.0
+    h = m.norm(x, blk["ln2"]).astype(jnp.bfloat16)
+    assert grouped_matmul.supports(12 * m.top_k, m.d_model, 2 * m.d_ff,
+                                   h.dtype, jnp.bfloat16)
+    f = lambda h: dropless_moe(  # noqa: E731
+        h, blk["router"], blk["w1"].astype(jnp.bfloat16),
+        blk["w2"].astype(jnp.bfloat16), top_k=m.top_k, held=m.held)
+    assert str(jax.make_jaxpr(f)(h)).count("name=grouped_matmul") == 2
+    shared = decode.gated_mlp(m, h.astype(jnp.float32), blk["shared_w1"],
+                              blk["shared_w2"])
+    want = reference.moe(blk, x, _cfg(m)) - shared
+    assert float(jnp.abs(want).max()) > 0.05
+    np.testing.assert_allclose(f(h), want, atol=0.03, rtol=0.03)
+
+
+@pytest.mark.parametrize("body", ["kernel", "plain"])
+def test_engine_logprobs_match_the_reference_in_bf16(monkeypatch, body):
+    """The engine's two programs with the grouped products through the
+    kernel (interpreted here), and the same engine held to
+    ``lax.ragged_dot``: each within bf16's distance of the float32
+    reference, each program naming what it runs under ``moe/experts``."""
+    from tpu_ddp.ops.pallas import grouped_matmul
+    if body == "plain":
+        monkeypatch.setattr(grouped_matmul, "supports", lambda *a: False)
+    # the engine's step builders are cached by the model: a name apart
+    m = _kernel_model(name=f"hybrid-{body}")
+    p = m.init(jax.random.key(7))
+    eng, prompts, reqs = _serve(m, p, CASES[:5], **KERNEL_GEOM)
+    for prompt, r, (_, new) in zip(prompts, reqs, CASES):
+        assert r.done and len(r.tokens) == new
+        assert _worst(m, p, prompt, r) < BF16_TOL
+    for lower, name in ((eng.lower_decode_step, "serve_decode"),
+                        (eng.lower_prefill_step, "serve_prefill")):
+        text = lower().as_text(debug_info=True)
+        assert f"jit({name})/mlp/moe/experts/" in text, name
+        assert (GROUPED_CALL in text) == (body == "kernel"), name
+        assert ("ragged_dot" in text) == (body == "plain"), name

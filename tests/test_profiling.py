@@ -63,10 +63,10 @@ def test_every_kernel_and_scope_name_is_in_its_table_and_used():
     assert set(kernels) == set(KERNELS)
     assert {f for fs in kernels.values() for f in fs} == {
         "flash_attention.py", "quant_matmul.py", "sgd.py", "bn_relu.py",
-        "paged_attention.py", "ssm_state_step.py"}
+        "paged_attention.py", "ssm_state_step.py", "grouped_matmul.py"}
     pallas = sum(p.read_text().count("pl.pallas_call(")
                  for p in (ROOT / "tpu_ddp/ops/pallas").glob("*.py"))
-    assert pallas == len(KERNELS) == 11
+    assert pallas == len(KERNELS) == 12
     scopes = _calls(re.compile(r'jax\.named_scope\("(\w+)"\)'))
     assert set(scopes) == set(SCOPES)
 

@@ -186,15 +186,42 @@ def test_hybrid_programs_fit_and_update_state_and_pool_in_place(
     assert mem.temp_size_in_bytes < temp_gb * 1e9
 
 
+@pytest.mark.parametrize("program,rows", [("serve_decode", 640),
+                                          ("serve_prefill", 2560)])
 def test_hybrid_decode_holds_the_grouped_products_and_the_paged_kernel(
-        hybrid_compiled):
-    """Twenty grouped products (two a layer) as the TPU's own ragged
-    dot, not a (T, E, C) dispatch; attention reads the pool in place."""
-    _, text = hybrid_compiled["serve_decode"]
+        hybrid_compiled, program, rows):
+    """PR 37: twenty grouped products (two a layer) in each program, as
+    the ``grouped_matmul`` kernel under ``moe/experts`` (its predicate
+    takes 640 and 2,560 sorted rows at these widths), not a (T, E, C)
+    dispatch and no longer the TPU's own ragged dot; decode attention
+    reads the pool in place. A program whose shapes the predicate
+    refused would hold the twenty as ``ragged-dot-none`` custom calls:
+    the two counts add up to twenty either way."""
+    from tpu_ddp.ops.pallas import grouped_matmul
+    mem, text = hybrid_compiled[program]
     entry = text[text.index("ENTRY "):]
-    assert len(re.findall(r"= [^=]*custom-call\([^\n]*ragged_dot", entry)) \
-        == 20 or entry.count(" %ragged-dot-none") >= 20
-    assert "paged_decode_attn" in text
+    calls = re.findall(
+        r"\n\s*%grouped_matmul[.\d]* = (f32\[\d+,\d+\])[^=\n]* "
+        r"custom-call\(([^\n]*)", entry)
+    plain = len(re.findall(r"= [^=]*custom-call\([^\n]*ragged_dot", entry)) \
+        or entry.count(" %ragged-dot-none")
+    assert grouped_matmul.supports(rows, 4096, 1536, jnp.bfloat16,
+                                   jnp.bfloat16)
+    assert grouped_matmul.supports(rows, 768, 4096, jnp.bfloat16,
+                                   jnp.bfloat16)
+    assert len(calls) == 20 and plain == 0
+    assert sorted({shape for shape, _ in calls}) == [
+        f"f32[{rows},1536]", f"f32[{rows},4096]"]
+    for _, call in calls:
+        assert 'custom_call_target="tpu_custom_call"' in call
+        assert (f'op_name="jit({program})/mlp/moe/experts/jit(_impl)/'
+                'grouped_matmul/pallas_call"') in call
+    if program == "serve_decode":
+        assert "paged_decode_attn" in text
+    # the rows and the products of a layer are the program's largest
+    # new temporaries (2,560 x 4096 float32 is 42 MB): inside the limit
+    # the neighbouring test holds
+    assert mem.temp_size_in_bytes < (0.1e9 if rows == 640 else 0.3e9)
 
 
 def test_hybrid_decode_advances_the_state_in_the_pool_by_the_kernel(

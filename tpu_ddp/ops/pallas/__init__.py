@@ -21,6 +21,9 @@ Pallas:
 - :mod:`ssm_state_step` — the one-token Mamba-2 state step over the
                     serving state pool: the state read once, written
                     once, and ``y`` summed out of the tile held.
+- :mod:`grouped_matmul` — the dropless expert layer's grouped product
+                    over sorted rows: each held expert's matrix
+                    streamed once past rows resident on chip.
 
 Every kernel runs compiled on TPU and falls back to interpreter mode on
 CPU (tests force the host platform, conftest.py), selected automatically.

@@ -185,6 +185,21 @@ def moe_mlp(y, router_w, w1, w2, *, num_experts: int,
     return y_out.reshape(b, L, dm), aux
 
 
+def grouped_dot(rows, w, sizes):
+    """``rows`` (m, k) sorted by group times ``w`` (groups, k, n), group
+    ``g`` being the next ``sizes[g]`` rows: (m, n) float32. The Pallas
+    kernel (ops/pallas/grouped_matmul.py) where its predicate takes the
+    shapes and dtypes, and ``lax.ragged_dot``, its definition, where it
+    does not. The rows past the last group hold nothing a caller may
+    use (the kernel writes zeros there, ``ragged_dot`` what it likes)."""
+    from tpu_ddp.ops.pallas import grouped_matmul as kernel
+    if kernel.supports(rows.shape[0], w.shape[1], w.shape[2], rows.dtype,
+                       w.dtype):
+        return kernel.grouped_matmul(rows, w, sizes)
+    return lax.ragged_dot(rows, w, sizes,
+                          preferred_element_type=jnp.float32)
+
+
 def dropless_moe(x, router_w, w1, w2, *, top_k: int, held: tuple):
     """Dropless top-k routed gated MLP, for a chip that holds a share of
     the experts: (T, dm) -> (T, dm) float32.
@@ -200,7 +215,7 @@ def dropless_moe(x, router_w, w1, w2, *, top_k: int, held: tuple):
     computed, none dropped, and every shape is static whatever the
     routing: the ``T * top_k`` assignments are sorted by expert (those of
     absent experts last, in no group) and the two products are grouped
-    ones (``lax.ragged_dot``) over the sorted rows, not a (T, E, C)
+    ones (:func:`grouped_dot`) over the sorted rows, not a (T, E, C)
     dispatch tensor. The capacity form above stays for the models that
     train with it."""
     T = x.shape[0]
@@ -223,12 +238,10 @@ def dropless_moe(x, router_w, w1, w2, *, top_k: int, held: tuple):
             jnp.arange(order.shape[0], dtype=order.dtype))
     with jax.named_scope("experts"):
         rows = x[order // top_k]                                # (T*k, dm)
-        uv = lax.ragged_dot(rows, w1.astype(cd), sizes,
-                            preferred_element_type=jnp.float32)
+        uv = grouped_dot(rows, w1.astype(cd), sizes)
         u, v = jnp.split(uv, 2, axis=-1)
         act = (jax.nn.silu(u) * v).astype(cd)
-        out = lax.ragged_dot(act, w2.astype(cd), sizes,
-                             preferred_element_type=jnp.float32)
+        out = grouped_dot(act, w2.astype(cd), sizes)
         # rows past the last group (absent experts) hold nothing defined
         out = jnp.where((weight > 0)[:, None], out * weight[:, None], 0.0)
         return out[back].reshape(T, top_k, -1).sum(axis=1)

@@ -165,6 +165,10 @@ KERNELS = {
                       "state-space layer, the recurrent state advanced in "
                       "the state pool and y read out of the tile held, one "
                       "call a state layer",
+    "grouped_matmul": "grouped_matmul.py: the dropless expert layer's "
+                      "grouped product over the sorted assignments, each "
+                      "held expert's matrix streamed once past rows "
+                      "resident on chip, two calls an expert layer",
 }
 
 # jax.named_scope at the layer boundaries of the programs the cells run.
@@ -196,10 +200,12 @@ SCOPES = {
              "assignments by expert (the path moe/route)",
     "experts": "inside moe: the gather of the sorted rows, the two "
                "grouped products and the weighted sum back to tokens "
-               "(the path moe/experts). XLA:TPU runs each grouped "
-               "product (lax.ragged_dot) as a custom call of its own "
-               "whose operations are named ragged-dot-none and carry NO "
-               "scope path: a reader counts them by that name",
+               "(the path moe/experts). Each grouped product is the "
+               "grouped_matmul kernel where it takes the shapes, under "
+               "this path like any operation; elsewhere lax.ragged_dot, "
+               "which XLA:TPU runs as a custom call of its own whose "
+               "operations are named ragged-dot-none and carry NO scope "
+               "path: a reader counts those by that name",
     "shared_mlp": "inside mlp: the shared gated MLP, every token",
     "head": "final LayerNorm and output head",
     "sample": "serving: token sampling and the non-finite check",
