@@ -482,4 +482,7 @@ def test_engine_logprobs_match_the_reference_in_bf16(monkeypatch, body):
         text = lower().as_text(debug_info=True)
         assert f"jit({name})/mlp/moe/experts/" in text, name
         assert (GROUPED_CALL in text) == (body == "kernel"), name
-        assert ("ragged_dot" in text) == (body == "plain"), name
+        # the primitive's name in the op paths: a test that traced the
+        # kernel first leaves its own name (``..._ragged_dot``) in the
+        # cached kernel body's locations
+        assert ("ragged_dot_general" in text) == (body == "plain"), name

@@ -8,6 +8,7 @@ give bit-equal tokens and losses (a span is a no-op, a name is metadata).
 import glob
 import os
 import re
+import time
 from pathlib import Path
 
 import jax
@@ -28,7 +29,8 @@ from tpu_ddp.utils.profiling import (KERNELS, PROGRAMS, SCOPES, SPANS,
 
 ROOT = Path(__file__).resolve().parents[1]
 SOURCES = sorted((ROOT / "tpu_ddp").rglob("*.py"))
-SPAN_CALL = re.compile(r"\bspan(?:ned)?\(\s*(?:[\w.]+,\s*)?([^\s,)]+)")
+SPAN_CALL = re.compile(
+    r"\b(?:span(?:ned)?|mark)\(\s*(?:[\w.]+,\s*)?([^\s,)]+)")
 SERVE = sorted(n for n in SPANS if n.startswith("tpu_ddp.serve."))
 
 
@@ -38,7 +40,7 @@ def _calls(pattern, sources=SOURCES) -> dict:
     for path in sources:
         text = path.read_text()
         if path.name == "profiling.py":
-            text = text.split("\ndef span(")[0]     # the tables, not the API
+            text = text.split("\ndef mark(")[0]     # the tables, not the API
         for m in pattern.finditer(text):
             found.setdefault(m.group(1), []).append(path.name)
     return found
@@ -71,10 +73,10 @@ def test_every_kernel_and_scope_name_is_in_its_table_and_used():
     assert set(scopes) == set(SCOPES)
 
 
-def test_every_gauge_of_the_table_is_observed_by_the_engine_and_printed():
+def test_the_gauges_of_the_table_are_those_the_engine_observes_and_printed():
     observed = _calls(re.compile(r'metrics\.observe\(\s*"(\w+)"'),
                       [ROOT / "tpu_ddp/serve/engine.py"])
-    assert set(profiling.GAUGES) <= set(observed)
+    assert set(profiling.GAUGES) == set(observed)
     section = (ROOT / "docs" / "DESIGN.md").read_text().split(
         "Tracing", 1)[1]
     for name in profiling.GAUGES:
@@ -206,9 +208,13 @@ def test_span_is_emitted_with_the_counts_of_the_table(runs, name):
             if s[0] == name]
     assert hits, f"no run emitted {name}"
     counts = set(SPANS[name][2])
+    seen = set()
     for _, start, end, stats in hits:
-        assert set(stats) == counts and end >= start
+        # only ``dry`` is ever left out: at rest, or at a trainer's first
+        assert counts - {"dry"} <= set(stats) <= counts and end >= start
         assert all(isinstance(v, (int, float)) for v in stats.values())
+        seen |= set(stats)
+    assert seen == counts
 
 
 def _parent(spans, child):
@@ -223,6 +229,7 @@ def _parent(spans, child):
 def test_serve_spans_nest_as_the_table_says(runs, key):
     spans = runs["spans"][key]
     want = {"tpu_ddp.serve.step": None,
+            "tpu_ddp.serve.tally": None,
             "tpu_ddp.serve.schedule": "tpu_ddp.serve.step",
             "tpu_ddp.serve.admit": "tpu_ddp.serve.schedule",
             "tpu_ddp.serve.prefill": "tpu_ddp.serve.step",
@@ -231,23 +238,21 @@ def test_serve_spans_nest_as_the_table_says(runs, key):
                  if n.startswith("tpu_ddp.serve.decode.")})
     assert {s[0] for s in spans} == set(SERVE)
     harvest = ["tpu_ddp.serve.decode.fetch", "tpu_ddp.serve.decode.emit"]
+    steps = [s for s in spans if s[0] == "tpu_ddp.serve.step"]
     for s in spans:
         parent = _parent(spans, s)
         if s[0] in harvest and parent[0] == "tpu_ddp.serve.step":
             # Read back outside a decode span: the first tokens of the
             # chunks before a speculative step's own body, and the plain
             # engine's last step, read in a step with no decode left.
-            assert key != "serve" or parent[3]["n"] == sum(
-                x[0] == "tpu_ddp.serve.step" for x in spans) - 1
+            assert key != "serve" or parent is steps[-2]
             continue
         assert (parent[0] if parent else None) == want[s[0]], s
-    steps = [s for s in spans if s[0] == "tpu_ddp.serve.step"]
-    assert [s[3]["n"] for s in steps] == list(range(1, len(steps) + 1))
     assert len(steps) == runs["traced"][key]["steps"] + 1   # the idle one
     for s in steps:
         kids = [c[0] for c in spans if _parent(spans, c) is s]
         assert kids[0] == "tpu_ddp.serve.schedule"
-        assert 0 <= s[3]["live"] <= 4 and s[3]["blocks_in_use"] >= 0
+        assert s[3] == {}
     for d in (s for s in spans if s[0] == "tpu_ddp.serve.decode"):
         kids = [c[0] for c in spans if _parent(spans, c) is d]
         assert kids[:2] == ["tpu_ddp.serve.decode.tables",
@@ -272,23 +277,21 @@ def test_a_request_shares_its_rid_between_admit_and_prefill(runs):
         assert sum(c["tokens"] for c in chunks) == prompt   # not padded
         assert [c["start"] for c in chunks] == list(range(0, prompt, 8))
         assert [c["final"] for c in chunks] == [0] * (len(chunks) - 1) + [1]
-    # six requests on four slots: the last two waited in the queue
-    assert max(s[3]["queue"] for s in spans
-               if s[0] == "tpu_ddp.serve.step") == 6
 
 
 def test_context_tokens_are_the_schedulers_own_lengths(runs):
     spans, run = runs["spans"]["serve"], runs["traced"]["serve"]
     decodes = [s[3] for s in spans if s[0] == "tpu_ddp.serve.decode"]
     assert [d["context_tokens"] for d in decodes] == run["lengths"]
-    assert all(1 <= d["slots"] <= 4 for d in decodes)
 
 
 def test_trainer_spans_carry_their_steps(runs):
     lm = runs["spans"]["lm"]
     assert [s[0] for s in lm] == ["tpu_ddp.lm.put_batch",
                                   "tpu_ddp.lm.train_step"] * 3
-    assert [s[3] for s in lm[1::2]] == [{"step": i} for i in range(3)]
+    # the test reads each loss before the next step: the device is dry
+    assert [s[3] for s in lm[1::2]] == [
+        {"step": 0}, {"step": 1, "dry": 1}, {"step": 2, "dry": 1}]
     assert all(s[3] == {"tokens": 64} for s in lm[0::2])
     for key, dispatches in (("epoch", 5), ("epoch_multi", 3)):
         spans = runs["spans"][key]
@@ -375,16 +378,17 @@ def test_design_page_prints_the_tables():
 
 
 def test_span_outside_a_session_is_a_noop():
-    with span("tpu_ddp.serve.step", n=1, queue=0, live=0, blocks_in_use=0):
+    with span("tpu_ddp.serve.step"):
         pass
+    profiling.mark("tpu_ddp.serve.tally", lambda: 1 / 0)   # never called
     assert list(profiling.spanned([1, 2, 3], "tpu_ddp.train.data_next")) \
         == [1, 2, 3]
 
 
 # ---- bursts -----------------------------------------------------------------
 
-def _step_span(n):
-    return span("tpu_ddp.serve.step", n=n, queue=0, live=0, blocks_in_use=0)
+def _step_span():
+    return span("tpu_ddp.serve.step")
 
 
 @pytest.mark.parametrize("n, kept", [
@@ -395,20 +399,20 @@ def _step_span(n):
     (profiling.BURST_EVERY + profiling.BURST_STEPS + 1, False)])
 def test_burst_keeps_the_first_steps_of_every_period(n, kept):
     with profiling.burst(n):
-        inside = _step_span(n)
+        inside = _step_span()
         with profiling.burst(1):        # an engine stepped inside another's
-            assert isinstance(_step_span(1), jax.profiler.TraceAnnotation)
-        again = _step_span(n)
+            assert isinstance(_step_span(), jax.profiler.TraceAnnotation)
+        again = _step_span()
     for s in (inside, again):
         assert isinstance(s, jax.profiler.TraceAnnotation) == kept
-    assert isinstance(_step_span(n), jax.profiler.TraceAnnotation)
+    assert isinstance(_step_span(), jax.profiler.TraceAnnotation)
 
 
 def test_burst_restores_the_thread_after_an_exception():
     with pytest.raises(RuntimeError):
         with profiling.burst(profiling.BURST_STEPS + 1):
             raise RuntimeError
-    assert isinstance(_step_span(1), jax.profiler.TraceAnnotation)
+    assert isinstance(_step_span(), jax.profiler.TraceAnnotation)
 
 
 def test_the_tiny_runs_fit_in_one_burst(runs):
@@ -426,10 +430,14 @@ def test_an_engine_step_is_annotated_whole_or_not_at_all(tmp_path):
     params = model.init(jax.random.key(0))
     prompt = np.random.default_rng(5).integers(0, 1024, size=12)
 
+    calls = []
+
     def run(jump):
         engine = ServeEngine(model, params, num_slots=2, block_size=8,
                              prefill_chunk=8)
         req = engine.submit(prompt, 6, seed=0)
+        inner = engine.step
+        engine.step = lambda: calls.append(engine._step_n + 1) or inner()
         for at in jump:
             engine._step_n = at
             engine.step()
@@ -438,6 +446,7 @@ def test_an_engine_step_is_annotated_whole_or_not_at_all(tmp_path):
         return list(req.tokens)
 
     plain = run([0, 2])
+    calls.clear()
     with profile_trace(str(tmp_path)):
         with jax.profiler.TraceAnnotation("test.jump"):
             traced = run([profiling.BURST_STEPS - 1, profiling.BURST_EVERY])
@@ -446,12 +455,190 @@ def test_an_engine_step_is_annotated_whole_or_not_at_all(tmp_path):
     spans = [s for s in _read_events(str(tmp_path), profiling.PREFIX)
              if lo <= s[1] and s[2] <= hi]
     steps = [s for s in spans if s[0] == "tpu_ddp.serve.step"]
-    kept = [s[3]["n"] for s in steps]
-    assert kept[:2] == [profiling.BURST_STEPS, profiling.BURST_EVERY + 1]
-    assert all((n - 1) % profiling.BURST_EVERY < profiling.BURST_STEPS
-               for n in kept)
-    # nothing outside a step span: a step left out leaves no child either
+    assert calls[:3] == [profiling.BURST_STEPS, profiling.BURST_STEPS + 1,
+                         profiling.BURST_EVERY + 1]
+    kept = [n for n in calls
+            if (n - 1) % profiling.BURST_EVERY < profiling.BURST_STEPS]
+    assert len(kept) == len(calls) - 1 and len(steps) == len(kept)
+    # nothing outside a step span but the tally: a step left out leaves
+    # no child either
     for s in spans:
-        assert any(p[1] <= s[1] and s[2] <= p[2] for p in steps), s
+        assert s[0] == "tpu_ddp.serve.tally" \
+            or any(p[1] <= s[1] and s[2] <= p[2] for p in steps), s
     assert {s[0] for s in spans} >= {"tpu_ddp.serve.prefill",
                                      "tpu_ddp.serve.schedule"}
+
+
+# ---- the tally, dry dispatch, host busy time --------------------------------
+
+TALLY = "tpu_ddp.serve.tally"
+# tally count -> (gauge or counter, what of it)
+FROM_LOGGER = {
+    "steps": ("serve_host_busy_ms", "count"),
+    "decode_steps": ("serve_decode_rows", "count"),
+    "decode_ahead": ("serve_decode_ahead", None),
+    "dry_steps": ("serve_decode_dry", None),
+    "decode_rows": ("serve_decode_rows", "total"),
+    "context_tokens": ("serve_decode_context_tokens", "total"),
+    "prefill_chunks": ("serve_prefill_tokens", "count"),
+    "prefill_tokens": ("serve_prefill_tokens", "total"),
+    "kv_blocks_in_use": ("serve_kv_blocks_in_use", "total"),
+    "host_busy_ms": ("serve_host_busy_ms", "total"),
+    "fetch_wait_ms": ("serve_fetch_wait_ms", "total"),
+    "queue_depth": ("serve_queue_depth", "total"),
+}
+
+
+def _logged(metrics) -> dict:
+    """The tally's counts as read straight from ``metrics``."""
+    out = {}
+    for count, (name, what) in FROM_LOGGER.items():
+        if what is None:
+            out[count] = metrics.counters.get(name, 0)
+        else:
+            out[count] = metrics.gauges.get(name, {}).get(what, 0)
+    return out
+
+
+@pytest.fixture(scope="module")
+def tallied(tmp_path_factory):
+    """Six requests through four slots with a tally before every step
+    (``TALLY_S`` 0), the logger read after each step, and the host's
+    clock around each ``step()``; every step is in the first burst."""
+    from tpu_ddp.serve import engine as engine_mod
+
+    model = _lm()
+    params = model.init(jax.random.key(0))
+    engine = ServeEngine(model, params, num_slots=4, block_size=8,
+                         prefill_chunk=8)
+    rng = np.random.default_rng(3)
+    for i, n in enumerate((5, 12, 20, 7, 9, 17)):
+        engine.submit(rng.integers(0, 1024, size=n), 5, seed=i)
+    after, walls = [_logged(engine.metrics)], []
+    logdir = str(tmp_path_factory.mktemp("tally"))
+    saved = engine_mod.TALLY_S
+    engine_mod.TALLY_S = 0.0
+    try:
+        with profile_trace(logdir):
+            more = True
+            while more:
+                t = time.perf_counter()
+                more = engine.step()
+                walls.append((time.perf_counter() - t) * 1e3)
+                after.append(_logged(engine.metrics))
+    finally:
+        engine_mod.TALLY_S = saved
+    assert engine._step_n < profiling.BURST_STEPS
+    return {"spans": _read_events(logdir, profiling.PREFIX),
+            "after": after, "walls": walls, "engine": engine}
+
+
+def test_a_tally_opens_every_step_with_the_loggers_totals(tallied):
+    spans, after = tallied["spans"], tallied["after"]
+    tallies = [s[3] for s in spans if s[0] == TALLY]
+    assert len(tallies) == len(after) - 1
+    usable = tallied["engine"].pool.total_usable
+    for k, tally in enumerate(tallies):
+        assert tally.pop("kv_blocks_usable") == usable
+        assert tally == pytest.approx(after[k], abs=1e-9)
+    # so the difference of any two is what the logger summed between
+    first, last = tallies[1], tallies[-1]
+    for count in first:
+        assert last[count] - first[count] == pytest.approx(
+            after[len(tallies) - 1][count] - after[1][count], abs=1e-9)
+    # six requests on four slots: two waited while the first four filled
+    assert tallied["engine"].metrics.gauges["serve_queue_depth"]["max"] == 2
+
+
+def test_the_tallys_differences_are_the_spans_sums(tallied):
+    """Over every step between the first tally and the last, the counts
+    the spans of those steps carry add up to the tallies' differences."""
+    spans = tallied["spans"]
+    marks = [s for s in spans if s[0] == TALLY]
+    lo, hi = marks[0][1], marks[-1][1]
+    inside = [s for s in spans if lo <= s[1] < hi]
+    decodes = [s[3] for s in inside if s[0] == "tpu_ddp.serve.decode"]
+    chunks = [s[3] for s in inside if s[0] == "tpu_ddp.serve.prefill"]
+    diff = {k: marks[-1][3][k] - marks[0][3][k] for k in marks[0][3]}
+    assert diff["steps"] == sum(s[0] == "tpu_ddp.serve.step" for s in inside)
+    assert diff["decode_steps"] == len(decodes)
+    assert diff["decode_ahead"] == sum(d["ahead"] for d in decodes)
+    assert diff["dry_steps"] == sum(d.get("dry", 0) for d in decodes)
+    assert diff["context_tokens"] == sum(d["context_tokens"] for d in decodes)
+    assert diff["prefill_chunks"] == len(chunks)
+    assert diff["prefill_tokens"] == sum(c["tokens"] for c in chunks)
+    assert diff["kv_blocks_in_use"] > 0 and diff["queue_depth"] > 0
+
+
+def test_host_busy_and_fetch_wait_split_the_steps_wall_time(tallied):
+    engine, walls = tallied["engine"], tallied["walls"]
+    busy = engine.metrics.gauges["serve_host_busy_ms"]
+    fetch = engine.metrics.gauges["serve_fetch_wait_ms"]
+    assert busy["count"] == fetch["count"] == len(walls)
+    assert fetch["total"] > 0 and busy["total"] > 0
+    # the engine's own clock sits inside the caller's
+    assert busy["total"] + fetch["total"] <= sum(walls)
+    assert busy["total"] + fetch["total"] >= 0.9 * sum(walls)
+
+
+def test_the_tally_is_recorded_in_a_step_the_burst_leaves_out(tmp_path):
+    model = _lm()
+    engine = ServeEngine(model, model.init(jax.random.key(0)), num_slots=2,
+                         block_size=8, prefill_chunk=8)
+    engine.submit(np.arange(12), 3, seed=0)
+    engine._step_n = profiling.BURST_STEPS      # the next one is muted
+    with profile_trace(str(tmp_path)):
+        engine.step()
+        engine.step()
+    spans = _read_events(str(tmp_path), profiling.PREFIX)
+    assert [s[0] for s in spans] == [TALLY]
+    assert spans[0][3]["steps"] == 0 and spans[0][2] >= spans[0][1]
+
+
+def test_the_tally_costs_nothing_with_no_session_open():
+    model = _lm()
+    engine = ServeEngine(model, model.init(jax.random.key(0)), num_slots=2,
+                         block_size=8, prefill_chunk=8)
+    engine._tally = lambda: 1 / 0       # would raise if it were counted
+    engine.submit(np.arange(12), 3, seed=0)
+    assert engine.run() > 0
+
+
+def test_dry_is_1_after_the_host_waited_out_the_step_before(tmp_path):
+    """The host blocks until the step before is done, then steps: the
+    decode span says dry = 1 and the counter agrees; the first decode
+    step, dispatched at rest, has no dry at all."""
+    model = _lm()
+    engine = ServeEngine(model, model.init(jax.random.key(0)), num_slots=2,
+                         block_size=8, prefill_chunk=8)
+    engine.submit(np.arange(5), 6, seed=0)
+    with profile_trace(str(tmp_path)):
+        for _ in range(4):
+            if engine._unread is not None and engine._unread.rows:
+                jax.block_until_ready(engine._unread.out)
+                time.sleep(0.01)
+            engine.step()
+    decodes = [s[3] for s in _read_events(str(tmp_path), profiling.PREFIX)
+               if s[0] == "tpu_ddp.serve.decode"]
+    assert decodes[0]["ahead"] == 0 and "dry" not in decodes[0]
+    assert [d["dry"] for d in decodes[1:]] == [1, 1, 1]
+    assert engine.metrics.counters["serve_decode_dry"] == 3
+    assert engine.metrics.counters["serve_decode_ahead"] == 3
+
+
+@pytest.mark.parametrize("ready, want", [(True, 1), (False, 0)])
+def test_lm_train_step_says_whether_the_step_before_was_done(
+        tmp_path, ready, want):
+    from types import SimpleNamespace
+
+    trainer = LMTrainer(_lm(), make_mesh(jax.devices()[:1]))
+    state = trainer.init_state(seed=2)
+    x, y = trainer.put_batch(*make_lm_batch(np.zeros((2, 33), np.int64)))
+    with profile_trace(str(tmp_path)):
+        state, loss = trainer.train_step(state, x, y)
+        jax.block_until_ready(loss)
+        trainer._last_loss = SimpleNamespace(is_ready=lambda: ready)
+        trainer.train_step(state, x, y)
+    steps = [s[3] for s in _read_events(str(tmp_path), profiling.PREFIX)
+             if s[0] == "tpu_ddp.lm.train_step"]
+    assert steps == [{"step": 0}, {"step": 1, "dry": want}]

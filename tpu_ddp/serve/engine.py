@@ -61,6 +61,7 @@ round trip in one call.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import itertools
@@ -110,7 +111,9 @@ from tpu_ddp.utils.profiling import (
     SERVE_DECODE,
     SERVE_FEED,
     SERVE_PREFILL,
+    TALLY_S,
     burst,
+    mark,
     program,
     span,
 )
@@ -729,6 +732,9 @@ class ServeEngine:
         # sampled tokens on the device, which the next step feeds from.
         self._unread: _Unread | None = None
         self._sampled = jnp.zeros(self.num_slots, jnp.int32)
+        # seconds this step spent blocked in fetch; when the next tally
+        self._fetch_s = 0.0
+        self._tally_due = 0.0
         # Weight streaming (tpu_ddp/publish/): the served version id
         # and the subscriber that advances it. ``swap_params`` is the
         # ONLY mutation path for ``self.params`` after construction —
@@ -1022,13 +1028,46 @@ class ServeEngine:
         (slots empty faster than they refill) and the window would run
         at a fraction of its width. Matching the budgets keeps bank
         occupancy at its k=0 level."""
+        t0 = time.perf_counter()
+        if t0 >= self._tally_due:
+            self._tally_due = t0 + TALLY_S
+            mark("tpu_ddp.serve.tally", self._tally)
         self._step_n += 1
-        with burst(self._step_n), \
-                span("tpu_ddp.serve.step", n=self._step_n,
-                     queue=len(self.sched.queue), live=self.sched.live,
-                     blocks_in_use=(self.pool.total_usable
-                                    - self.pool.free_count)):
-            return self._step()
+        self._fetch_s = 0.0
+        with burst(self._step_n), span("tpu_ddp.serve.step"):
+            did = self._step()
+        wall_ms = (time.perf_counter() - t0) * 1e3
+        fetch_ms = self._fetch_s * 1e3
+        self.metrics.observe("serve_host_busy_ms", wall_ms - fetch_ms)
+        self.metrics.observe("serve_fetch_wait_ms", fetch_ms)
+        self.metrics.observe("serve_kv_blocks_in_use",
+                             self.pool.total_usable - self.pool.free_count)
+        return did
+
+    def _tally(self) -> dict:
+        """The counts of a ``serve.tally``: running totals since the
+        engine was built, out of its ``MetricsLogger``."""
+        c, g = self.metrics.counters, self.metrics.gauges
+        empty = {"count": 0, "total": 0.0}
+
+        def gauge(name):
+            return g.get(name, empty)
+
+        return {
+            "steps": gauge("serve_host_busy_ms")["count"],
+            "decode_steps": gauge("serve_decode_rows")["count"],
+            "decode_ahead": c.get("serve_decode_ahead", 0),
+            "dry_steps": c.get("serve_decode_dry", 0),
+            "decode_rows": gauge("serve_decode_rows")["total"],
+            "context_tokens": gauge("serve_decode_context_tokens")["total"],
+            "prefill_chunks": gauge("serve_prefill_tokens")["count"],
+            "prefill_tokens": gauge("serve_prefill_tokens")["total"],
+            "kv_blocks_in_use": gauge("serve_kv_blocks_in_use")["total"],
+            "kv_blocks_usable": self.pool.total_usable,
+            "host_busy_ms": gauge("serve_host_busy_ms")["total"],
+            "fetch_wait_ms": gauge("serve_fetch_wait_ms")["total"],
+            "queue_depth": gauge("serve_queue_depth")["total"],
+        }
 
     def _step(self) -> bool:
         with span("tpu_ddp.serve.schedule"):
@@ -1069,6 +1108,12 @@ class ServeEngine:
         before = self._unread
         mine = _Unread(self.param_version)
         did = before is not None
+        # ahead: the decode step before this one is still unread (a final
+        # chunk's first token alone does not count); dry: and the device
+        # had finished it before this step dispatched anything
+        # (is_ready does not wait)
+        ahead = int(did and bool(before.rows))
+        dry = int(ahead and before.out[0].is_ready())
 
         budget = self.spec_k + 1 if self.spec_k > 0 else 1
         for chunk in range(budget):
@@ -1090,20 +1135,17 @@ class ServeEngine:
             dslots = self.sched.decode_slots()
         if dslots:
             did = True
-            # ahead: the decode step before this one is still unread
-            # (a final chunk's first token alone does not count)
-            ahead = int(before is not None and bool(before.rows))
             if self.spec_k == 0:
                 self.metrics.inc("serve_decode_ahead" if ahead
                                  else "serve_decode_at_rest")
             context = sum(self.sched.slots[i].length
                           + self.sched.slots[i].ahead for i in dslots)
-            # every step's, where the span's counts are a burst's only
             self.metrics.observe("serve_decode_rows", len(dslots))
             self.metrics.observe("serve_decode_context_tokens", context)
-            with span("tpu_ddp.serve.decode", slots=len(dslots),
-                      context_tokens=context,
-                      ahead=ahead,
+            if dry:
+                self.metrics.inc("serve_decode_dry")
+            with span("tpu_ddp.serve.decode", context_tokens=context,
+                      ahead=ahead, **({"dry": dry} if ahead else {}),
                       state_slots=len(dslots) if self.state.arrays
                       else 0):
                 if self.spec_k > 0:
@@ -1224,6 +1266,7 @@ class ServeEngine:
         s = self.sched.slots[pi]
         start = s.prefill_done
         end = min(start + self.prefill_chunk, int(s.request.prompt.size))
+        self.metrics.observe("serve_prefill_tokens", end - start)
         with span("tpu_ddp.serve.prefill", rid=s.request.rid,
                   tokens=end - start, start=start,
                   final=int(end >= s.request.prompt.size),
@@ -1413,6 +1456,17 @@ class ServeEngine:
         if before is not None:
             self._harvest(before)
 
+    @contextlib.contextmanager
+    def _fetching(self):
+        """A blocking read of samples: ``serve.decode.fetch``, its
+        seconds added to the step's wait."""
+        t = time.perf_counter()
+        try:
+            with span("tpu_ddp.serve.decode.fetch"):
+                yield
+        finally:
+            self._fetch_s += time.perf_counter() - t
+
     def _harvest(self, unread: _Unread) -> None:
         """Read one step's samples back and hand them out in the order
         the synchronous engine did: the first tokens of its final
@@ -1428,7 +1482,7 @@ class ServeEngine:
         which were freed or scrubbed in stream order after it, and its
         sample is dropped (DESIGN.md §19)."""
         if unread.firsts:
-            with span("tpu_ddp.serve.decode.fetch"):
+            with self._fetching():
                 firsts = [(int(tok), float(lp))
                           for _, _, tok, lp in unread.firsts]
             with span("tpu_ddp.serve.decode.emit"):
@@ -1437,7 +1491,7 @@ class ServeEngine:
                     self._emit(i, tok, lp, unread.version)
         if not unread.rows:
             return
-        with span("tpu_ddp.serve.decode.fetch"):
+        with self._fetching():
             toks, lps, bad = map(np.asarray, unread.out)
         with span("tpu_ddp.serve.decode.emit"):
             for i, s in unread.rows.items():
@@ -1507,7 +1561,7 @@ class ServeEngine:
                 jnp.asarray(last), jnp.asarray(temps),
                 jnp.asarray(seeds), jnp.asarray(limits))
             self.pool.commit(k, v)
-        with span("tpu_ddp.serve.decode.fetch"):
+        with self._fetching():
             drafted, toks = np.asarray(drafted), np.asarray(toks)
             lps, bad = np.asarray(lps), np.asarray(bad)
         with span("tpu_ddp.serve.decode.emit"):

@@ -140,11 +140,18 @@ class _MeshTrainer:
         with span("tpu_ddp.lm.put_batch", tokens=np.size(inputs)):
             return self._put_batch(inputs, targets)
 
+    _last_loss = None   # the loss of the step before, on the device
+
     def train_step(self, state: LMTrainState, inputs, targets):
-        with span("tpu_ddp.lm.train_step", step=state.step):
+        # dry: the step before was already done (is_ready, which does
+        # not wait), so the device had run out of work
+        dry = {} if self._last_loss is None \
+            else {"dry": int(self._last_loss.is_ready())}
+        with span("tpu_ddp.lm.train_step", step=state.step, **dry):
             params, opt_state, loss = self._train_step(
                 state.params, state.opt_state, inputs, targets,
                 *self._extra_args(state))
+        self._last_loss = loss
         return LMTrainState(params, opt_state, state.step + 1), loss
 
     def lower_train_step(self, state: LMTrainState, inputs, targets):

@@ -45,8 +45,19 @@ PREFIX = "tpu_ddp."
 # name -> (layer as in PERF.md section 3, what the span covers, counts)
 SPANS = {
     "tpu_ddp.serve.step": (
-        "serving", "all of ServeEngine.step()",
-        ("n", "queue", "live", "blocks_in_use")),
+        "serving", "all of ServeEngine.step() but its tally", ()),
+    "tpu_ddp.serve.tally": (
+        "serving", "a zero-width marker (mark) before serve.step at the "
+        "first step after every TALLY_S of host clock, burst or not: the "
+        "engine's running totals since it was built, out of its "
+        "MetricsLogger (GAUGES, serve_decode_ahead, serve_decode_dry), "
+        "each summed over the steps, decode steps or chunks it names, "
+        "and the pool's usable blocks; two tallies differ by the sums "
+        "over every step between them",
+        ("steps", "decode_steps", "decode_ahead", "dry_steps",
+         "decode_rows", "context_tokens", "prefill_chunks",
+         "prefill_tokens", "kv_blocks_in_use", "kv_blocks_usable",
+         "host_busy_ms", "fetch_wait_ms", "queue_depth")),
     "tpu_ddp.serve.schedule": (
         "serving", "chaos / subscriber hooks, deadline shedding, "
         "sched.admit(), and each pick of a prefill slot or of the decode "
@@ -67,10 +78,15 @@ SPANS = {
         "harvest of the step BEFORE it. ahead: 1 where that step's "
         "decode rows were still unread at the dispatch, 0 where the "
         "engine was at rest (at spec_k == 0 also the counters "
-        "serve_decode_ahead / serve_decode_at_rest). state_slots: the "
-        "slots whose recurrent state the step advances (its rows, for a "
-        "model that keeps such state; else 0)",
-        ("slots", "context_tokens", "ahead", "state_slots")),
+        "serve_decode_ahead / serve_decode_at_rest). dry, only where "
+        "ahead: 1 where that step's samples were already on the device "
+        "(is_ready) before the step dispatched anything (its chunk, if "
+        "it has one, goes first), so the device had run out of work "
+        "before the host gave it more; 0 where they were not (also the "
+        "counter serve_decode_dry). state_slots: the slots whose "
+        "recurrent state the step advances (its rows, for a model that "
+        "keeps such state; else 0)",
+        ("context_tokens", "ahead", "dry", "state_slots")),
     "tpu_ddp.serve.decode.tables": (
         "serving", "ensure_blocks, tier residency, the numpy tables "
         "and vectors", ()),
@@ -93,7 +109,11 @@ SPANS = {
         "arrays to sharded device arrays", ("tokens",)),
     "tpu_ddp.lm.train_step": (
         "train loop", "the dispatch of one jitted LM step (not the wait "
-        "for its loss: that is the caller's)", ("step",)),
+        "for its loss: that is the caller's). dry, from the second step "
+        "of a trainer on: 1 where the step before's loss was already on "
+        "the device (is_ready) as the span opened, so the device had run "
+        "out of work before the host gave it more; else 0",
+        ("step", "dry")),
     "tpu_ddp.train.data_next": (
         "host data path", "next() of the batch stream train_epoch "
         "iterates", ()),
@@ -218,21 +238,35 @@ SCOPES = {
 
 # Gauges the serving engine keeps in its ``MetricsLogger`` whether or not
 # a trace is being taken (``metrics.gauges[name]``: count, total, max,
-# last). Not in a trace: a benchmark's runner reads them around its
-# window. The two per-step ones repeat counts of the ``serve.decode``
-# span for EVERY step, where the spans are a burst's only (below).
+# last). A ``serve.tally`` puts their running counts and totals into the
+# trace, for EVERY step where the spans are a burst's only (below).
 GAUGES = {
     "serve_kv_pool_bytes": "set once: what the paged K/V pool holds on "
                            "the device",
     "serve_state_pool_bytes": "set once: what the state pool holds (0 "
                               "for a model without recurrent state)",
-    "serve_decode_rows": "every decode step: its rows (the span's "
-                         "slots; for a model with recurrent state also "
-                         "its state_slots)",
+    "serve_decode_rows": "every decode step: its rows (for a model with "
+                         "recurrent state also its state_slots)",
     "serve_decode_context_tokens": "every decode step: the K/V "
                                    "positions it reads (the span's "
                                    "context_tokens)",
+    "serve_prefill_tokens": "every prefill chunk: its prompt tokens "
+                            "(the span's tokens)",
+    "serve_queue_depth": "every step: the requests waiting for a slot",
+    "serve_slot_occupancy": "every step: the share of slots held",
+    "serve_kv_blocks_in_use": "every step: the K/V pool's blocks held",
+    "serve_host_busy_ms": "every step: its wall time less what it spent "
+                          "blocked in serve.decode.fetch, the host's own "
+                          "work",
+    "serve_fetch_wait_ms": "every step: what it spent blocked in "
+                           "serve.decode.fetch",
+    "serve_ttft_ms": "every request's first token: milliseconds since "
+                     "it was submitted",
 }
+
+# A ``serve.tally`` marker goes into the trace at the first engine step
+# after every TALLY_S seconds of host clock.
+TALLY_S = 0.5
 
 
 # ``ServeEngine.step`` annotates its steps in bursts: the first
@@ -243,6 +277,15 @@ BURST_STEPS = 24
 BURST_EVERY = 240
 
 _thread = threading.local()     # .quiet: this thread's spans are dropped
+
+
+def mark(name: str, counts) -> None:
+    """A zero-width span ``name`` with the counts ``counts()`` returns,
+    recorded whether or not :func:`burst` left the step out. Costs one
+    check while no profiler session is open: ``counts`` is not called."""
+    if jax.profiler.TraceAnnotation.is_enabled():
+        with jax.profiler.TraceAnnotation(name, **counts()):
+            pass
 
 
 def span(name: str, **counts):
