@@ -486,6 +486,8 @@ FROM_LOGGER = {
     "host_busy_ms": ("serve_host_busy_ms", "total"),
     "fetch_wait_ms": ("serve_fetch_wait_ms", "total"),
     "queue_depth": ("serve_queue_depth", "total"),
+    "selected_tokens": ("serve_decode_selected_tokens", "total"),
+    "index_scored_tokens": ("serve_decode_index_tokens", "total"),
 }
 
 

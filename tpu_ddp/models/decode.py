@@ -60,18 +60,43 @@ def check_decodable(model) -> None:
             "canonical checkpoint path")
 
 
+# The same features against a model that caches an index key per
+# position in a pool of its own beside the K/V (``model.index_dim``: a
+# learned sparse attention's indexer, models/sparse_moe.py).
+_INDEX_REFUSALS = {
+    "prefix_cache": "a shared prefix's K/V pages can be adopted, its "
+                    "index-key pages are not registered with them",
+    "kv_tiers": "the tiered step programs carry no index-key pool",
+    "spec_k": "the fused speculative step attends every position and "
+              "writes no index key",
+    "cp_prefill": "the context-parallel chunk attends every position "
+                  "and writes no index key",
+    "decode_quant": "ops/quant.quantize_params knows the dense block's "
+                    "matrices only",
+    "mesh": "the index-key pool and the expert layer have no sharding "
+            "rules",
+    "disagg": "the edge ships K/V blocks only; the index keys of a "
+              "finished prefill would stay behind",
+}
+
+
 def check_state_servable(model, **features) -> None:
     """Refuse, with the reason, a model that keeps recurrent state
-    (``model.state_shapes``) under a serving feature that cannot carry
-    it. ``features``: keys of ``_STATE_REFUSALS``, true where the caller
-    has the feature in use. One place, so that such a model is never
-    half served."""
+    (``model.state_shapes``) or an index-key pool (``model.index_dim``)
+    under a serving feature that cannot carry it. ``features``: keys of
+    ``_STATE_REFUSALS``, true where the caller has the feature in use.
+    One place, so that such a model is never half served."""
     on = [k for k, v in features.items() if v]
     if on and model.state_shapes(1):
         raise ValueError(
             f"{model.name} keeps recurrent state per sequence, which "
             "these serving features cannot carry yet: " + "; ".join(
                 f"{k} ({_STATE_REFUSALS[k]})" for k in on))
+    if on and getattr(model, "index_dim", 0):
+        raise ValueError(
+            f"{model.name} caches an index key per position beside its "
+            "K/V, which these serving features cannot carry yet: "
+            + "; ".join(f"{k} ({_INDEX_REFUSALS[k]})" for k in on))
 
 
 def gated_mlp(model, y, w1, w2):
@@ -90,8 +115,9 @@ def mlp(model, blk, y):
 
     A model that says which experts it holds (``model.held``) runs the
     dropless expert layer over its share (parallel/moe.py
-    ``dropless_moe``, scope ``moe``) plus the shared gated MLP (scope
-    ``shared_mlp``), summed in float32.
+    ``dropless_moe``, scope ``moe``) plus, where it has one
+    (``model.shared_ff``), the shared gated MLP (scope ``shared_mlp``),
+    summed in float32.
 
     Dense models run the two qdot matmuls (fp or fused int8). MoE
     models run the routed layer (tpu_ddp/parallel/moe.py) with the
@@ -116,6 +142,8 @@ def mlp(model, blk, y):
             out = dropless_moe(
                 y.reshape(-1, y.shape[-1]), blk["router"], blk["w1"],
                 blk["w2"], top_k=model.top_k, held=model.held)
+        if not model.shared_ff:
+            return out.reshape(y.shape).astype(cd)
         with jax.named_scope("shared_mlp"):
             out = out.reshape(y.shape) + gated_mlp(
                 model, y, blk["shared_w1"], blk["shared_w2"])
@@ -164,6 +192,26 @@ def attend_cached(model, q, ck, cv, q_pos):
     mask = k_pos[None, None, None, None, :] \
         > qp[:, None, None, :, None]
     scores = jnp.where(mask, _NEG_INF, scores)
+    p = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bkgqs,bskd->bqkgd", p, cv.astype(jnp.float32),
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, lq, h, hd).astype(q.dtype)
+
+
+def attend_masked(model, q, ck, cv, keep):
+    """:func:`attend_cached`'s contraction with the positions given by a
+    mask: ``keep`` (B, Lq, S), true where query ``q[:, i]`` may read row
+    ``s`` of ``ck`` / ``cv`` (B, S, KV, hd). A row that is not kept gets
+    an exact zero weight. The sparse mixer's two forms attend through it
+    (serve/engine.py): a decode step over the rows it gathered, a prefill
+    chunk over the gathered view with the selection as the mask."""
+    scale = attn_scale(model)
+    b, lq, h, hd = q.shape
+    kv = ck.shape[2]
+    qg = q.reshape(b, lq, kv, h // kv, hd)
+    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, ck,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(keep[:, None, None], scores, _NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bqkgd", p, cv.astype(jnp.float32),
                      preferred_element_type=jnp.float32)

@@ -49,6 +49,16 @@ beside it, donated through both step programs and updated in place like
 the pool (docs/DESIGN.md §31 has the three rules that take the place of
 the null block there).
 
+A model may attend, in some layers, over a learned selection of the
+cached positions (``"sparse_attention"``, models/sparse_moe.py): those
+layers also cache an index key per position, in the pool's ``index``
+pages at the same block ids, donated through both programs beside the
+state's arrays. A decode step scores every position of the slot, keeps
+the top ``index_topk`` and gathers only their K/V rows
+(:func:`sparse_step`); a prefill chunk scores its queries against the
+gathered view and attends over it with the selection as the mask
+(:func:`sparse_chunk`). docs/DESIGN.md §32.
+
 Sampling is per-request and stateless (decode.sample_token): keyed by
 (request seed, absolute position), so a request replayed after
 cancellation or across engines reproduces its tokens exactly.
@@ -77,6 +87,7 @@ from jax import lax
 
 from tpu_ddp.models.decode import (
     attend_cached,
+    attend_masked,
     attn_scale,
     block_finish,
     check_decodable,
@@ -88,6 +99,11 @@ from tpu_ddp.models.decode import (
     ssm_mix,
 )
 from tpu_ddp.models.hybrid import advance_state
+from tpu_ddp.models.sparse_moe import (
+    index_scores,
+    select_top,
+    selection_mask,
+)
 from tpu_ddp.ops.pallas import paged_attention, ssm_state_step
 from tpu_ddp.serve.kv_pool import (
     PagedKVPool,
@@ -265,6 +281,117 @@ def state_chunk(model, blk, x, state, si: int, slot, fresh, n_valid):
     return mlp_half(model, blk, x), state
 
 
+def index_write(index, xi: int, bidx, off, keys, run: bool = False):
+    """Write the index keys ``keys`` (n, Di) into sparse layer ``xi``'s
+    pages at ``(bidx, off)``: a key's ``Di`` lanes of its page's row
+    (serve/kv_pool.py ``index_page``), one windowed scatter. ``run``: the
+    keys are of consecutive positions from a row's first, a whole number
+    of rows (a prefill chunk), and are written a row at a time, as K/V
+    are."""
+    n, di = keys.shape
+    per_row = index.shape[-1] // di
+    if run:
+        return index.at[xi, bidx[::per_row], off[::per_row] // per_row].set(
+            keys.reshape(n // per_row, -1).astype(index.dtype))
+    at = jnp.stack([jnp.full_like(bidx, xi), bidx, off // per_row,
+                    off % per_row * di], axis=-1)
+    dims = lax.ScatterDimensionNumbers(
+        update_window_dims=(1,), inserted_window_dims=(0, 1, 2),
+        scatter_dims_to_operand_dims=(0, 1, 2, 3))
+    return lax.scatter(index, at, keys.astype(index.dtype), dims,
+                       mode=lax.GatherScatterMode.FILL_OR_DROP)
+
+
+def index_view(index, xi: int, tables):
+    """Sparse layer ``xi``'s index-key pages gathered through ``tables``
+    (S, BPS): (S, rows, width), the keys of positions ``0..BPS*block_size
+    - 1`` in order, as :func:`sparse_moe.index_scores` reads them."""
+    pages = index[xi, tables]
+    return pages.reshape(pages.shape[0], -1, pages.shape[-1])
+
+
+def sparse_step(model, blk, x, pool_k, pool_v, index, li: int, xi: int,
+                bidx, off, tables, lengths, block_size: int):
+    """A sparse-attention layer of the decode step, for the whole bank:
+    the new K/V and index key written at ``(bidx, off)``; under the scope
+    ``attn/index`` the indexer's projections, every position of each
+    slot's table scored against the slot's query (the index pages
+    gathered through ``tables``) and the top ``index_topk`` at positions
+    <= ``lengths`` kept; under ``attn/sparse`` only those positions' K/V
+    rows gathered from the pool and attended. ``li`` / ``xi``: the
+    layer's place among the K/V and the index pages. Returns (x, pool_k,
+    pool_v, index)."""
+    pos = lengths[:, None]
+    with jax.named_scope("attn"):
+        y = model.norm(x, blk["ln1"])
+        q, k, v = model.qkv_proj(blk, y, pos)
+        with jax.named_scope("index"):
+            qi, ki, w = model.index_proj(blk, y, pos)
+        pool_k, pool_v = kv_write(pool_k, pool_v, li, bidx, off, k[:, 0],
+                                  v[:, 0])
+        with jax.named_scope("kv_write"):
+            index = index_write(index, xi, bidx, off, ki[:, 0])
+        with jax.named_scope("index"):
+            sel, keep = select_top(
+                model, index_scores(model, qi, w,
+                                    index_view(index, xi, tables)), pos)
+        with jax.named_scope("sparse"):
+            sel = sel[:, 0]                                     # (S, K)
+            at = (jnp.take_along_axis(tables, sel // block_size, axis=1),
+                  sel % block_size)
+            shape = sel.shape + (model.kv_heads, model.head_dim)
+            ck = pool_k[li, at[0], at[1]].reshape(shape)
+            cv = pool_v[li, at[0], at[1]].reshape(shape)
+            o = attend_masked(model, q, ck, cv, keep)
+    return block_finish(model, blk, x, o), pool_k, pool_v, index
+
+
+def sparse_chunk(model, blk, x, pool_k, pool_v, index, li: int, xi: int,
+                 blk_idx, off, table, p):
+    """A sparse-attention layer of a prefill chunk ``x`` (1, C, dm) at
+    positions ``p``: the chunk's K/V and index keys written, the slot's
+    pages gathered (under ``kv_gather`` as every chunk does), every query
+    of the chunk scored against every position and its selection made
+    under ``attn/index`` (the scores in parts, float32 within
+    ``PREFILL_SCORES_BYTES``; the selection for the whole chunk at once),
+    and the chunk attended over the gathered view in :func:`attend_chunk`'s
+    parts with its selection as the mask. Returns (x, pool_k, pool_v,
+    index)."""
+    C = x.shape[1]
+    with jax.named_scope("attn"):
+        y = model.norm(x, blk["ln1"])
+        q, k, v = model.qkv_proj(blk, y, p)
+        with jax.named_scope("index"):
+            qi, ki, w = model.index_proj(blk, y, p)
+        pool_k, pool_v, ck, cv = paged_kv(model, pool_k, pool_v, li, blk_idx,
+                                          off, k[0], v[0], table[None])
+        with jax.named_scope("kv_write"):
+            # a chunk starts at a multiple of its length
+            index = index_write(index, xi, blk_idx, off, ki[0],
+                                run=C % (index.shape[-1] // ki.shape[-1]) == 0)
+        with jax.named_scope("kv_gather"):
+            # one key a row, in the order of the positions: the chunk's
+            # float32 scores then come out in that order, part by part
+            keys = index_view(index, xi, table[None]).reshape(
+                1, -1, model.index_dim)
+        T = ck.shape[1]
+        with jax.named_scope("index"):
+            n = C // _parts(C, 4 * model.index_heads * T)
+            scores = lax.map(
+                lambda a: index_scores(model, a[0][None], a[1][None],
+                                       keys)[0],
+                (qi[0].reshape((C // n, n) + qi.shape[2:]),
+                 w[0].reshape((C // n, n) + w.shape[2:])))
+            keep = selection_mask(model, scores.reshape(1, C, T), p[None])
+        n = C // _parts(C, 4 * q.shape[2] * T)
+        o = lax.map(
+            lambda a: attend_masked(model, a[0][None], ck, cv, a[1][None])[0],
+            (q[0].reshape((C // n, n) + q.shape[2:]),
+             keep[0].reshape(C // n, n, T)))
+    return (block_finish(model, blk, x, o.reshape(q.shape)), pool_k, pool_v,
+            index)
+
+
 # What one ``attend_cached`` call of a prefill chunk may hold as float32
 # scores, (heads, queries, keys): half of the 128 MiB of on-chip memory
 # beside a v5e's HBM. Whether the compiler keeps the scores there or in
@@ -276,6 +403,17 @@ def state_chunk(model, blk, x, state, si: int, slot, fresh, n_valid):
 PREFILL_SCORES_BYTES = 64 << 20
 
 
+def _parts(C: int, row_bytes: int) -> int:
+    """Into how many equal parts a chunk of ``C`` queries is cut so that
+    a part's float32 scores, ``row_bytes`` a query, stay within
+    ``PREFILL_SCORES_BYTES``: halved while they do not and C allows."""
+    parts = 1
+    while C * row_bytes > parts * PREFILL_SCORES_BYTES \
+            and C % (2 * parts) == 0:
+        parts *= 2
+    return parts
+
+
 def attend_chunk(model, q, ck, cv, p):
     """``attend_cached`` for a prefill chunk's queries ``q`` (1, C, H,
     hd) at positions ``p`` (C,), over equal parts of the chunk where the
@@ -283,11 +421,7 @@ def attend_chunk(model, q, ck, cv, p):
     row's result does not depend on the rows beside it, so the parts
     give what the whole gives."""
     C = q.shape[1]
-    scores_bytes = 4 * q.shape[0] * q.shape[2] * C * ck.shape[1]
-    parts = 1
-    while (scores_bytes > parts * PREFILL_SCORES_BYTES
-           and C % (2 * parts) == 0):
-        parts *= 2
+    parts = _parts(C, 4 * q.shape[0] * q.shape[2] * ck.shape[1])
     if parts == 1:
         return attend_cached(model, q, ck, cv, p)
     n = C // parts
@@ -315,7 +449,9 @@ def decode_bank(model, block_size: int, params, pool_k, pool_v, tables,
     ``state``: the state pool's arrays, for a model with layers that keep
     recurrent state; it is then returned last, advanced for the slots
     that have a row. Those are the slots whose table row is not all null:
-    an idle slot's, and one's between two prefill chunks, is zeros."""
+    an idle slot's, and one's between two prefill chunks, is zeros. For a
+    model with sparse-attention layers it holds the index-key pages under
+    ``"index"`` (:func:`sparse_step`), returned with the new keys."""
     cd = model.compute_dtype
     in_place = paged_attention.supports(model.head_dim, block_size,
                                         pool_k.dtype, cd)
@@ -326,11 +462,18 @@ def decode_bank(model, block_size: int, params, pool_k, pool_v, tables,
         tables, (lengths // block_size)[:, None], axis=1)[:, 0]
     off = lengths % block_size
     active = tables[:, 0] != PagedKVPool.NULL_BLOCK
-    li = si = 0     # the layer's place among those of its kind
+    li = si = xi = 0     # the layer's place among those of its kind
     for blk, mixer in zip(params["blocks"], model.mixers):
-        if mixer != "attention":
+        if mixer == "mamba":
             x, state = state_step(model, blk, x, state, si, active)
             si += 1
+            continue
+        if mixer == "sparse_attention":
+            x, pool_k, pool_v, index = sparse_step(
+                model, blk, x, pool_k, pool_v, state["index"], li, xi, bidx,
+                off, tables, lengths, block_size)
+            state = dict(state, index=index)
+            li, xi = li + 1, xi + 1
             continue
         with jax.named_scope("attn"):
             q, k, v = project_qkv(model, blk, x, pos)
@@ -366,6 +509,13 @@ def decode_bank(model, block_size: int, params, pool_k, pool_v, tables,
     return pool_k, pool_v, toks, lps, bad, state
 
 
+def _carries_more(model) -> bool:
+    """Do the step programs take a third donated argument beside the K/V
+    pool: the state pool's arrays, the index-key pages, or both."""
+    return bool(model.state_shapes(1)) or bool(getattr(model, "index_dim",
+                                                       0))
+
+
 # Both step builders are memoized on (model, block_size, blocks_per_seq)
 # — model is a frozen dataclass, so the key is by-value. Every engine
 # with the same cache geometry shares ONE compiled program; sweep
@@ -377,7 +527,7 @@ def _build_decode_step(model, block_size: int, blocks_per_seq: int):
     ``lengths`` (S,) cache positions written so far, ``last_tokens``
     (S,) the pending token each slot feeds at position ``lengths``."""
 
-    if model.state_shapes(1):
+    if _carries_more(model):
         # the state pool's arrays ride beside the K/V pool's, donated
         @program(SERVE_DECODE)
         def step(params, pool_k, pool_v, state, tables, lengths,
@@ -410,7 +560,8 @@ def _build_prefill_step(model, block_size: int, blocks_per_seq: int):
     For a model with layers that keep recurrent state the program also
     takes the state pool's arrays (donated, returned last) and the
     ``slot`` the chunk belongs to: the state has no block table to find
-    it by."""
+    it by. A model with sparse-attention layers passes its index-key
+    pages the same way (``state["index"]``)."""
 
     def chunk(params, pool_k, pool_v, table, tokens, start, prompt_len,
               temp, seed, state=None, slot=None):
@@ -422,13 +573,20 @@ def _build_prefill_step(model, block_size: int, blocks_per_seq: int):
         off = p % block_size
         with jax.named_scope("embed"):
             x = model.embed(params, tokens)                   # (1, C, dm)
-        li = si = 0
+        li = si = xi = 0
         for blkp, mixer in zip(params["blocks"], model.mixers):
-            if mixer != "attention":
+            if mixer == "mamba":
                 x, state = state_chunk(
                     model, blkp, x, state, si, slot, start == 0,
                     jnp.clip(prompt_len - start, 0, C))
                 si += 1
+                continue
+            if mixer == "sparse_attention":
+                x, pool_k, pool_v, index = sparse_chunk(
+                    model, blkp, x, pool_k, pool_v, state["index"], li, xi,
+                    blk_idx, off, table, p)
+                state = dict(state, index=index)
+                li, xi = li + 1, xi + 1
                 continue
             with jax.named_scope("attn"):
                 q, k, v = project_qkv(model, blkp, x, p)
@@ -447,7 +605,7 @@ def _build_prefill_step(model, block_size: int, blocks_per_seq: int):
             return pool_k, pool_v, tok, lp
         return pool_k, pool_v, tok, lp, state
 
-    if model.state_shapes(1):
+    if _carries_more(model):
         @program(SERVE_PREFILL)
         def step(params, pool_k, pool_v, state, table, tokens, start,
                  prompt_len, temp, seed, slot):
@@ -613,8 +771,7 @@ class ServeEngine:
         self.tenant_counts: dict[str, dict[str, int]] = {}
         self.metrics = metrics if metrics is not None \
             else MetricsLogger(None)
-        self.metrics.observe("serve_kv_pool_bytes",
-                             self.pool.k.nbytes + self.pool.v.nbytes)
+        self.metrics.observe("serve_kv_pool_bytes", self.pool.nbytes)
         self.metrics.observe("serve_state_pool_bytes", self.state.nbytes)
         self._decode = _build_decode_step(model, self.block_size,
                                           self.blocks_per_seq)
@@ -805,13 +962,23 @@ class ServeEngine:
             sds((1, self.prefill_chunk), jnp.int32),
             sds((), jnp.int32), sds((), jnp.int32),
             sds((), jnp.float32), sds((), jnp.int32),
-            *((sds((), jnp.int32),) if self.state.arrays else ()))
+            *((sds((), jnp.int32),) if self._state_args() else ()))
 
     def _state_args(self) -> tuple:
-        """The state pool's arrays as the step programs take them:
-        one more donated argument after the K/V pool's, or none for a
-        model that keeps no recurrent state."""
-        return (self.state.arrays,) if self.state.arrays else ()
+        """The state pool's arrays and the index-key pages as the step
+        programs take them: one more donated argument after the K/V
+        pool's, or none for a model that keeps neither."""
+        arrays = dict(self.state.arrays)
+        if self.pool.index is not None:
+            arrays["index"] = self.pool.index
+        return (arrays,) if arrays else ()
+
+    def _commit_state(self, arrays=None) -> None:
+        """Store what a step program returned for :meth:`_state_args`."""
+        if arrays is not None and self.pool.index is not None:
+            arrays = dict(arrays)
+            self.pool.index = arrays.pop("index")
+        self.state.commit(arrays or None)
 
     def lower_spec_step(self):
         """``jit.lower`` the fused speculative step (same audit
@@ -1067,6 +1234,9 @@ class ServeEngine:
             "host_busy_ms": gauge("serve_host_busy_ms")["total"],
             "fetch_wait_ms": gauge("serve_fetch_wait_ms")["total"],
             "queue_depth": gauge("serve_queue_depth")["total"],
+            "selected_tokens": gauge("serve_decode_selected_tokens")["total"],
+            "index_scored_tokens":
+            gauge("serve_decode_index_tokens")["total"],
         }
 
     def _step(self) -> bool:
@@ -1142,12 +1312,24 @@ class ServeEngine:
                           + self.sched.slots[i].ahead for i in dslots)
             self.metrics.observe("serve_decode_rows", len(dslots))
             self.metrics.observe("serve_decode_context_tokens", context)
+            selected = 0
+            if self.pool.index is not None:
+                # each row reads its positions 0..length (the new one
+                # included), of which attention keeps at most index_topk
+                top = self.model.index_topk
+                selected = sum(min(self.sched.slots[i].length
+                                   + self.sched.slots[i].ahead + 1, top)
+                               for i in dslots)
+                self.metrics.observe("serve_decode_selected_tokens",
+                                     selected)
+                self.metrics.observe("serve_decode_index_tokens",
+                                     context + len(dslots))
             if dry:
                 self.metrics.inc("serve_decode_dry")
             with span("tpu_ddp.serve.decode", context_tokens=context,
                       ahead=ahead, **({"dry": dry} if ahead else {}),
                       state_slots=len(dslots) if self.state.arrays
-                      else 0):
+                      else 0, selected_tokens=selected):
                 if self.spec_k > 0:
                     self._run_spec_step(dslots)
                 else:
@@ -1267,11 +1449,16 @@ class ServeEngine:
         start = s.prefill_done
         end = min(start + self.prefill_chunk, int(s.request.prompt.size))
         self.metrics.observe("serve_prefill_tokens", end - start)
+        # a sparse-attention model's indexer scores each query against
+        # its positions 0..p: sum of p + 1 over the chunk's prompt tokens
+        scored = ((end * (end + 1) - start * (start + 1)) // 2
+                  if self.pool.index is not None else 0)
         with span("tpu_ddp.serve.prefill", rid=s.request.rid,
                   tokens=end - start, start=start,
                   final=int(end >= s.request.prompt.size),
                   state_reset=int(bool(self.state.arrays)
-                                  and start == 0)):
+                                  and start == 0),
+                  index_scored_tokens=scored):
             self._prefill_chunk(pi, s, start, mine)
 
     def _prefill_chunk(self, pi: int, s, start: int,
@@ -1311,7 +1498,7 @@ class ServeEngine:
                 jnp.int32(start), jnp.int32(req.prompt.size),
                 jnp.float32(req.temperature), jnp.int32(req.seed),
                 *((jnp.int32(pi),) if state else ()))
-            self.state.commit(*state)
+            self._commit_state(*state)
         self.pool.commit(k, v)
         s.prefill_done = min(start + C, int(req.prompt.size))
         s.length = s.prefill_done
@@ -1429,7 +1616,7 @@ class ServeEngine:
                     jnp.asarray(tables), jnp.asarray(lengths),
                     d_last, jnp.asarray(temps),
                     jnp.asarray(seeds))
-                self.state.commit(*state)
+                self._commit_state(*state)
             self.pool.commit(k, v)
             mine.rows = dict(zip(dslots, slots))
             mine.out = (toks, lps, bad)

@@ -114,6 +114,20 @@ def rows(x):
     return x.reshape(x.shape[:-2] + (-1,))
 
 
+def index_page(block_size: int, dim: int) -> tuple:
+    """``(rows, width)`` of one block's page of index keys: ``block_size``
+    keys of ``dim`` values, ``width // dim`` of them side by side in a
+    row of up to 128 lanes. A key of 64 values alone in a row would fill
+    half of the lanes of a v5e's (8, 128) tile and leave the other half
+    as padding: the whole pool would take twice its bytes, and XLA:TPU
+    then copied it into another layout in every layer to make room
+    (more than half of a decode step at Keye-VL-2.0's widths)."""
+    per_row = min(block_size, max(1, 128 // dim))
+    if block_size % per_row:
+        per_row = 1
+    return block_size // per_row, per_row * dim
+
+
 def gather_view(pool, li: int, tables, model):
     """Layer ``li``'s pages gathered through ``tables`` (S, BPS) into
     the contiguous (S, BPS*block_size, KV, hd) view
@@ -208,7 +222,8 @@ class PagedKVPool:
         self.dtype = resolve_act_dtype(cache_dtype, model.compute_dtype)
         # Pages for the layers that keep K/V only: a layer whose mixer
         # keeps recurrent state has none (its state is in the StatePool).
-        self.layers = sum(m == "attention" for m in model.mixers)
+        self.layers = sum(m in ("attention", "sparse_attention")
+                          for m in model.mixers)
         page = (block_size, model.kv_heads * model.head_dim)
         # Hot buffers: at tiers == 1 the logical id IS the hot slot
         # (identity map, num_blocks slots) — the round-12 layout,
@@ -228,6 +243,18 @@ class PagedKVPool:
         shape = (self.layers, self.hbm_blocks) + page
         self.k = pin_committed(jnp.zeros(shape, self.dtype))
         self.v = pin_committed(jnp.zeros(shape, self.dtype))
+        # The index key a learned sparse attention caches per position
+        # (``model.index_dim``; None for any other model): pages of their
+        # own, ``(sparse layers, blocks) + index_page(...)``, at the same
+        # block ids, so the same tables, the same null block and the same
+        # reservation serve both. Updated in place through the step
+        # programs like ``k`` / ``v`` (the engine donates it beside them).
+        self.index = None
+        if getattr(model, "index_dim", 0):
+            self.index = pin_committed(jnp.zeros(
+                (sum(m == "sparse_attention" for m in model.mixers),
+                 self.hbm_blocks) + index_page(block_size, model.index_dim),
+                self.dtype))
         self.cold_k = self.cold_v = None
         self.cold_sk = self.cold_sv = None
         if tiers > 1:
@@ -712,6 +739,8 @@ class PagedKVPool:
             ids = jnp.asarray(np.asarray(blocks, np.int32))
             self.k = self.k.at[:, ids].set(0)
             self.v = self.v.at[:, ids].set(0)
+            if self.index is not None:
+                self.index = self.index.at[:, ids].set(0)
             return
         hot = [self._hot_slot[b] for b in blocks
                if self._tier[b] == "hot"]
@@ -733,6 +762,12 @@ class PagedKVPool:
                                        for a in self._spill[b])
 
     # ---- device state --------------------------------------------------
+
+    @property
+    def nbytes(self) -> int:
+        """What the pool holds on the device: K, V and index-key pages."""
+        return self.k.nbytes + self.v.nbytes + (
+            self.index.nbytes if self.index is not None else 0)
 
     def commit(self, k, v) -> None:
         """Store the jitted step's updated (hot) buffers (the old ones

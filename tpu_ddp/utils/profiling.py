@@ -57,7 +57,9 @@ SPANS = {
         ("steps", "decode_steps", "decode_ahead", "dry_steps",
          "decode_rows", "context_tokens", "prefill_chunks",
          "prefill_tokens", "kv_blocks_in_use", "kv_blocks_usable",
-         "host_busy_ms", "fetch_wait_ms", "queue_depth")),
+         "host_busy_ms", "fetch_wait_ms", "queue_depth",
+         # 0 but for a model with sparse-attention layers
+         "selected_tokens", "index_scored_tokens")),
     "tpu_ddp.serve.schedule": (
         "serving", "chaos / subscriber hooks, deadline shedding, "
         "sched.admit(), and each pick of a prefill slot or of the decode "
@@ -70,8 +72,12 @@ SPANS = {
         "pool.commit; the final chunk's first token stays on the device "
         "and is read with the step's decode rows. state_reset: 1 on a "
         "request's first chunk of a model that keeps recurrent state "
-        "(the slot's state starts from zero), else 0",
-        ("rid", "tokens", "start", "final", "state_reset")),
+        "(the slot's state starts from zero), else 0. "
+        "index_scored_tokens: the positions a sparse-attention model's "
+        "indexer scores as live, p + 1 summed over the chunk's prompt "
+        "positions p (0 for any other model)",
+        ("rid", "tokens", "start", "final", "state_reset",
+         "index_scored_tokens")),
     "tpu_ddp.serve.decode": (
         "serving", "one whole-bank decode step (plain or fused "
         "speculative): its dispatch and, in the plain engine, the "
@@ -85,8 +91,11 @@ SPANS = {
         "before the host gave it more; 0 where they were not (also the "
         "counter serve_decode_dry). state_slots: the slots whose "
         "recurrent state the step advances (its rows, for a model that "
-        "keeps such state; else 0)",
-        ("context_tokens", "ahead", "dry", "state_slots")),
+        "keeps such state; else 0). selected_tokens: the positions a "
+        "sparse-attention model's attention reads, min(length + 1, "
+        "index_topk) summed over the rows (0 for any other model)",
+        ("context_tokens", "ahead", "dry", "state_slots",
+         "selected_tokens")),
     "tpu_ddp.serve.decode.tables": (
         "serving", "ensure_blocks, tier residency, the numpy tables "
         "and vectors", ()),
@@ -227,6 +236,13 @@ SCOPES = {
                "operations are named ragged-dot-none and carry NO scope "
                "path: a reader counts those by that name",
     "shared_mlp": "inside mlp: the shared gated MLP, every token",
+    "index": "inside attn, serving, a sparse-attention layer: the "
+             "indexer's projections, the scores of every position of "
+             "the slot's table and the selection (the path attn/index; "
+             "in serve_prefill inside the loop over the chunk's parts)",
+    "sparse": "inside attn, serve_decode, a sparse-attention layer: the "
+              "gather of the selected positions' K/V rows and attention "
+              "over them (the path attn/sparse)",
     "head": "final LayerNorm and output head",
     "sample": "serving: token sampling and the non-finite check",
     "loss": "trainers: cross-entropy on the logits",
@@ -242,7 +258,7 @@ SCOPES = {
 # trace, for EVERY step where the spans are a burst's only (below).
 GAUGES = {
     "serve_kv_pool_bytes": "set once: what the paged K/V pool holds on "
-                           "the device",
+                           "the device, its index-key pages included",
     "serve_state_pool_bytes": "set once: what the state pool holds (0 "
                               "for a model without recurrent state)",
     "serve_decode_rows": "every decode step: its rows (for a model with "
@@ -252,6 +268,14 @@ GAUGES = {
                                    "context_tokens)",
     "serve_prefill_tokens": "every prefill chunk: its prompt tokens "
                             "(the span's tokens)",
+    "serve_decode_selected_tokens": "every decode step of a model with "
+                                    "sparse-attention layers: the "
+                                    "positions attention reads (the "
+                                    "span's selected_tokens)",
+    "serve_decode_index_tokens": "every decode step of a model with "
+                                 "sparse-attention layers: the live "
+                                 "positions the indexer scores, "
+                                 "context_tokens plus the rows",
     "serve_queue_depth": "every step: the requests waiting for a slot",
     "serve_slot_occupancy": "every step: the share of slots held",
     "serve_kv_blocks_in_use": "every step: the K/V pool's blocks held",
