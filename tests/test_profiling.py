@@ -65,10 +65,11 @@ def test_every_kernel_and_scope_name_is_in_its_table_and_used():
     assert set(kernels) == set(KERNELS)
     assert {f for fs in kernels.values() for f in fs} == {
         "flash_attention.py", "quant_matmul.py", "sgd.py", "bn_relu.py",
-        "paged_attention.py", "ssm_state_step.py", "grouped_matmul.py"}
+        "paged_attention.py", "ssm_state_step.py", "grouped_matmul.py",
+        "sparse_prefill_attention.py"}
     pallas = sum(p.read_text().count("pl.pallas_call(")
                  for p in (ROOT / "tpu_ddp/ops/pallas").glob("*.py"))
-    assert pallas == len(KERNELS) == 12
+    assert pallas == len(KERNELS) == 13
     scopes = _calls(re.compile(r'jax\.named_scope\("(\w+)"\)'))
     assert set(scopes) == set(SCOPES)
 
@@ -482,6 +483,8 @@ FROM_LOGGER = {
     "context_tokens": ("serve_decode_context_tokens", "total"),
     "prefill_chunks": ("serve_prefill_tokens", "count"),
     "prefill_tokens": ("serve_prefill_tokens", "total"),
+    "prefill_sparse_kernel_chunks": ("serve_prefill_sparse_kernel_chunks",
+                                     "total"),
     "kv_blocks_in_use": ("serve_kv_blocks_in_use", "total"),
     "host_busy_ms": ("serve_host_busy_ms", "total"),
     "fetch_wait_ms": ("serve_fetch_wait_ms", "total"),

@@ -13,7 +13,11 @@ PERF.md section 6) and what no CPU lowering can show:
   ``engine.attend_chunk`` halves them so that they stay.
 
 The compiler decides both for the whole program, so the whole program
-is what is compiled. One file, the topology described inside a fixture:
+is what is compiled. The hybrid cell's two programs, and the sparse
+cell's ``serve_prefill`` (its attention in the
+``sparse_prefill_attn`` kernel: a VMEM overrun shows here, not on the
+chip), are compiled the same way. One file, the topology described
+inside a fixture:
 only the worker that runs this file loads the TPU's library.
 """
 
@@ -263,3 +267,93 @@ def test_hybrid_decode_advances_the_state_in_the_pool_by_the_kernel(
 def test_hybrid_prefill_holds_no_state_kernel(hybrid_compiled):
     _, text = hybrid_compiled["serve_prefill"]
     assert "ssm_state_step" not in text
+
+
+# ---- the sparse cell's prefill chunk ----------------------------------------
+
+@pytest.fixture(scope="module")
+def keye_prefill(one_chip):
+    """``serve_prefill`` of ``keye-serve-long16``'s configuration at its
+    geometry (16 slots x 36,864 positions, block 16, chunk 512) and
+    published widths, at 2 of its 8 layers (every layer is alike),
+    compiled for the v5e: {"kernel": text, "plain": text}, the second
+    with the kernel's predicate refusing, so the chunk attends in the
+    ``lax.map`` parts the kernel replaces."""
+    import json
+    from pathlib import Path
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from benchmark.lib.families import keye_vl2
+    from tpu_ddp.ops import pallas as pallas_ops
+    from tpu_ddp.ops.pallas import sparse_prefill_attention
+    from tpu_ddp.serve.kv_pool import index_page
+
+    root = Path(__file__).resolve().parents[1]
+    cfg = json.loads((root / "benchmark/configs/"
+                      "keye-vl-2.0-30b-a3b-l8e16.json").read_text())
+    geo = cfg["serve"]
+    S, B, C = geo["num_slots"], geo["block_size"], geo["prefill_chunk"]
+    bps, L = geo["max_seq_len"] // B, 2
+    model = keye_vl2.build(dict(cfg, num_hidden_layers=L),
+                           max_seq_len=geo["max_seq_len"],
+                           param_dtype=jnp.bfloat16)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(model.init, jax.random.key(0)))
+    pool = sds((L, S * bps + 1, B, model.kv_heads * model.head_dim),
+               jnp.bfloat16)
+    index = sds((L, S * bps + 1) + index_page(B, model.index_dim),
+                jnp.bfloat16)
+    i32, f32 = jnp.int32, jnp.float32
+    args = (params, pool, pool, {"index": index}, sds((bps,), i32),
+            sds((1, C), i32), sds((), i32), sds((), i32), sds((), f32),
+            sds((), i32), sds((), i32))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    interpret = pallas_ops.interpret_mode
+    supports = sparse_prefill_attention.supports
+    pallas_ops.interpret_mode = lambda: False   # the kernel, for the chip
+    out = {}
+    try:
+        for form in ("kernel", "plain"):
+            if form == "plain":
+                sparse_prefill_attention.supports = lambda *shapes: False
+            # not memoized: the two forms have one geometry
+            step = engine._build_prefill_step.__wrapped__(model, B, bps)
+            out[form] = step.trace(*args).lower(
+                lowering_platforms=("tpu",)).compile().as_text()
+    finally:
+        sparse_prefill_attention.supports = supports
+        pallas_ops.interpret_mode = interpret
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    return out
+
+
+def test_keye_prefill_attends_through_the_sparse_kernel(keye_prefill):
+    """One ``sparse_prefill_attn`` call a layer, under ``attn``, taking
+    the 512 queries (transposed to (KV, G, C, hd)) and the gathered view
+    as it is: no value of the ``lax.map`` form's per-part scores or
+    weights over the whole table (``[4,8,8,36864]``, eight queries of a
+    part) is left, and no copy of the 75 MB view in another layout."""
+    text = keye_prefill["kernel"]
+    entry = text[text.index("ENTRY "):]
+    calls = re.findall(
+        r"\n\s*%sparse_prefill_attn[.\d]* = (bf16\[[\d,]+\])[^=\n]* "
+        r"custom-call\(([^\n]*)", entry)
+    assert [shape for shape, _ in calls] == ["bf16[4,8,512,128]"] * 2
+    for _, call in calls:
+        assert 'custom_call_target="tpu_custom_call"' in call
+        assert ('op_name="jit(serve_prefill)/attn/jit(_impl)/'
+                'sparse_prefill_attn/pallas_call"') in call
+    parts = r"\[(?:1,)?4,8,8,36864\]|\[4,36864,8,8\]"
+    assert not re.findall(parts, text)
+    assert not re.findall(r"bf16\[(?:1,)?(?:4,36864|36864,4),128\]", entry)
+    # the same program with the kernel refused has the parts' values
+    assert len(re.findall(parts, keye_prefill["plain"])) >= 4
+    assert "sparse_prefill_attn" not in keye_prefill["plain"]

@@ -359,6 +359,42 @@ def test_the_null_block_and_idle_slots_leak_into_nothing(model, params):
         assert _worst(model, params, p, r) < TOL
 
 
+def test_a_chunk_through_the_kernel_gives_the_lax_map_forms_tokens(
+        monkeypatch):
+    """Heads of 128 and a table of 2,048 positions (two key blocks, the
+    second masked for every query of these prompts): the chunks attend
+    through the sparse prefill kernel, and the engine gives the tokens
+    of the ``lax.map`` form over ``attend_masked`` and the reference's
+    log-probabilities."""
+    model = _model(head_dim_=128, max_seq_len=2048)
+    params = model.init(jax.random.key(7))
+    geom = dict(prefill_chunk=32)
+    assert engine_mod.sparse_kernel_takes(model, 32, 2048, jnp.float32)
+    eng, prompts, reqs = _serve(model, params, **geom)
+    kernel = "sparse_prefill_attn/pallas_call"
+    assert eng._sparse_kernel and kernel in eng.lower_prefill_step(
+    ).as_text(debug_info=True)
+    tally = eng._tally()
+    assert tally["prefill_sparse_kernel_chunks"] == tally["prefill_chunks"]
+    assert tally["prefill_chunks"] == sum(-(-n // 32) for n, _ in CASES)
+    monkeypatch.setattr(engine_mod.sparse_prefill_attention, "supports",
+                        lambda *shapes: False)
+    # the programs are memoized on the geometry: build this one anew,
+    # and leave none of the refused form behind
+    engine_mod._build_prefill_step.cache_clear()
+    try:
+        plain, _, plain_reqs = _serve(model, params, **geom)
+        text = plain.lower_prefill_step().as_text(debug_info=True)
+    finally:
+        engine_mod._build_prefill_step.cache_clear()
+    assert not plain._sparse_kernel and kernel not in text
+    assert plain._tally()["prefill_sparse_kernel_chunks"] == 0
+    for p, r, q in zip(prompts, reqs, plain_reqs):
+        assert r.tokens == q.tokens
+        np.testing.assert_allclose(r.logprobs, q.logprobs, atol=TOL, rtol=0)
+        assert _worst(model, params, p, r) < TOL
+
+
 # ---- the counts ---------------------------------------------------------------------
 
 def test_the_tally_counts_what_the_selection_kept(model, params):
@@ -375,6 +411,10 @@ def test_the_tally_counts_what_the_selection_kept(model, params):
     assert other.pool.index is None
     assert other._tally()["selected_tokens"] == 0
     assert other._tally()["index_scored_tokens"] == 0
+    # heads of 16 lanes: the chunks keep the lax.map form
+    assert tally["prefill_chunks"] == 3
+    assert tally["prefill_sparse_kernel_chunks"] == 0
+    assert other._tally()["prefill_sparse_kernel_chunks"] == 0
 
 
 # ---- (e) what refuses --------------------------------------------------------------

@@ -24,6 +24,10 @@ Pallas:
 - :mod:`grouped_matmul` — the dropless expert layer's grouped product
                     over sorted rows: each held expert's matrix
                     streamed once past rows resident on chip.
+- :mod:`sparse_prefill_attention` — a sparse-attention layer's prefill
+                    chunk over the gathered K/V with the indexer's
+                    selection as the mask: the scores computed once, in
+                    one flash-style pass.
 
 Every kernel runs compiled on TPU and falls back to interpreter mode on
 CPU (tests force the host platform, conftest.py), selected automatically.

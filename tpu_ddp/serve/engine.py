@@ -104,7 +104,11 @@ from tpu_ddp.models.sparse_moe import (
     select_top,
     selection_mask,
 )
-from tpu_ddp.ops.pallas import paged_attention, ssm_state_step
+from tpu_ddp.ops.pallas import (
+    paged_attention,
+    sparse_prefill_attention,
+    ssm_state_step,
+)
 from tpu_ddp.serve.kv_pool import (
     PagedKVPool,
     StatePool,
@@ -346,6 +350,16 @@ def sparse_step(model, blk, x, pool_k, pool_v, index, li: int, xi: int,
     return block_finish(model, blk, x, o), pool_k, pool_v, index
 
 
+def sparse_kernel_takes(model, C: int, T: int, kv_dtype) -> bool:
+    """Does a sparse-attention layer's prefill chunk of ``C`` queries
+    attend over its ``T``-position view of ``kv_dtype`` through the
+    kernel (ops/pallas/sparse_prefill_attention.py)? Shapes and dtypes
+    only, and the K/V in the compute dtype."""
+    return jnp.dtype(kv_dtype) == jnp.dtype(model.compute_dtype) \
+        and sparse_prefill_attention.supports(
+            C, T, model.num_heads, model.kv_heads, model.head_dim, kv_dtype)
+
+
 def sparse_chunk(model, blk, x, pool_k, pool_v, index, li: int, xi: int,
                  blk_idx, off, table, p):
     """A sparse-attention layer of a prefill chunk ``x`` (1, C, dm) at
@@ -354,9 +368,10 @@ def sparse_chunk(model, blk, x, pool_k, pool_v, index, li: int, xi: int,
     of the chunk scored against every position and its selection made
     under ``attn/index`` (the scores in parts, float32 within
     ``PREFILL_SCORES_BYTES``; the selection for the whole chunk at once),
-    and the chunk attended over the gathered view in :func:`attend_chunk`'s
-    parts with its selection as the mask. Returns (x, pool_k, pool_v,
-    index)."""
+    and the chunk attended over the gathered view with its selection as
+    the mask: in one pass of the kernel where :func:`sparse_kernel_takes`
+    holds, else in :func:`attend_chunk`'s parts through
+    ``attend_masked``. Returns (x, pool_k, pool_v, index)."""
     C = x.shape[1]
     with jax.named_scope("attn"):
         y = model.norm(x, blk["ln1"])
@@ -383,11 +398,16 @@ def sparse_chunk(model, blk, x, pool_k, pool_v, index, li: int, xi: int,
                 (qi[0].reshape((C // n, n) + qi.shape[2:]),
                  w[0].reshape((C // n, n) + w.shape[2:])))
             keep = selection_mask(model, scores.reshape(1, C, T), p[None])
-        n = C // _parts(C, 4 * q.shape[2] * T)
-        o = lax.map(
-            lambda a: attend_masked(model, a[0][None], ck, cv, a[1][None])[0],
-            (q[0].reshape((C // n, n) + q.shape[2:]),
-             keep[0].reshape(C // n, n, T)))
+        if sparse_kernel_takes(model, C, T, ck.dtype):
+            o = sparse_prefill_attention.sparse_prefill_attention(
+                q[0], ck[0], cv[0], keep[0], scale=attn_scale(model))
+        else:
+            n = C // _parts(C, 4 * q.shape[2] * T)
+            o = lax.map(
+                lambda a: attend_masked(model, a[0][None], ck, cv,
+                                        a[1][None])[0],
+                (q[0].reshape((C // n, n) + q.shape[2:]),
+                 keep[0].reshape(C // n, n, T)))
     return (block_finish(model, blk, x, o.reshape(q.shape)), pool_k, pool_v,
             index)
 
@@ -777,6 +797,11 @@ class ServeEngine:
                                           self.blocks_per_seq)
         self._prefill = _build_prefill_step(model, self.block_size,
                                             self.blocks_per_seq)
+        # the choice sparse_chunk makes as the prefill program is traced
+        self._sparse_kernel = "sparse_attention" in model.mixers \
+            and sparse_kernel_takes(model, self.prefill_chunk,
+                                    self.blocks_per_seq * self.block_size,
+                                    self.pool.dtype)
         # Long-context programs (§27). The tiered step twins replace
         # the decode/prefill programs only when tiers > 1 — at the
         # default they are never built and the round-12 programs run
@@ -1229,6 +1254,8 @@ class ServeEngine:
             "context_tokens": gauge("serve_decode_context_tokens")["total"],
             "prefill_chunks": gauge("serve_prefill_tokens")["count"],
             "prefill_tokens": gauge("serve_prefill_tokens")["total"],
+            "prefill_sparse_kernel_chunks":
+            gauge("serve_prefill_sparse_kernel_chunks")["total"],
             "kv_blocks_in_use": gauge("serve_kv_blocks_in_use")["total"],
             "kv_blocks_usable": self.pool.total_usable,
             "host_busy_ms": gauge("serve_host_busy_ms")["total"],
@@ -1449,6 +1476,9 @@ class ServeEngine:
         start = s.prefill_done
         end = min(start + self.prefill_chunk, int(s.request.prompt.size))
         self.metrics.observe("serve_prefill_tokens", end - start)
+        if self.pool.index is not None:
+            self.metrics.observe("serve_prefill_sparse_kernel_chunks",
+                                 int(self._sparse_kernel))
         # a sparse-attention model's indexer scores each query against
         # its positions 0..p: sum of p + 1 over the chunk's prompt tokens
         scored = ((end * (end + 1) - start * (start + 1)) // 2
@@ -1458,7 +1488,8 @@ class ServeEngine:
                   final=int(end >= s.request.prompt.size),
                   state_reset=int(bool(self.state.arrays)
                                   and start == 0),
-                  index_scored_tokens=scored):
+                  index_scored_tokens=scored,
+                  sparse_kernel=int(self._sparse_kernel)):
             self._prefill_chunk(pi, s, start, mine)
 
     def _prefill_chunk(self, pi: int, s, start: int,

@@ -56,7 +56,8 @@ SPANS = {
         "over every step between them",
         ("steps", "decode_steps", "decode_ahead", "dry_steps",
          "decode_rows", "context_tokens", "prefill_chunks",
-         "prefill_tokens", "kv_blocks_in_use", "kv_blocks_usable",
+         "prefill_tokens", "prefill_sparse_kernel_chunks",
+         "kv_blocks_in_use", "kv_blocks_usable",
          "host_busy_ms", "fetch_wait_ms", "queue_depth",
          # 0 but for a model with sparse-attention layers
          "selected_tokens", "index_scored_tokens")),
@@ -75,9 +76,11 @@ SPANS = {
         "(the slot's state starts from zero), else 0. "
         "index_scored_tokens: the positions a sparse-attention model's "
         "indexer scores as live, p + 1 summed over the chunk's prompt "
-        "positions p (0 for any other model)",
+        "positions p (0 for any other model). sparse_kernel: 1 where "
+        "the chunk's sparse-attention layers attend through the "
+        "sparse_prefill_attn kernel, else 0",
         ("rid", "tokens", "start", "final", "state_reset",
-         "index_scored_tokens")),
+         "index_scored_tokens", "sparse_kernel")),
     "tpu_ddp.serve.decode": (
         "serving", "one whole-bank decode step (plain or fused "
         "speculative): its dispatch and, in the plain engine, the "
@@ -198,6 +201,11 @@ KERNELS = {
                       "grouped product over the sorted assignments, each "
                       "held expert's matrix streamed once past rows "
                       "resident on chip, two calls an expert layer",
+    "sparse_prefill_attn": "sparse_prefill_attention.py: a prefill "
+                           "chunk's queries over the gathered K/V with "
+                           "the indexer's selection as the mask, one "
+                           "flash-style pass, one call a sparse-attention "
+                           "layer",
 }
 
 # jax.named_scope at the layer boundaries of the programs the cells run.
@@ -268,6 +276,11 @@ GAUGES = {
                                    "context_tokens)",
     "serve_prefill_tokens": "every prefill chunk: its prompt tokens "
                             "(the span's tokens)",
+    "serve_prefill_sparse_kernel_chunks": "every prefill chunk of a "
+                                          "model with sparse-attention "
+                                          "layers: 1 where they attend "
+                                          "through the kernel (the "
+                                          "span's sparse_kernel)",
     "serve_decode_selected_tokens": "every decode step of a model with "
                                     "sparse-attention layers: the "
                                     "positions attention reads (the "
