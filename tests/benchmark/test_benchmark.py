@@ -3,7 +3,9 @@
 this process with the device check stubbed (the command line has no
 switch that lets a CPU through)."""
 
+import copy
 import gzip
+import importlib
 import json
 import re
 import sys
@@ -16,9 +18,11 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 HERE = Path(__file__).resolve().parent
 TINY = HERE / "tiny"
-if str(ROOT) not in sys.path:
-    sys.path.insert(0, str(ROOT))
+for path in (ROOT, HERE):    # the program; _spec.py beside this file
+    if str(path) not in sys.path:
+        sys.path.insert(0, str(path))
 
+import _spec  # noqa: E402
 from benchmark import run  # noqa: E402
 from benchmark.lib import loadgen, shapes, trace  # noqa: E402
 
@@ -224,6 +228,75 @@ def test_benchmark_json_names_units_and_references():
         assert set(m.get("workloads", cells)) <= set(
             moved.get("workloads", cells)), m["name"]
         assert run.find("layer_metrics", m["name"], ".py").is_file()
+
+
+# The test modules that hold entries of BENCHMARK.json, each through its
+# ``check_spec(spec)``.
+ENTRY_CHECKS = ["test_tally", "test_decode_attn_kernel_share",
+                "test_decode_ahead_share", "test_decode_expert_kernel_share",
+                "test_decode_state_kernel_share", "test_program_trace",
+                "test_serve_model", "test_keye_cell"]
+FOREIGN = "zz-foreign-cell"
+
+
+def grown(spec: dict) -> dict:
+    """``spec`` as a later PR leaves it: one more cell, joined to every
+    ``workloads`` list, and one more per-layer entry at the end."""
+    out = copy.deepcopy(spec)
+    out["workloads"].append({"name": FOREIGN, "config": spec["configs"][0][
+        "name"], "traffic": "zz-foreign", "chips": 1, "why": "test"})
+    for m in out["end_to_end"] + out["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].append(FOREIGN)
+    out["per_layer"].append({
+        "name": "zz_foreign_share", "unit": "%", "better": "lower",
+        "source": "device_trace", "layer": "kernels", "moves": "itl_p95_ms",
+        "workloads": [FOREIGN]})
+    return out
+
+
+@pytest.mark.parametrize("module", ENTRY_CHECKS)
+def test_entry_checks_hold_after_an_entry_and_a_cell_are_appended(module):
+    """What a later PR may do to BENCHMARK.json (append a per-layer
+    entry, join a cell to the lists) turns no entry check red."""
+    checks = importlib.import_module(module).check_spec
+    checks(SPEC)
+    checks(grown(SPEC))
+
+
+SUBJECT = "serve_dry_step_share"    # an entry whose list three cells share
+
+
+def _listed_twice(m, spec):
+    m["workloads"].append(m["workloads"][0])
+
+
+def _cell_left_out(m, spec):
+    m["workloads"].pop(0)
+
+
+def _field_changed(m, spec):
+    m["better"] = "higher"
+
+
+def _entry_twice(m, spec):
+    spec["per_layer"].append(copy.deepcopy(m))
+
+
+@pytest.mark.parametrize("fault", [_listed_twice, _cell_left_out,
+                                   _field_changed, _entry_twice],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_entry_checks_fail_a_changed_entry(fault):
+    """The helpers still refuse what a later PR may not do to an entry
+    that is there, on the grown spec as on the tree's."""
+    for spec in (copy.deepcopy(SPEC), grown(SPEC)):
+        m = _spec.entry(spec, SUBJECT)
+        cells = list(m["workloads"])
+        fields = {k: v for k, v in m.items() if k not in ("name", "workloads")}
+        _spec.check_entry(spec, SUBJECT, cells, **fields)
+        fault(m, spec)
+        with pytest.raises(AssertionError):
+            _spec.check_entry(spec, SUBJECT, cells, **fields)
 
 
 # ---- load generation -------------------------------------------------------

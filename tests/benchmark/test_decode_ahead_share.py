@@ -15,9 +15,11 @@ import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 HERE = Path(__file__).resolve().parent
-if str(ROOT) not in sys.path:
-    sys.path.insert(0, str(ROOT))
+for path in (ROOT, HERE):    # the program; _spec.py beside this file
+    if str(path) not in sys.path:
+        sys.path.insert(0, str(path))
 
+from _spec import check_entry  # noqa: E402
 from benchmark import run  # noqa: E402
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -75,22 +77,18 @@ def test_recorded_slices_of_the_parent_read_none(kind):
         window=tuple(data["window"]))) is None
 
 
-def _entry(name):
-    return next(m for m in SPEC["per_layer"] if m["name"] == name)
+def check_spec(spec):
+    """By name, wherever in the list they stand: this metric's entry, and
+    PR 27's, which ``test_decode_attn_kernel_share.py`` holds too."""
+    check_entry(spec, NAME, ["sc2-serve-gen"], unit="%", better="higher",
+                source="device_trace", layer="serving", moves="serve_tok_s")
+    check_entry(spec, "decode_attn_kernel_share", ["sc2-serve-gen"],
+                unit="%", better="lower", source="device_trace",
+                layer="kernels", moves="itl_p95_ms")
+    names = [m["name"] for m in spec["per_layer"]]
+    assert len(names) == len(set(names))
 
 
 def test_benchmark_json_entries():
-    """By name, wherever in the list they stand: this PR's entry, and
-    PR 27's, which ``test_decode_attn_kernel_share.py`` looks for in
-    the list's last place and no longer finds there."""
-    assert _entry(NAME) == {
-        "name": NAME, "unit": "%", "better": "higher",
-        "source": "device_trace", "layer": "serving",
-        "moves": "serve_tok_s", "workloads": ["sc2-serve-gen"]}
+    check_spec(SPEC)
     assert (ROOT / "benchmark" / "layer_metrics" / f"{NAME}.py").is_file()
-    assert _entry("decode_attn_kernel_share") == {
-        "name": "decode_attn_kernel_share", "unit": "%",
-        "better": "lower", "source": "device_trace", "layer": "kernels",
-        "moves": "itl_p95_ms", "workloads": ["sc2-serve-gen"]}
-    names = [m["name"] for m in SPEC["per_layer"]]
-    assert len(names) == len(set(names))

@@ -18,9 +18,11 @@ import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 HERE = Path(__file__).resolve().parent
-if str(ROOT) not in sys.path:
-    sys.path.insert(0, str(ROOT))
+for path in (ROOT, HERE):    # the program; _spec.py beside this file
+    if str(path) not in sys.path:
+        sys.path.insert(0, str(path))
 
+from _spec import check_entry  # noqa: E402
 from benchmark import run  # noqa: E402
 from benchmark.lib import scope_time  # noqa: E402
 
@@ -125,12 +127,18 @@ def test_recorded_slices_without_the_kernel_read_none(kind, name):
         window=tuple(data["window"]))) is None
 
 
+def check_benchmark_json(spec, name):
+    check_entry(spec, name, ["gr4h-serve-chat"], unit="%", better="lower",
+                source="device_trace", layer="kernels", moves="itl_p95_ms")
+
+
+def check_spec(spec):
+    for name in (DECODE, PREFILL):
+        check_benchmark_json(spec, name)
+
+
 @pytest.mark.parametrize("name", [DECODE, PREFILL])
 def test_benchmark_json_entry(name):
-    entry = next(m for m in SPEC["per_layer"] if m["name"] == name)
-    assert entry == {"name": name, "unit": "%", "better": "lower",
-                     "source": "device_trace", "layer": "kernels",
-                     "moves": "itl_p95_ms",
-                     "workloads": ["gr4h-serve-chat"]}
+    check_benchmark_json(SPEC, name)
     reader = ROOT / "benchmark" / "layer_metrics" / f"{name}.py"
     assert reader.is_file() and f'"{PROGRAM[name]}"' in reader.read_text()

@@ -16,9 +16,11 @@ import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 HERE = Path(__file__).resolve().parent
-if str(ROOT) not in sys.path:
-    sys.path.insert(0, str(ROOT))
+for path in (ROOT, HERE):    # the program; _spec.py beside this file
+    if str(path) not in sys.path:
+        sys.path.insert(0, str(path))
 
+from _spec import check_cells, check_entry, entry  # noqa: E402
 from benchmark import run  # noqa: E402
 from benchmark.lib import shapes_sparse  # noqa: E402
 
@@ -45,14 +47,18 @@ PUBLISHED = {
                   "q_chunk_size": 512, "topk": 2048},
     "sliding_window": None, "tie_word_embeddings": False,
     "use_sliding_window": False, "vocab_size": 151936}
-# readers of the indexer and the selection, not yet entries of
-# BENCHMARK.json: a new entry goes at the end of per_layer, where
-# test_tally.py holds the tally's four as the last four (PERF.md, section 7)
-NEW = ["decode_index_share", "decode_sparse_attn_share",
-       "decode_sparse_attn_hbm_util", "decode_kept_share"]
+# the readers of the indexer and the selection, entries of BENCHMARK.json
+# that list the cell: (layer, source, better)
+NEW = {"decode_index_share": ("kernels", "device_trace", "lower"),
+       "decode_sparse_attn_share": ("kernels", "device_trace", "lower"),
+       "decode_sparse_attn_hbm_util": ("kernels", "device_trace", "higher"),
+       "decode_kept_share": ("serving", "program_counter", "lower")}
 # itl_p95_ms and what moves it: the 45 s window holds about five of the
-# cell's completions, so its serve_tok_s is the seed's (PERF.md, section 2)
-JOINED = ["itl_p95_ms", "serve_device_idle_share"]
+# cell's completions, so its serve_tok_s is the seed's (PERF.md, section 2).
+# Not prefill_device_ms_per_chunk: a 5 s slice of this cell may hold no
+# prefill chunk, and a listed metric that reads nothing refuses a run.
+JOINED = ["itl_p95_ms", "serve_device_idle_share",
+          "decode_device_ms_per_step", "serve_dry_step_share"]
 
 
 def _config() -> dict:
@@ -347,19 +353,26 @@ def test_the_check_fails_a_planted_fault(fault, tmp_path, monkeypatch,
 
 # ---- the cell and the lists it joined ---------------------------------------------
 
+def check_spec(spec):
+    """The cell's own entries and the lists it joined, by name and by
+    membership, so that a later entry or cell changes nothing here."""
+    for name, (layer, source, better) in NEW.items():
+        check_entry(spec, name, [CELL], unit="%", better=better,
+                    source=source, layer=layer, moves="itl_p95_ms")
+    for name in JOINED:
+        check_cells(entry(spec, name), [CELL])
+    # what moves serve_tok_s does not list the cell, which does not
+    # report it
+    for m in spec["per_layer"]:
+        if m["moves"] == "serve_tok_s":
+            assert CELL not in m.get("workloads", [CELL]), m["name"]
+
+
 def test_the_cell_and_the_lists_it_joined():
     cell = next(w for w in SPEC["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, "serve-long16", 1)
-    metrics = {m["name"]: m for sec in ("end_to_end", "per_layer")
-               for m in SPEC[sec]}
-    for name in JOINED:
-        assert CELL in metrics[name]["workloads"], name
-    # what moves serve_tok_s does not list the cell, which does not
-    # report it
-    for m in SPEC["per_layer"]:
-        if m["moves"] == "serve_tok_s":
-            assert CELL not in m.get("workloads", [CELL]), m["name"]
+    check_spec(SPEC)
     for name in NEW:
         assert run.find("layer_metrics", name, ".py").is_file()
 

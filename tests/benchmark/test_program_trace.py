@@ -23,9 +23,11 @@ import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 HERE = Path(__file__).resolve().parent
-if str(ROOT) not in sys.path:
-    sys.path.insert(0, str(ROOT))
+for path in (ROOT, HERE):    # the program; _spec.py beside this file
+    if str(path) not in sys.path:
+        sys.path.insert(0, str(path))
 
+from _spec import check_cells, entry  # noqa: E402
 from benchmark import run  # noqa: E402
 from benchmark.lib import program_trace, shapes, trace  # noqa: E402
 
@@ -100,23 +102,41 @@ def test_reader_finds_nothing_in_a_trace_without_its_names(name):
     assert read(name, mine) is not None
 
 
+# PR 24's eleven per-layer entries, which came before every reader above
+FIRST = ["compile_s", "compiles_in_window", "train_device_idle_share", "mfu",
+         "flash_time_share", "slot_occupancy_mean", "host_ms_per_engine_step",
+         "decode_hbm_util", "serve_device_idle_share", "train_peak_hbm_gb",
+         "serve_peak_hbm_gb"]
+
+
+def check_benchmark_json(spec, name):
+    """The entry by its name and its cell by membership: a later entry or
+    cell changes nothing here."""
+    m = entry(spec, name)
+    assert set(m) == {"name", "unit", "better", "source", "layer",
+                      "moves", "workloads"}
+    check_cells(m, [READERS[name]])
+    check_cells(entry(spec, m["moves"]), [READERS[name]])
+    assert m["layer"] in {entry(spec, n)["layer"] for n in FIRST}
+    assert m["source"] == ("program_counter" if "tokens" in name
+                           else "device_trace")
+    assert m["better"] in ("lower", "higher")
+    assert m["unit"] == ("%" if name.endswith(("_share", "_util"))
+                         else "tokens" if "tokens" in name else "ms")
+    # appended, nothing before it touched: PR 24's eleven come first
+    names = [e["name"] for e in spec["per_layer"]]
+    assert max(map(names.index, FIRST)) < names.index(name)
+
+
+def check_spec(spec):
+    for name in READERS:
+        check_benchmark_json(spec, name)
+
+
 @pytest.mark.parametrize("name", sorted(READERS))
 def test_benchmark_json_entry(name):
-    entry = next(m for m in SPEC["per_layer"] if m["name"] == name)
-    e2e = {m["name"]: m for m in SPEC["end_to_end"]}
-    assert set(entry) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-    assert entry["workloads"] == [READERS[name]]
-    assert READERS[name] in e2e[entry["moves"]]["workloads"]
-    assert entry["layer"] in {m["layer"] for m in SPEC["per_layer"][:11]}
-    assert entry["source"] == ("program_counter" if "tokens" in name
-                               else "device_trace")
-    assert entry["better"] in ("lower", "higher")
-    assert entry["unit"] == ("%" if name.endswith(("_share", "_util"))
-                             else "tokens" if "tokens" in name else "ms")
+    check_benchmark_json(SPEC, name)
     assert (ROOT / "benchmark" / "layer_metrics" / f"{name}.py").is_file()
-    # appended, nothing before it touched: PR 24's eleven come first
-    assert [m["name"] for m in SPEC["per_layer"]].index(name) >= 11
 
 
 # ---- the values, by hand ----------------------------------------------------

@@ -12,9 +12,11 @@ import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 HERE = Path(__file__).resolve().parent
-if str(ROOT) not in sys.path:
-    sys.path.insert(0, str(ROOT))
+for path in (ROOT, HERE):    # the program; _spec.py beside this file
+    if str(path) not in sys.path:
+        sys.path.insert(0, str(path))
 
+from _spec import check_entry, listing  # noqa: E402
 from benchmark import run  # noqa: E402
 from benchmark.lib import scope_time, shapes_hybrid  # noqa: E402
 
@@ -142,18 +144,25 @@ def test_the_configuration_keeps_every_published_number():
     assert {"assumed", "departures", "deployment"} <= set(cfg)
 
 
+def check_spec(spec):
+    """The lists the cell joined, by membership: a later metric may list
+    the cell and a later cell may join these lists."""
+    assert listing(spec, CELL) >= {
+        "serve_tok_s", "itl_p95_ms", "slot_occupancy_mean",
+        "serve_device_idle_share", "serve_peak_hbm_gb", *NEW}
+    for name in NEW:
+        check_entry(spec, name, [CELL], unit="ms" if "_ms_" in name else "%",
+                    better="higher" if name.endswith("_hbm_util")
+                    else "lower", source="device_trace", layer="kernels",
+                    moves="itl_p95_ms")
+
+
 def test_the_cell_and_the_lists_it_joined():
     cell = next(w for w in SPEC["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "granite-4.0-h-small-l10e36", "serve-chat64", 1)
-    joined = {m["name"] for sec in ("end_to_end", "per_layer")
-              for m in SPEC[sec] if CELL in m.get("workloads", [])}
-    assert joined == {"serve_tok_s", "itl_p95_ms", "slot_occupancy_mean",
-                      "serve_device_idle_share", "serve_peak_hbm_gb",
-                      *NEW}
+    check_spec(SPEC)
     for name in NEW:
-        m = next(m for m in SPEC["per_layer"] if m["name"] == name)
-        assert m["workloads"] == [CELL] and m["moves"] == "itl_p95_ms"
         assert run.find("layer_metrics", name, ".py").is_file()
     traffic = json.loads((ROOT / "benchmark/traffic/serve-chat64.json")
                          .read_text())

@@ -16,9 +16,11 @@ import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 HERE = Path(__file__).resolve().parent
-if str(ROOT) not in sys.path:
-    sys.path.insert(0, str(ROOT))
+for path in (ROOT, HERE):    # the program; _spec.py beside this file
+    if str(path) not in sys.path:
+        sys.path.insert(0, str(path))
 
+from _spec import check_entry  # noqa: E402
 from benchmark import run  # noqa: E402
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -108,18 +110,28 @@ def test_recorded_slices_of_an_older_program_read_none(kind, name):
         window=tuple(data["window"]))) is None
 
 
+def check_benchmark_json(spec, name):
+    """The entry by its name, wherever it stands in ``per_layer``; its
+    cells by membership, so that a later entry or cell changes nothing
+    here."""
+    serve = name in SERVE
+    check_entry(
+        spec, name,
+        ["sc2-serve-gen", "gr4h-serve-chat"] if serve else ["sc2-train-s4k"],
+        unit="ms" if "_ms_" in name else "%",
+        better="higher" if name == "kv_pool_used_share" else "lower",
+        source="program_counter",
+        layer="serving" if serve else "train loop",
+        moves={"serve_dry_step_share": "itl_p95_ms",
+               "train_dry_step_share": "train_throughput"}.get(
+                   name, "serve_tok_s"))
+
+
+def check_spec(spec):
+    for name in NEW:
+        check_benchmark_json(spec, name)
+
+
 @pytest.mark.parametrize("name", NEW)
 def test_benchmark_json_entry(name):
-    entry = next(m for m in SPEC["per_layer"] if m["name"] == name)
-    serve = name in SERVE
-    assert entry == {
-        "name": name, "unit": "ms" if "_ms_" in name else "%",
-        "better": "higher" if name == "kv_pool_used_share" else "lower",
-        "source": "program_counter",
-        "layer": "serving" if serve else "train loop",
-        "moves": {"serve_dry_step_share": "itl_p95_ms",
-                  "train_dry_step_share": "train_throughput"}.get(
-                      name, "serve_tok_s"),
-        "workloads": ["sc2-serve-gen", "gr4h-serve-chat"] if serve
-        else ["sc2-train-s4k"]}
-    assert [m["name"] for m in SPEC["per_layer"]][-len(NEW):] == NEW
+    check_benchmark_json(SPEC, name)
