@@ -485,6 +485,7 @@ FROM_LOGGER = {
     "prefill_tokens": ("serve_prefill_tokens", "total"),
     "prefill_sparse_kernel_chunks": ("serve_prefill_sparse_kernel_chunks",
                                      "total"),
+    "prefill_state_carried": ("serve_prefill_state_carried", "total"),
     "kv_blocks_in_use": ("serve_kv_blocks_in_use", "total"),
     "host_busy_ms": ("serve_host_busy_ms", "total"),
     "fetch_wait_ms": ("serve_fetch_wait_ms", "total"),

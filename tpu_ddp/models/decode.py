@@ -119,6 +119,9 @@ def mlp(model, blk, y):
     (``model.shared_ff``), the shared gated MLP (scope ``shared_mlp``),
     summed in float32.
 
+    A model that says so (``model.swiglu``) runs one SiLU-gated MLP
+    (:func:`gated_mlp`, ``w1`` holding the gate and up projections).
+
     Dense models run the two qdot matmuls (fp or fused int8). MoE
     models run the routed layer (tpu_ddp/parallel/moe.py) with the
     expert axis UNSHARDED — serving params are dense — and capacity
@@ -136,6 +139,8 @@ def mlp(model, blk, y):
     """
     from tpu_ddp.ops.quant import qdot
     cd = model.compute_dtype
+    if getattr(model, "swiglu", False):
+        return gated_mlp(model, y, blk["w1"], blk["w2"]).astype(cd)
     if getattr(model, "held", None) is not None:
         from tpu_ddp.parallel.moe import dropless_moe
         with jax.named_scope("moe"):

@@ -47,7 +47,10 @@ sequence and no K/V (``model.mixers``): the paged pool then holds pages
 for the attention layers only, and a ``StatePool`` indexed by slot lives
 beside it, donated through both step programs and updated in place like
 the pool (docs/DESIGN.md §31 has the three rules that take the place of
-the null block there).
+the null block there). A model may keep such state in every layer and
+no K/V at all (power retention, models/retention.py): its pool then has
+0 layers and 0 bytes, its block tables only mark live slots, and the
+step programs donate the state alone (docs/DESIGN.md §33).
 
 A model may attend, in some layers, over a learned selection of the
 cached positions (``"sparse_attention"``, models/sparse_moe.py): those
@@ -95,10 +98,16 @@ from tpu_ddp.models.decode import (
     dense_params_from_checkpoint,
     mlp_half,
     project_qkv,
+    residual,
     sample_token,
     ssm_mix,
 )
 from tpu_ddp.models.hybrid import advance_state
+from tpu_ddp.models.retention import (
+    RETENTION,
+    retention_chunk,
+    retention_step,
+)
 from tpu_ddp.models.sparse_moe import (
     index_scores,
     select_top,
@@ -113,6 +122,7 @@ from tpu_ddp.serve.kv_pool import (
     PagedKVPool,
     StatePool,
     gather_view,
+    kv_layers,
     pin_committed,
     rows,
 )
@@ -219,6 +229,14 @@ def paged_kv(model, pool_k, pool_v, li: int, bidx, off, k, v, tables):
     return pool_k, pool_v, ck, cv
 
 
+def _keep(active, new, old):
+    """Rule 1 of the three (module docstring of kv_pool.StatePool):
+    ``new`` for the slots that have a row (``active`` (S,)), ``old``,
+    bit for bit, for the others."""
+    return jnp.where(active.reshape((-1,) + (1,) * (old.ndim - 1)),
+                     new.astype(old.dtype), old)
+
+
 def state_step(model, blk, x, state, si: int, active):
     """A state-space layer of the decode step: state layer ``si`` of the
     pool for the whole bank, advanced by one token where ``active`` (S,)
@@ -235,10 +253,6 @@ def state_step(model, blk, x, state, si: int, active):
     traced."""
     ssm, conv = state["ssm"], state["conv"]
 
-    def keep(new, old):
-        return jnp.where(active.reshape((-1,) + (1,) * (old.ndim - 1)),
-                         new.astype(old.dtype), old)
-
     def in_pool(pool, *step):
         with jax.named_scope("state"):
             return ssm_state_step.ssm_state_step(pool, *step, layer=si,
@@ -249,7 +263,7 @@ def state_step(model, blk, x, state, si: int, active):
             old = pool[si]
         y, new = advance_state(old, *step)
         with jax.named_scope("state"):
-            return y, pool.at[si].set(keep(new, old))
+            return y, pool.at[si].set(_keep(active, new, old))
 
     takes = ssm_state_step.supports(ssm.shape[3], ssm.shape[4], ssm.dtype)
     with jax.named_scope("ssm"):
@@ -258,8 +272,49 @@ def state_step(model, blk, x, state, si: int, active):
         x, ssm, new_conv = ssm_mix(model, blk, x, ssm, old_conv,
                                    advance=in_pool if takes else plain)
         with jax.named_scope("state"):
-            conv = conv.at[si].set(keep(new_conv, old_conv))
+            conv = conv.at[si].set(_keep(active, new_conv, old_conv))
     return mlp_half(model, blk, x), {"ssm": ssm, "conv": conv}
+
+
+def retention_state_step(model, blk, x, state, si: int, active, pos):
+    """A power-retention layer of the decode step (scope ``retention``):
+    state layer ``si`` of the pool for the whole bank at positions ``pos``
+    (S, 1), under rule 1 as in :func:`state_step`. Norm, projections and
+    gate, then under ``retention/state`` the slots' ``S`` and ``z`` read
+    out of the pool, the readout from them and the update written back
+    where the slot has a row (models/retention.py ``retention_step``: the
+    readout and the update each read the layer's state once, and the
+    update writes it once, in place), then the output projection and the
+    residual."""
+    with jax.named_scope("retention"):
+        y = model.norm(x, blk["ln1"])
+        q, k, v = model.qkv_proj(blk, y, pos)
+        a = model.gate(blk, y)
+        with jax.named_scope("state"):
+            S, z = state["S"], state["z"]
+            o, new_S, new_z = retention_step(model, q[:, 0], k[:, 0],
+                                             v[:, 0], a[:, 0], S[si], z[si])
+            S = S.at[si].set(_keep(active, new_S, S[si]))
+            z = z.at[si].set(_keep(active, new_z, z[si]))
+        x = residual(model, x, model.out_proj(blk, o[:, None]))
+    return mlp_half(model, blk, x), {"S": S, "z": z}
+
+
+def _slot_state(state, si: int, slot, fresh) -> dict:
+    """Slot ``slot``'s state in state layer ``si``, each array of
+    ``state``: zero where ``fresh`` (rule 2: a request's first chunk,
+    whatever the slot's last tenant left there)."""
+    return {k: jnp.where(fresh, 0, lax.dynamic_slice(
+        a, (si, slot) + (0,) * (a.ndim - 2), (1, 1) + a.shape[2:])[0, 0])
+        .astype(a.dtype) for k, a in state.items()}
+
+
+def _put_slot_state(state, new: dict, si: int, slot) -> dict:
+    """``state`` with slot ``slot``'s state in layer ``si`` set to
+    ``new``, in place."""
+    return {k: lax.dynamic_update_slice(
+        a, new[k].astype(a.dtype)[None, None],
+        (si, slot) + (0,) * (a.ndim - 2)) for k, a in state.items()}
 
 
 def state_chunk(model, blk, x, state, si: int, slot, fresh, n_valid):
@@ -270,18 +325,39 @@ def state_chunk(model, blk, x, state, si: int, slot, fresh, n_valid):
     3, models/hybrid.py ``ssm_chunk``)."""
     with jax.named_scope("ssm"):
         with jax.named_scope("state"):
-            at = {k: (si, slot) + (0,) * (a.ndim - 2)
-                  for k, a in state.items()}
-            old = {k: jnp.where(fresh, 0, lax.dynamic_slice(
-                a, at[k], (1, 1) + a.shape[2:])[0, 0]).astype(a.dtype)
-                for k, a in state.items()}
+            old = _slot_state(state, si, slot, fresh)
         x, ssm, conv = ssm_mix(model, blk, x, old["ssm"], old["conv"],
                                n_valid)
         with jax.named_scope("state"):
-            new = {"ssm": ssm, "conv": conv}
-            state = {k: lax.dynamic_update_slice(
-                a, new[k].astype(a.dtype)[None, None], at[k])
-                for k, a in state.items()}
+            state = _put_slot_state(state, {"ssm": ssm, "conv": conv}, si,
+                                    slot)
+    return mlp_half(model, blk, x), state
+
+
+def retention_state_chunk(model, blk, x, state, si: int, slot, fresh,
+                          n_valid, pos):
+    """A power-retention layer of a prefill chunk ``x`` (1, C, dm) at
+    positions ``pos`` (scope ``retention``), under rules 2 and 3 as in
+    :func:`state_chunk`: the slot's incoming state read (from zero where
+    ``fresh``) and the outgoing one written under ``retention/carry``,
+    with the part of the chunked form that reads and makes the state;
+    the quadratic part within the chunk under ``retention/chunk``
+    (models/retention.py ``retention_chunk``). Rule 3: a padding row's
+    gate is 1 and its key and value are zero, so it advances nothing."""
+    C = x.shape[1]
+    with jax.named_scope("retention"):
+        y = model.norm(x, blk["ln1"])
+        q, k, v = model.qkv_proj(blk, y, pos)
+        valid = jnp.arange(C) < n_valid
+        k = jnp.where(valid[:, None, None], k[0], 0)
+        v = jnp.where(valid[:, None, None], v[0], 0)
+        a = jnp.where(valid[:, None], model.gate(blk, y)[0], 0.0)
+        with jax.named_scope("carry"):
+            old = _slot_state(state, si, slot, fresh)
+        o, S, z = retention_chunk(model, q[0], k, v, a, old["S"], old["z"])
+        with jax.named_scope("carry"):
+            state = _put_slot_state(state, {"S": S, "z": z}, si, slot)
+        x = residual(model, x, model.out_proj(blk, o[None]))
     return mlp_half(model, blk, x), state
 
 
@@ -488,6 +564,11 @@ def decode_bank(model, block_size: int, params, pool_k, pool_v, tables,
             x, state = state_step(model, blk, x, state, si, active)
             si += 1
             continue
+        if mixer == RETENTION:
+            x, state = retention_state_step(model, blk, x, state, si,
+                                            active, pos)
+            si += 1
+            continue
         if mixer == "sparse_attention":
             x, pool_k, pool_v, index = sparse_step(
                 model, blk, x, pool_k, pool_v, state["index"], li, xi, bidx,
@@ -536,6 +617,14 @@ def _carries_more(model) -> bool:
                                                        0))
 
 
+def _donated(model) -> tuple:
+    """What a step program of a model that carries more donates: the
+    K/V pool and the third argument, or the third alone where the pool
+    has no layer (a model whose every layer keeps state: its pool
+    arrays hold 0 bytes and no layer reads or writes them)."""
+    return (1, 2, 3) if kv_layers(model) else (3,)
+
+
 # Both step builders are memoized on (model, block_size, blocks_per_seq)
 # — model is a frozen dataclass, so the key is by-value. Every engine
 # with the same cache geometry shares ONE compiled program; sweep
@@ -556,7 +645,7 @@ def _build_decode_step(model, block_size: int, blocks_per_seq: int):
                                tables, lengths, last_tokens, temps, seeds,
                                state=state)
 
-        return jax.jit(step, donate_argnums=(1, 2, 3))
+        return jax.jit(step, donate_argnums=_donated(model))
 
     @program(SERVE_DECODE)
     def step(params, pool_k, pool_v, tables, lengths, last_tokens,
@@ -601,6 +690,12 @@ def _build_prefill_step(model, block_size: int, blocks_per_seq: int):
                     jnp.clip(prompt_len - start, 0, C))
                 si += 1
                 continue
+            if mixer == RETENTION:
+                x, state = retention_state_chunk(
+                    model, blkp, x, state, si, slot, start == 0,
+                    jnp.clip(prompt_len - start, 0, C), p)
+                si += 1
+                continue
             if mixer == "sparse_attention":
                 x, pool_k, pool_v, index = sparse_chunk(
                     model, blkp, x, pool_k, pool_v, state["index"], li, xi,
@@ -632,7 +727,7 @@ def _build_prefill_step(model, block_size: int, blocks_per_seq: int):
             return chunk(params, pool_k, pool_v, table, tokens, start,
                          prompt_len, temp, seed, state, slot)
 
-        return jax.jit(step, donate_argnums=(1, 2, 3))
+        return jax.jit(step, donate_argnums=_donated(model))
 
     @program(SERVE_PREFILL)
     def step(params, pool_k, pool_v, table, tokens, start, prompt_len,
@@ -728,8 +823,12 @@ class ServeEngine:
             raise ValueError("prefill_chunk must be >= 1")
         self.blocks_per_seq = math.ceil(model.max_seq_len
                                         / self.block_size)
-        if num_blocks is None:
-            num_blocks = self.num_slots * self.blocks_per_seq + 1
+        if num_blocks is None or not kv_layers(model):
+            # A pool without K/V layers holds nothing (a model whose every
+            # layer keeps state): its blocks only mark which slots are
+            # live, so its reservation never bounds the slots.
+            num_blocks = max(num_blocks or 0,
+                             self.num_slots * self.blocks_per_seq + 1)
         cache_dtype = (cache_dtype if cache_dtype is not None
                        else config.serve_cache_dtype)
         # Tiered KV (§27, TPU_DDP_KV_TIERS / TPU_DDP_KV_COLD_DTYPE):
@@ -1256,6 +1355,8 @@ class ServeEngine:
             "prefill_tokens": gauge("serve_prefill_tokens")["total"],
             "prefill_sparse_kernel_chunks":
             gauge("serve_prefill_sparse_kernel_chunks")["total"],
+            "prefill_state_carried":
+            gauge("serve_prefill_state_carried")["total"],
             "kv_blocks_in_use": gauge("serve_kv_blocks_in_use")["total"],
             "kv_blocks_usable": self.pool.total_usable,
             "host_busy_ms": gauge("serve_host_busy_ms")["total"],
@@ -1476,6 +1577,10 @@ class ServeEngine:
         start = s.prefill_done
         end = min(start + self.prefill_chunk, int(s.request.prompt.size))
         self.metrics.observe("serve_prefill_tokens", end - start)
+        # 1 where the chunk starts from the state the slot carries
+        carried = int(bool(self.state.arrays) and start > 0)
+        if self.state.arrays:
+            self.metrics.observe("serve_prefill_state_carried", carried)
         if self.pool.index is not None:
             self.metrics.observe("serve_prefill_sparse_kernel_chunks",
                                  int(self._sparse_kernel))
@@ -1488,6 +1593,7 @@ class ServeEngine:
                   final=int(end >= s.request.prompt.size),
                   state_reset=int(bool(self.state.arrays)
                                   and start == 0),
+                  state_carried=carried,
                   index_scored_tokens=scored,
                   sparse_kernel=int(self._sparse_kernel)):
             self._prefill_chunk(pi, s, start, mine)

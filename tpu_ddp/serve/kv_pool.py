@@ -184,6 +184,13 @@ def _promote_prog(hot_k, hot_v, cold_k, cold_v, cold_sk, cold_sv,
     return hot_k, hot_v
 
 
+def kv_layers(model) -> int:
+    """The layers of ``model`` that keep K/V pages: attention and sparse
+    attention. A layer whose mixer keeps recurrent state has none (its
+    state is in the StatePool)."""
+    return sum(m in ("attention", "sparse_attention") for m in model.mixers)
+
+
 class PagedKVPool:
     """One paged K and V buffer covering every layer of one model.
 
@@ -222,8 +229,14 @@ class PagedKVPool:
         self.dtype = resolve_act_dtype(cache_dtype, model.compute_dtype)
         # Pages for the layers that keep K/V only: a layer whose mixer
         # keeps recurrent state has none (its state is in the StatePool).
-        self.layers = sum(m in ("attention", "sparse_attention")
-                          for m in model.mixers)
+        # A model with no such layer (power retention in every layer)
+        # gets arrays of 0 layers, 0 bytes, that nothing reads or writes:
+        # the step programs do not donate them (engine._donated), scrub
+        # leaves them, and the paths that read pages (prefix reuse, tiers,
+        # disaggregation) refuse a model with state
+        # (decode.check_state_servable). Block ids are still allocated,
+        # on the host only: a slot's table marks it live.
+        self.layers = kv_layers(model)
         page = (block_size, model.kv_heads * model.head_dim)
         # Hot buffers: at tiers == 1 the logical id IS the hot slot
         # (identity map, num_blocks slots) — the round-12 layout,
@@ -733,7 +746,7 @@ class PagedKVPool:
         was demoted or spilled carries its NaNs through the quantizer,
         so every tier scrubs."""
         blocks = list(blocks)
-        if not blocks:
+        if not blocks or not self.layers:
             return
         if self.tiers == 1:
             ids = jnp.asarray(np.asarray(blocks, np.int32))

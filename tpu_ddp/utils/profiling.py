@@ -57,6 +57,7 @@ SPANS = {
         ("steps", "decode_steps", "decode_ahead", "dry_steps",
          "decode_rows", "context_tokens", "prefill_chunks",
          "prefill_tokens", "prefill_sparse_kernel_chunks",
+         "prefill_state_carried",
          "kv_blocks_in_use", "kv_blocks_usable",
          "host_busy_ms", "fetch_wait_ms", "queue_depth",
          # 0 but for a model with sparse-attention layers
@@ -73,14 +74,16 @@ SPANS = {
         "pool.commit; the final chunk's first token stays on the device "
         "and is read with the step's decode rows. state_reset: 1 on a "
         "request's first chunk of a model that keeps recurrent state "
-        "(the slot's state starts from zero), else 0. "
+        "(the slot's state starts from zero), else 0. state_carried: 1 "
+        "on a later chunk of such a model (it starts from the state the "
+        "slot carries), else 0. "
         "index_scored_tokens: the positions a sparse-attention model's "
         "indexer scores as live, p + 1 summed over the chunk's prompt "
         "positions p (0 for any other model). sparse_kernel: 1 where "
         "the chunk's sparse-attention layers attend through the "
         "sparse_prefill_attn kernel, else 0",
         ("rid", "tokens", "start", "final", "state_reset",
-         "index_scored_tokens", "sparse_kernel")),
+         "state_carried", "index_scored_tokens", "sparse_kernel")),
     "tpu_ddp.serve.decode": (
         "serving", "one whole-bank decode step (plain or fused "
         "speculative): its dispatch and, in the plain engine, the "
@@ -230,7 +233,19 @@ SCOPES = {
            "norm, output projection, residual",
     "state": "inside ssm, serving: the reads and writes of the state "
              "pool (the path ssm/state); in serve_decode the "
-             "ssm_state_step kernel where it takes the pool",
+             "ssm_state_step kernel where it takes the pool. Inside "
+             "retention, serve_decode: the slots' S and z read from the "
+             "pool, the readout from them, the update written back (the "
+             "path retention/state)",
+    "retention": "a power-retention block's mixer half: norm, q / k / v "
+                 "projections with their norms and RoPE, the gate, the "
+                 "retention, output projection, residual",
+    "carry": "inside retention, serve_prefill: what reads or makes the "
+             "state, the slot's incoming state read, phi(Q)^T S0 and "
+             "the folded state written (the path retention/carry)",
+    "chunk": "inside retention, serve_prefill: the quadratic part "
+             "within the chunk, (q.k)^2 weights and their sums (the path "
+             "retention/chunk)",
     "moe": "inside mlp: the dropless expert layer over the experts "
            "held",
     "route": "inside moe: router, top-k, gates, and the sort of the "
@@ -276,6 +291,10 @@ GAUGES = {
                                    "context_tokens)",
     "serve_prefill_tokens": "every prefill chunk: its prompt tokens "
                             "(the span's tokens)",
+    "serve_prefill_state_carried": "every prefill chunk of a model with "
+                                   "recurrent state: 1 where it starts "
+                                   "from the state the slot carries (the "
+                                   "span's state_carried)",
     "serve_prefill_sparse_kernel_chunks": "every prefill chunk of a "
                                           "model with sparse-attention "
                                           "layers: 1 where they attend "
